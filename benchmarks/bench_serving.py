@@ -117,10 +117,12 @@ import argparse
 import json
 import os
 
+import jax
 import numpy as np
 
 from benchmarks.common import make_climber
 from repro.core.pda import RemoteFeatureStore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import create_engine
 from repro.serving.scheduler import (TrafficConfig, generate_traffic,
                                      run_workload_async)
@@ -740,6 +742,14 @@ def run_sharded_profile(bundle, params, csv=True):
     import sys
 
     del bundle, params
+    if jax.default_backend() != "cpu":
+        # the forced-host-device child needs a backend of its own, but this
+        # process has touched JAX and holds the accelerator; the mesh path
+        # on real chips is `python chip_smoke.py --four-chips`
+        raise SystemExit(
+            "bench_serving --profile sharded emulates a 4-device mesh on "
+            "the CPU backend only (run with JAX_PLATFORMS=cpu); on TPU "
+            "chips run `python chip_smoke.py --four-chips`")
     print("\n=== Sharded serving: (data=2, model=2) host mesh vs "
           f"single-device (forced {SHARDED_DEVICES} devices, repeat-user "
           f"workload, history {REPEAT_HISTORY}) ===")
@@ -1347,6 +1357,7 @@ PROFILE_RUNNERS = {
 
 
 def main(csv=True, profile: str = "all"):
+    enable_compile_cache()
     cfg, bundle, params = make_climber(d_model=64, layers=2, blocks=2)
     if profile in PROFILE_RUNNERS:
         _merge_report(profile, PROFILE_RUNNERS[profile](bundle, params, csv))

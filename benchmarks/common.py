@@ -1,26 +1,17 @@
 """Shared benchmark utilities."""
-import dataclasses
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config
+from repro.configs import climber as climber_configs
 from repro.models import build_model
-from repro.types import ClimberConfig
-
-
-def bench_climber_cfg(d_model=128, layers=2, blocks=2):
-    """CPU-feasible Climber with the paper's structure (blocks/SUMI/head)."""
-    return dataclasses.replace(
-        get_config("climber"), vocab_size=50_000, d_model=d_model,
-        d_ff=4 * d_model, n_heads=4, n_kv_heads=4, head_dim=d_model // 4,
-        climber=ClimberConfig(num_blocks=blocks, layers_per_block=layers))
 
 
 def make_climber(d_model=128, layers=2, blocks=2, seed=0):
-    cfg = bench_climber_cfg(d_model, layers, blocks)
+    """CPU-feasible Climber with the paper's structure (blocks/SUMI/head)."""
+    cfg = climber_configs.config("reduced", d_model=d_model,
+                                 layers_per_block=layers, num_blocks=blocks)
     bundle = build_model(cfg)
     params, _ = bundle.init(jax.random.key(seed))
     return cfg, bundle, params

@@ -22,6 +22,8 @@ def main() -> None:
                              "roofline"])
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_dso, bench_fke, bench_pda, bench_roofline,
                             bench_serving)
     jobs = {"pda": bench_pda.main, "fke": bench_fke.main,

@@ -3,8 +3,9 @@
 
 Scans the backtick code spans of the narrative docs for repo-relative
 path-like references (contain a ``/`` or a known suffix) and verifies each
-resolves to a real file or directory.  Keeps docs/ARCHITECTURE.md,
-benchmarks/README.md and DESIGN.md honest as the tree refactors.
+resolves to a real file or directory.  Keeps README.md,
+docs/ARCHITECTURE.md, benchmarks/README.md and DESIGN.md honest as the tree
+refactors.
 
 Also cross-checks the ``--profile <name>`` tokens in benchmarks/README.md
 against the ``PROFILE_RUNNERS`` registry in benchmarks/bench_serving.py
@@ -20,7 +21,8 @@ import re
 import sys
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-DOCS = ["docs/ARCHITECTURE.md", "benchmarks/README.md", "DESIGN.md"]
+DOCS = ["README.md", "docs/ARCHITECTURE.md", "benchmarks/README.md",
+        "DESIGN.md"]
 SUFFIXES = (".py", ".md", ".sh", ".json", ".yml")
 
 # `code span` that looks like a repo path: has a slash or a known suffix

@@ -5,7 +5,13 @@ base (512 history + 128 candidates, 3.72 GFLOPs) / long (1024 + 512,
 16.4 GFLOPs).  d_model is not published; d_model=256 reproduces the paper's
 per-request GFLOPs to within ~2x and is recorded as an estimate in DESIGN.md.
 Item/user features enter through an embedding table (vocab = item catalog).
+
+:data:`CONFIG` is the published model.  :func:`config` returns it, or its
+CPU-sized ``reduced`` variant (same structure, smaller widths, depth and
+catalog) for tests, examples and CPU rehearsals.
 """
+import dataclasses
+
 from repro.types import ModelConfig, ClimberConfig
 
 CONFIG = ModelConfig(
@@ -26,3 +32,23 @@ CONFIG = ModelConfig(
     sub_quadratic=False,
     source="arXiv:2502.09888 (Climber) / FLAME Table 2",
 )
+
+
+SIZES = ("published", "reduced")
+
+
+def config(size: str = "published", *, d_model: int = 128,
+           layers_per_block: int = 2, num_blocks: int = 2) -> ModelConfig:
+    """``published``: :data:`CONFIG` unchanged (the keyword widths are
+    ignored).  ``reduced``: the paper's structure (blocks, SUMI, expert
+    head) at ``d_model`` with 4 heads, ``num_blocks`` x
+    ``layers_per_block`` layers and a 50k-item catalog."""
+    if size == "published":
+        return CONFIG
+    if size != "reduced":
+        raise ValueError(f"size must be one of {SIZES}, got {size!r}")
+    return dataclasses.replace(
+        CONFIG, vocab_size=50_000, d_model=d_model, d_ff=4 * d_model,
+        n_heads=4, n_kv_heads=4, head_dim=d_model // 4,
+        climber=ClimberConfig(num_blocks=num_blocks,
+                              layers_per_block=layers_per_block))

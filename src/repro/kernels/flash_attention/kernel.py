@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -165,7 +167,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_kernel(q, k, v, *, mode: str, window: int = 0,
                            n_history: int = 0, sq: int, sk: int,
                            bq: int = 128, bk: int = 128,
-                           interpret: bool = True, q_offset: int = 0):
+                           interpret: bool | None = None, q_offset: int = 0):
     """q [B,H,Sqp,D], k/v [B,Hkv,Skp,D] (pre-padded to block/lane multiples).
 
     ``sq``/``sk`` are the *unpadded* lengths (padding is masked out).
@@ -228,5 +230,5 @@ def flash_attention_kernel(q, k, v, *, mode: str, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),    # l (running denom)
             pltpu.VMEM((bq, d), jnp.float32),    # acc
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(q, k, v)

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
 
@@ -32,8 +31,6 @@ def flash_attention_bhsd(q, k, v, mode: str = "causal", *, window: int = 0,
                          n_history: int = 0, bq: int = 128, bk: int = 128,
                          interpret: bool | None = None, q_offset: int = 0):
     """q [B,H,Sq,D]; k,v [B,Hkv,Sk,D] -> [B,H,Sq,D]."""
-    if interpret is None:
-        interpret = default_interpret()
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bq = min(bq, max(8, 1 << (sq - 1).bit_length()))

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -65,7 +67,7 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_decode_kernel(q, k_cache, v_cache, lengths, *, window: int = 0,
-                        bk: int = 256, interpret: bool = True):
+                        bk: int = 256, interpret: bool | None = None):
     """q [B,Hkv,G,D]; caches [B,S,Hkv,D]; lengths [B,1] i32.
 
     Returns [B,Hkv,G,D].  S must be a multiple of bk (ops.py pads)."""
@@ -98,5 +100,5 @@ def flash_decode_kernel(q, k_cache, v_cache, lengths, *, window: int = 0,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(lengths, q, k_cache, v_cache)

@@ -6,7 +6,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret
 from repro.kernels.flash_decode.kernel import flash_decode_kernel
 
 
@@ -15,8 +14,6 @@ def flash_decode(q, k_cache, v_cache, lengths, *, window: int = 0,
                  bk: int = 256, interpret: bool | None = None):
     """q [B,H,D] (one new token per sequence); caches [B,S,Hkv,D];
     lengths [B].  Returns [B,H,D]."""
-    if interpret is None:
-        interpret = default_interpret()
     b, h, d = q.shape
     s = k_cache.shape[1]
     hkv = k_cache.shape[2]

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 
 def _ffn_kernel(x_ref, scale_ref, wu_ref, wg_ref, wd_ref, o_ref,
                 xn_ref, acc_ref, *, activation: str, nf: int, eps: float,
@@ -60,7 +62,7 @@ def _ffn_kernel(x_ref, scale_ref, wu_ref, wg_ref, wd_ref, o_ref,
 def fused_ffn_kernel(x, norm_scale, w_up, w_gate, w_down, *,
                      activation: str = "swiglu", has_norm: bool = True,
                      bt: int = 256, bf: int = 512, eps: float = 1e-6,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """x [T, d] (T % bt == 0, f % bf == 0 — padded by ops.py)."""
     t, d = x.shape
     f = w_up.shape[1]
@@ -83,5 +85,5 @@ def fused_ffn_kernel(x, norm_scale, w_up, w_gate, w_down, *,
             pltpu.VMEM((bt, d), jnp.float32),                  # normalized x
             pltpu.VMEM((bt, d), jnp.float32),                  # f32 accumulator
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(x, norm_scale, w_up, w_gate, w_down)

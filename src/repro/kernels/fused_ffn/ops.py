@@ -6,7 +6,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret
 from repro.kernels.fused_ffn.kernel import fused_ffn_kernel
 
 
@@ -16,8 +15,6 @@ def fused_ffn_2d(x, w_up, w_down, w_gate=None, norm_scale=None, *,
                  activation: str = "swiglu", bt: int = 256, bf: int = 512,
                  interpret: bool | None = None):
     """x [T,d] -> [T,d] fused norm+FFN."""
-    if interpret is None:
-        interpret = default_interpret()
     t, d = x.shape
     f = w_up.shape[1]
     bt = min(bt, max(8, t))

@@ -56,6 +56,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -138,7 +140,7 @@ def _fused_kernel(idx_ref, lens_ref, ks_ref, vs_ref, q_ref, kh_ref, vh_ref,
 def fused_score_kernel(row_index, lengths, k_scale, v_scale, q, k_hist,
                        v_hist, k_cand, v_cand, *, mode: str, sq: int,
                        s_hist: int, bq: int = 128, bk: int = 128,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """q [B,H,Mp,D] (pre-scaled); k_hist/v_hist [U,Hkv,Sp,D] stored dtype;
     lengths [U] int32 per-pool-row valid history prefix (<= s_hist; pass
     ``full(s_hist)`` for the static cached/extend masks);
@@ -208,6 +210,6 @@ def fused_score_kernel(row_index, lengths, k_scale, v_scale, q, k_hist,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(row_index, lengths, k_scale, v_scale, q, k_hist, v_hist, k_cand,
       v_cand)

@@ -50,7 +50,9 @@ from repro.kernels.fused_score.ref import dequantize_values
 
 
 def _auto_path() -> str:
-    return "kernel" if jax.default_backend() == "tpu" else "jnp"
+    """The Pallas kernel where it compiles for real; the jnp formulation
+    where it would only be interpreted."""
+    return "jnp" if default_interpret() else "kernel"
 
 
 # Observability for the 2-D (segment-packed) row_index auto-reroute below:
@@ -300,7 +302,7 @@ def _pad_to(x, axis, mult):
                                              "interpret"))
 def _fused_kernel_call(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
                        row_index, lengths, mode: str, bq: int, bk: int,
-                       interpret: bool):
+                       interpret: bool | None):
     b, m, h, d = q.shape
     u, s_hist, hkv, _ = k_hist.shape
     bq = min(bq, max(8, 1 << (m - 1).bit_length()))
@@ -387,8 +389,6 @@ def _fused_attention(q, k_hist, v_hist, k_cand, v_cand, *, mode: str,
     if path == "auto":
         path = _auto_path()
     if path == "kernel":
-        if interpret is None:
-            interpret = default_interpret()
         return _fused_kernel_call(q, k_hist, v_hist, k_cand, v_cand,
                                   ks, vs, row_index, lengths, mode, bq,
                                   128, interpret)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, wl_ref, u_ref, s0_ref, o_ref, sf_ref,
                 state_ref, *, chunk: int, n_chunks: int):
@@ -68,7 +70,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, wl_ref, u_ref, s0_ref, o_ref, sf_ref,
 
 
 def rwkv6_scan_kernel(r, k, v, w_log, u, s0, *, chunk: int = 64,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """r,k,v,w_log [BH, S, D] (S % chunk == 0); u [BH, 1, D]; s0 [BH, D, D].
 
     Returns (o [BH, S, D], final_state [BH, D, D])."""
@@ -93,5 +95,5 @@ def rwkv6_scan_kernel(r, k, v, w_log, u, s0, *, chunk: int = 64,
             jax.ShapeDtypeStruct((bh, d, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(r, k, v, w_log, u, s0)

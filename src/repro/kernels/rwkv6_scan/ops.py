@@ -6,7 +6,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret
 from repro.kernels.rwkv6_scan.kernel import rwkv6_scan_kernel
 
 
@@ -16,8 +15,6 @@ def rwkv6_scan(r, k, v, w_log, u, state=None, *, chunk: int = 64,
     """r,k,v,w_log [B,S,H,D]; u [H,D]; state [B,H,D,D] (optional).
 
     Returns (o [B,S,H,D], final_state [B,H,D,D])."""
-    if interpret is None:
-        interpret = default_interpret()
     b, s, h, d = r.shape
     chunk = min(chunk, s)
     pad = (-s) % chunk

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) on the production
 mesh, with ShapeDtypeStruct stand-ins (no allocation), and record
 memory/cost/collective analysis for the roofline.
@@ -8,12 +5,14 @@ memory/cost/collective analysis for the roofline.
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-72b --shape train_4k
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] [--fast]
 
-The XLA_FLAGS line above MUST precede any jax import: jax locks the device
-count at first backend init.  Smoke tests / benches import repro.* directly
-and keep seeing 1 device.
+Run as a script, it forces 512 host devices through ``XLA_FLAGS`` before
+the first backend initialization (jax locks the device count there, not at
+import).  Importing this module sets no flags: smoke tests / benches import
+repro.* directly and keep seeing their own devices.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from typing import Dict, Optional, Tuple
@@ -360,4 +359,5 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

@@ -1,13 +1,20 @@
-"""Production mesh definitions (TPU v5e pods).
+"""Mesh construction: production TPU v5e pods and serving host meshes.
 
-A FUNCTION, not a module-level constant — importing this module never touches
-jax device state (critical: the dry-run sets XLA_FLAGS before first init).
+Functions, not module-level constants — importing this module never touches
+jax device state.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
+
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with Auto axis types: the repo's sharding goes
+    through ``with_sharding_constraint`` and AOT in/out shardings, which
+    expect the partitioner to propagate layouts (``jax.make_mesh`` defaults
+    to Explicit axes)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(shape, axis_names, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,6 +3,9 @@
     PYTHONPATH=src python -m repro.launch.serve --engine flame \
         --requests 32 --buckets 64,32,16 --distribution jittered
     PYTHONPATH=src python -m repro.launch.serve --engine flame \
+        --size published --history 512 --buckets 128,32 --impl fused \
+        --history-cache --pool-dtype int8    # paper-size Climber (TPU)
+    PYTHONPATH=src python -m repro.launch.serve --engine flame \
         --history-cache --pool-slots 128 --users 8 --requests 64
     PYTHONPATH=src python -m repro.launch.serve --engine flame \
         --generate topk --gen-steps 8     # generative candidate decode
@@ -20,20 +23,21 @@ chunk coalescing is exercised for the flame engine.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 
 import jax
 import numpy as np
 
-from repro.configs import get_config, reduced_config
+from repro.configs import climber as climber_configs
+from repro.configs import reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import ServeRequest, available_engines, create_engine
 from repro.serving.api import BeamConfig, DegradationPolicy, TopKConfig
 from repro.serving.faults import FaultInjector
+from repro.serving.kv_cache import POOL_DTYPES, quantized_nbytes
 from repro.serving.scheduler import (TrafficConfig, generate_traffic,
                                      run_workload_async)
 from repro.training import checkpoint
-from repro.types import ClimberConfig
 
 
 def _print_metrics(tag: str, m: dict):
@@ -52,6 +56,31 @@ def _parse_kv_floats(spec: str, what: str) -> dict:
         k, v = part.split("=", 1)
         out[k.strip()] = float(v)
     return out
+
+
+def random_params(bundle, seed: int):
+    """Random weights from ``seed``, initialized in one compiled program
+    (op-by-op init of a 2M-row embedding costs a compile per op)."""
+    return jax.jit(lambda k: bundle.init(k)[0])(jax.random.key(seed))
+
+
+def print_memory_estimate(cfg, bundle, n_history: int):
+    """Print the bytes the rec serving path holds, from shapes alone
+    (nothing is allocated): the params, the item embedding among them,
+    and one user's history-KV pool entry per stored precision."""
+    shapes = jax.eval_shape(lambda k: bundle.init(k)[0], jax.random.key(0))
+    nbytes = lambda a: a.size * a.dtype.itemsize  # noqa: E731
+    kv = bundle.history_kv_specs(shapes, n_history, batch=1)
+    est = {"params": sum(nbytes(a) for a in jax.tree.leaves(shapes)),
+           "embedding": nbytes(shapes["embed"]["embedding"]),
+           **{f"pool_entry_{d}": quantized_nbytes(kv, d)
+              for d in POOL_DTYPES}}
+    c = cfg.climber
+    print(f"[serve] {cfg.name}: {c.num_blocks}x{c.layers_per_block} layers, "
+          f"d_model {cfg.d_model}, {cfg.n_heads} heads of {cfg.head_dim}, "
+          f"vocab {cfg.vocab_size:,}, history {n_history}")
+    print("[serve] estimated bytes: " + ", ".join(
+        f"{k} {v / 1e6:.1f} MB" for k, v in est.items()))
 
 
 def serve_text(args):
@@ -74,13 +103,10 @@ def serve_text(args):
 
 
 def serve_rec(args):
-    cfg = dataclasses.replace(
-        get_config("climber"), vocab_size=50_000, d_model=args.d_model,
-        d_ff=4 * args.d_model, n_heads=4, n_kv_heads=4,
-        head_dim=args.d_model // 4,
-        climber=ClimberConfig(num_blocks=2, layers_per_block=2))
+    cfg = climber_configs.config(args.size, d_model=args.d_model)
     bundle = build_model(cfg)
-    params, _ = bundle.init(jax.random.key(0))
+    print_memory_estimate(cfg, bundle, args.history)
+    params = random_params(bundle, args.seed)
     if args.ckpt:
         params, step = checkpoint.restore(args.ckpt, params)
         print(f"[serve] restored checkpoint @ step {step}")
@@ -389,13 +415,22 @@ def main():
                     help="admission queue bound (backpressure)")
     ap.add_argument("--arrival-gap-ms", type=float, default=0.0,
                     help="max random gap between request arrivals")
-    ap.add_argument("--d-model", type=int, default=128)
+    ap.add_argument("--size", default="reduced",
+                    choices=climber_configs.SIZES,
+                    help="rec engines: Climber as published (2x12 layers, "
+                         "d_model 256, 2M items) or the CPU-sized reduced "
+                         "variant (2x2 layers, --d-model, 50k items)")
+    ap.add_argument("--d-model", type=int, default=128,
+                    help="d_model of --size reduced")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the random weights")
     ap.add_argument("--ckpt", default=None, help="restore params from here")
     ap.add_argument("--arch", default="gemma3-12b",
                     help="text engine: reduced config name")
     ap.add_argument("--tokens", type=int, default=12,
                     help="text engine: tokens per request")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.engine == "text":
         serve_text(args)

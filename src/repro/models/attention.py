@@ -369,8 +369,6 @@ def context_parallel_attention(q, k, v, mode: str, *, window: int, mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     n = mesh.shape[seq_axis]
     batch_axes = tuple(a for a in mesh.axis_names if a != seq_axis)
     s_total = q.shape[1]
@@ -397,8 +395,8 @@ def context_parallel_attention(q, k, v, mode: str, *, window: int, mesh,
                                      window=window)
 
     spec = P(batch_axes, seq_axis, None, None)
-    return shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def attention(q, k, v, mode: str, *, impl: str = "chunked", window: int = 0,

@@ -152,8 +152,6 @@ def moe_apply_a2a(params, x, cfg, *, mesh, axis: str = "data",
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     m = cfg.moe
     b, s, d = x.shape
     e, k = m.num_experts, m.top_k
@@ -230,7 +228,7 @@ def moe_apply_a2a(params, x, cfg, *, mesh, axis: str = "data",
         return combined, lb, zl, dropped
 
     xt = x.reshape(b * s, d)
-    combined, lb, zl, dropped = shard_map(
+    combined, lb, zl, dropped = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(token_axes, None), P(None, None), P(axis, None, None),
                   P(axis, None, None), P(axis, None, None)),

@@ -619,6 +619,22 @@ class _Beam:
         self.pool_fp = pool_fp
 
 
+class _ParamsBound:
+    """An AOT executable whose first argument, the model params, is bound:
+    the DSO calls it with the per-dispatch operands only.  Attribute reads
+    (``as_text``, ``memory_analysis``, ...) reach the executable."""
+
+    def __init__(self, compiled, params):
+        self.compiled = compiled
+        self.params = params
+
+    def __call__(self, *args):
+        return self.compiled(self.params, *args)
+
+    def __getattr__(self, name):
+        return getattr(self.compiled, name)
+
+
 @register_engine("flame")
 class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
     """PDA -> coalescing DSO -> Climber, per the paper's Fig 1/Fig 4.
@@ -767,6 +783,11 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                  faults=None,
                  dispatch_retries: int = 2):
         self.bundle = bundle
+        if mesh is not None:
+            # the executors take the params as an argument: replicate them
+            # over the mesh once, so no dispatch moves weights
+            params = jax.device_put(params, jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec()))
         self.params = params
         self.cfg = bundle.cfg
         self.n_history = n_history
@@ -951,13 +972,17 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
             getattr(self, "_decode_row_specs", ()), batch)
 
         def build_fn(kind: str, bucket: int, batch: int):
+            # every executor takes the model params as its FIRST argument
+            # (bound at build time, see _ParamsBound): params closed over
+            # by the traced function would be embedded in each program as
+            # HLO constants — a copy of the item embedding per executable
             if kind == "full":
-                def fn(history, candidates, side):
+                def fn(params, history, candidates, side):
                     b = {"history": history,
                          # -1 chunk-padding sentinel -> a real (ignored) row
                          "candidates": jnp.maximum(candidates, 0),
                          "side": side}
-                    return bundle.prefill(self.params, b, impl=self.impl)
+                    return bundle.prefill(params, b, impl=self.impl)
                 shapes = (hist_spec(batch),
                           jax.ShapeDtypeStruct((batch, bucket), jnp.int32),
                           side_spec(batch))
@@ -968,9 +993,9 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 # miss pools what it just computed via put(prequantized=
                 # True) and scores from the same leaves, with no separate
                 # quantize pass and no raw read-back
-                def fn(history, side):
+                def fn(params, history, side):
                     kv = bundle.encode_history(
-                        self.params, {"history": history, "side": side},
+                        params, {"history": history, "side": side},
                         impl=self.impl)
                     if self._fused:
                         kv = quantize_kv_graph(kv, self.history_pool.dtype)
@@ -982,12 +1007,12 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 # Under the fused impl the basis arrives RAW (the pool's
                 # stored int8/bf16 leaves + scales, 4x fewer dispatch bytes
                 # for int8) and dequantizes in-graph inside extend_history
-                def fn(*args):
+                def fn(params, *args):
                     *kv_leaves, history, side = args
                     kv = jax.tree.unflatten(self._cached_treedef,
                                             list(kv_leaves))
                     out = bundle.extend_history(
-                        self.params, kv, {"history": history, "side": side},
+                        params, kv, {"history": history, "side": side},
                         prefix_len=bucket, impl=self.impl)
                     if self._fused:
                         # in-epilogue re-quantize: same contract as encode
@@ -1004,12 +1029,12 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                     # dedup row index — consumed in-kernel under fused,
                     # via the reference-structured segment attention
                     # elsewhere)
-                    def fn(*args):
+                    def fn(params, *args):
                         *kv_leaves, seg_idx, candidates = args
                         kv = jax.tree.unflatten(self._cached_treedef,
                                                 list(kv_leaves))
                         return bundle.score_candidates(
-                            self.params, kv, jnp.maximum(candidates, 0),
+                            params, kv, jnp.maximum(candidates, 0),
                             impl=self.impl, row_index=seg_idx)
                     # policy.rows (late-bound: build_fn runs inside the
                     # orchestrator's executor build) carries the mesh
@@ -1020,7 +1045,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                         jax.ShapeDtypeStruct((rows, bucket), jnp.int32))
                 elif self._kv_dedup:
                     # deduped signature: unique KV rows + per-row gather idx
-                    def fn(*args):
+                    def fn(params, *args):
                         *kv_leaves, idx, candidates = args
                         if self._fused:
                             # FKE: the raw (stored-precision) rows and the
@@ -1029,24 +1054,24 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                             kv = jax.tree.unflatten(self._cached_treedef,
                                                     list(kv_leaves))
                             return bundle.score_candidates(
-                                self.params, kv, jnp.maximum(candidates, 0),
+                                params, kv, jnp.maximum(candidates, 0),
                                 impl=self.impl, row_index=idx)
                         kv = jax.tree.unflatten(
                             self._cached_treedef,
                             [jnp.take(a, idx, axis=0) for a in kv_leaves])
                         return bundle.score_candidates(
-                            self.params, kv, jnp.maximum(candidates, 0),
+                            params, kv, jnp.maximum(candidates, 0),
                             impl=self.impl)
                     shapes = cached_row_shapes(batch) + (
                         jax.ShapeDtypeStruct((batch,), jnp.int32),
                         jax.ShapeDtypeStruct((batch, bucket), jnp.int32))
                 else:
-                    def fn(*args):
+                    def fn(params, *args):
                         *kv_leaves, candidates = args
                         kv = jax.tree.unflatten(self._cached_treedef,
                                                 list(kv_leaves))
                         return bundle.score_candidates(
-                            self.params, kv, jnp.maximum(candidates, 0),
+                            params, kv, jnp.maximum(candidates, 0),
                             impl=self.impl)
                     shapes = cached_row_shapes(batch) + (
                         jax.ShapeDtypeStruct((batch, bucket), jnp.int32),)
@@ -1061,12 +1086,12 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 # per-candidate seg index; ``lengths`` rides as an extra
                 # packable lead arg alongside the KV leaves.
                 if self._pack_tails:
-                    def fn(*args):
+                    def fn(params, *args):
                         *kv_leaves, lengths, seg_idx, candidates = args
                         kv = jax.tree.unflatten(self._cached_treedef,
                                                 list(kv_leaves))
                         return bundle.decode_logits(
-                            self.params, kv, jnp.maximum(candidates, 0),
+                            params, kv, jnp.maximum(candidates, 0),
                             lengths, impl=self.impl, row_index=seg_idx)
                     rows = policy.rows
                     shapes = decode_row_shapes(batch) + (
@@ -1074,12 +1099,12 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                         jax.ShapeDtypeStruct((rows, bucket), jnp.int32),
                         jax.ShapeDtypeStruct((rows, bucket), jnp.int32))
                 else:
-                    def fn(*args):
+                    def fn(params, *args):
                         *kv_leaves, lengths, candidates = args
                         kv = jax.tree.unflatten(self._cached_treedef,
                                                 list(kv_leaves))
                         return bundle.decode_logits(
-                            self.params, kv, jnp.maximum(candidates, 0),
+                            params, kv, jnp.maximum(candidates, 0),
                             lengths, impl=self.impl)
                     shapes = decode_row_shapes(batch) + (
                         jax.ShapeDtypeStruct((batch,), jnp.int32),
@@ -1088,12 +1113,12 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 # grow a beam cache by its chosen token's K/V at position
                 # ``lengths`` — a fixed-shape scatter into the padded cache,
                 # so every step of every beam reuses this one executor
-                def fn(*args):
+                def fn(params, *args):
                     *kv_leaves, lengths, tokens = args
                     kv = jax.tree.unflatten(self._cached_treedef,
                                             list(kv_leaves))
                     return bundle.append_token(
-                        self.params, kv, jnp.maximum(tokens, 0), lengths,
+                        params, kv, jnp.maximum(tokens, 0), lengths,
                         impl=self.impl)
                 shapes = decode_row_shapes(batch) + (
                     jax.ShapeDtypeStruct((batch,), jnp.int32),
@@ -1122,11 +1147,14 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                         for s in shapes)
                     out_sh = jax.tree.map(
                         lambda s: self._arg_sharding(s.shape),
-                        jax.eval_shape(fn, *shapes))
+                        jax.eval_shape(fn, self.params, *shapes))
                     with shd.mesh_rules(self.mesh, self._shard_rules):
-                        return jax.jit(fn, out_shardings=out_sh) \
-                            .lower(*shapes).compile()
-                return jax.jit(fn).lower(*shapes).compile()
+                        compiled = jax.jit(fn, out_shardings=out_sh) \
+                            .lower(self.params, *shapes).compile()
+                else:
+                    compiled = jax.jit(fn).lower(self.params,
+                                                 *shapes).compile()
+                return _ParamsBound(compiled, self.params)
             finally:
                 set_packed_alignment(prev_align)
 

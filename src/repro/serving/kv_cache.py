@@ -734,6 +734,21 @@ class HistoryKVPool:
             self.spill_bytes_used = 0
             self.shard_bytes_used = 0
 
+    def device_bytes(self) -> Dict[object, int]:
+        """Primary-tier stored bytes per device as actually placed: the
+        sum of every device-resident stored array's addressable shards on
+        each device (host-resident leaves count nowhere).  The measured
+        counterpart of :meth:`shard_bytes`' analytic accounting."""
+        with self._lock:
+            payloads = [e.payload for e in self._entries.values()]
+        out: Dict[object, int] = collections.Counter()
+        for payload in payloads:
+            for a in _stored_arrays(payload):
+                if isinstance(a, jax.Array):
+                    for s in a.addressable_shards:
+                        out[s.device] += s.data.nbytes
+        return dict(out)
+
     def shard_bytes(self) -> List[int]:
         """Primary-tier stored bytes per model shard (one gauge per shard;
         [] for mesh-less pools).  The serving layout is symmetric by

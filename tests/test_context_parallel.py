@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.attention import (context_parallel_attention,
                                     reference_attention)
 
@@ -21,7 +21,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.attention import context_parallel_attention, reference_attention
 
 mesh = make_mesh((2, 4), ("data", "model"))

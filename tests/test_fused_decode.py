@@ -467,17 +467,18 @@ def test_eos_early_exit_truncation_oracle(engines):
     (the no-eos run is the oracle), the row is -1-padded after it, and the
     skipped decode rounds are counted by gen_early_exits."""
     _, fused, _, _ = engines
-    rng = np.random.default_rng(11)   # greedy seq [32,32,9,9,55]: EOS=9
+    rng = np.random.default_rng(11)
     hist = rng.integers(0, VOCAB, N_HIST).astype(np.int32)
     uni = rng.integers(0, VOCAB, 9).astype(np.int32)
     free = fused.serve(hist, candidates=uni, user_id=500,
                        generate=TopKConfig(k=1, steps=5))
     assert (free[0] >= 0).all()
-    # EOS must be a token whose FIRST occurrence is mid-sequence, else the
-    # run legitimately finishes at that earlier step
-    p = next(i for i in range(1, 4)
-             if int(free[0][i]) not in [int(x) for x in free[0][:i]])
-    eos = int(free[0][p])
+    # EOS is the token at p, the latest position (with decode rounds left
+    # after it) where a token of the no-EOS run first occurs: an earlier
+    # occurrence would legitimately finish the run at that earlier step
+    seq = [int(x) for x in free[0]]
+    p = max(i for i in range(len(seq) - 1) if seq[i] not in seq[:i])
+    eos = seq[p]
     before = fused.metrics().get("gen_early_exits", 0)
     out = fused.serve(hist, candidates=uni, user_id=500,
                       generate=TopKConfig(k=1, steps=5, eos=eos))

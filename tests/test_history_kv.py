@@ -66,11 +66,16 @@ def test_cached_candidate_attention_helper():
     nh, m = 40, 12
     q, k, v = _qkv(7, 2, nh + m, 4, 4, 32)
     tau = 1.3
-    full = sumi.sumi_attention(q, k, v, nh, impl="reference",
-                               temperature=tau)[:, nh:]
-    out = sumi.cached_candidate_attention(
+    # jit-wrapped and compared against the candidate rows of the monolithic
+    # SUMI pass computed at the same query count (q_offset): XLA's CPU dots
+    # may round differently when only the query count changes, and
+    # test_q_offset_paths_match_monolithic links those rows to the full pass
+    full = jax.jit(lambda q, k, v: A.reference_attention(
+        q[:, nh:] / jnp.asarray(tau, q.dtype), k, v, "sumi", n_history=nh,
+        q_offset=nh))(q, k, v)
+    out = jax.jit(lambda q, k, v: sumi.cached_candidate_attention(
         q[:, nh:], k[:, :nh], v[:, :nh], k[:, nh:], v[:, nh:],
-        impl="reference", temperature=tau)
+        impl="reference", temperature=tau))(q, k, v)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(full))
 
 
@@ -104,9 +109,12 @@ def test_encode_score_matches_monolithic(climber, impl):
     scale), allclose at bf16-tight tolerance for the block-reordered pallas
     interpret path."""
     cfg, params, batch = climber
-    full = C.climber_forward(params, batch, cfg, impl=impl)
-    kv = C.encode_history(params, batch, cfg, impl=impl)
-    got = C.score_candidates(params, kv, batch["candidates"], cfg, impl=impl)
+    full = jax.jit(lambda p, b: C.climber_forward(p, b, cfg, impl=impl))(
+        params, batch)
+    kv = jax.jit(lambda p, b: C.encode_history(p, b, cfg, impl=impl))(
+        params, batch)
+    got = jax.jit(lambda p, kv, c: C.score_candidates(
+        p, kv, c, cfg, impl=impl))(params, kv, batch["candidates"])
     if impl == "pallas":
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(full, np.float32),
@@ -118,10 +126,12 @@ def test_encode_score_matches_monolithic(climber, impl):
 def test_bundle_split_surface_matches_prefill(climber):
     cfg, params, batch = climber
     bundle = build_model(cfg)
-    probs = bundle.prefill(params, batch, impl="reference")
-    kv = bundle.encode_history(params, batch, impl="reference")
-    got = bundle.score_candidates(params, kv, batch["candidates"],
-                                  impl="reference")
+    probs = jax.jit(lambda p, b: bundle.prefill(p, b, impl="reference"))(
+        params, batch)
+    kv = jax.jit(lambda p, b: bundle.encode_history(
+        p, b, impl="reference"))(params, batch)
+    got = jax.jit(lambda p, kv, c: bundle.score_candidates(
+        p, kv, c, impl="reference"))(params, kv, batch["candidates"])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(probs))
 
 
@@ -141,12 +151,17 @@ def test_kv_independent_of_candidates(climber):
     """The refactor's premise: history K/V must not depend on the candidate
     set (SUMI keeps the prefix self-contained)."""
     cfg, params, batch = climber
-    kv1 = C.encode_history(params, batch, cfg)
-    full1 = C.climber_forward(params, batch, cfg)
-    b2 = dict(batch, candidates=batch["candidates"][:, :5])
-    got = C.score_candidates(params, kv1, b2["candidates"], cfg)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(full1[:, :5]))
+    # K/V encoded once (no candidates in sight) score a DIFFERENT slate
+    # bitwise like the monolithic pass over that slate; both programs are
+    # jitted at one slate size, as the engine's bucketed executors are
+    kv1 = jax.jit(lambda p, b: C.encode_history(p, b, cfg))(params, batch)
+    other = jax.random.randint(jax.random.key(2),
+                               batch["candidates"].shape, 0, 3000)
+    b2 = dict(batch, candidates=other)
+    full2 = jax.jit(lambda p, b: C.climber_forward(p, b, cfg))(params, b2)
+    got = jax.jit(lambda p, kv, c: C.score_candidates(p, kv, c, cfg))(
+        params, kv1, b2["candidates"])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(full2))
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.layers import split_params
 from repro.models.moe import moe_apply, moe_apply_a2a, moe_init
 from tests.test_moe import make_cfg
@@ -23,7 +23,7 @@ import sys
 sys.path.insert(0, "src")
 sys.path.insert(0, ".")
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.layers import split_params
 from repro.models.moe import moe_apply, moe_apply_a2a, moe_init
 from tests.test_moe import make_cfg

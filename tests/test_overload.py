@@ -180,7 +180,11 @@ def test_retry_after_hint_on_shed_and_queue_full():
                        slo_tier_defaults={"interactive": 5.0, "bulk": 50.0})
     try:
         eng.submit(_req(tier="bulk")).result(timeout=30)   # warm the EWMA
-        futs = [eng.submit(_req(tier="bulk")) for _ in range(3)]
+        futs = [eng.submit(_req(tier="bulk"))]
+        t_end = time.perf_counter() + 30
+        while eng.metrics()["pending"] and time.perf_counter() < t_end:
+            time.sleep(0.001)          # the worker takes it: 50 ms busy
+        futs += [eng.submit(_req(tier="bulk")) for _ in range(2)]
         with pytest.raises(ShedError) as ei:
             eng.submit(_req(tier="bulk"))                  # incoming is shed
         assert ei.value.retry_after_s and ei.value.retry_after_s > 0

@@ -165,6 +165,23 @@ def test_pool_spill_respects_budget():
     assert s["spill_bytes"] <= 2 * ent and s["spill_entries"] <= 2
 
 
+@pytest.mark.parametrize("dtype", ["native", "int8"])
+def test_pool_device_bytes_measure_placed_entries(monkeypatch, dtype):
+    """device_bytes() sums the stored arrays' shards per device: it matches
+    bytes_used when entries are device-resident (the accelerator
+    placement, steered here on the CPU backend) and counts host-resident
+    entries nowhere."""
+    kv = _kv_tree(3, shape=(1, 2, 8, 2, 4))
+    host = HistoryKVPool(slots=4, dtype=dtype)
+    host.put("a", "f", kv)
+    assert host.device_bytes() == {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dev = HistoryKVPool(slots=4, dtype=dtype)
+    dev.put("a", "f", kv)
+    dev.put("b", "f", _kv_tree(4, shape=(1, 2, 8, 2, 4)))
+    assert dev.device_bytes() == {jax.devices()[0]: dev.bytes_used}
+
+
 def test_pool_stale_returns_extension_basis():
     p = HistoryKVPool(slots=4)
     p.put("u", "f1", _sized_kv(1, 4), hist_window=np.arange(8, dtype=np.int32))

@@ -30,31 +30,22 @@ import sys
 import jax
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro import sharding as shd
 from repro.core.dso import CoalescePolicy
 from repro.launch.mesh import make_serving_mesh
 
-try:
-    from jax.sharding import AbstractMesh
-except ImportError:                                    # pragma: no cover
-    AbstractMesh = None
-
-needs_abstract_mesh = pytest.mark.skipif(
-    AbstractMesh is None, reason="jax.sharding.AbstractMesh unavailable")
-
 
 def _amesh(shape, axes):
-    # this jax version's AbstractMesh takes ((name, size), ...)
-    return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 # ---------------------------------------------------------------------------
 # 1. rule / spec resolution
 # ---------------------------------------------------------------------------
 
-@needs_abstract_mesh
 def test_resolve_rules_drops_missing_axes():
     mesh = _amesh((2, 2), ("data", "model"))
     rules = shd.resolve_rules(mesh)
@@ -69,7 +60,6 @@ def test_resolve_rules_drops_missing_axes():
     assert shd.resolve_rules(mesh3)["batch"] == ("pod", "data")
 
 
-@needs_abstract_mesh
 def test_serving_rules_replicated_cache_batch_and_cp_fallback():
     mesh = _amesh((2, 2), ("data", "model"))
     # TP case: heads divide the model ways -> history length unsharded
@@ -89,7 +79,6 @@ def test_serving_rules_replicated_cache_batch_and_cp_fallback():
     assert shd.serving_rules(mesh)["cache_seq_shard"] == ()
 
 
-@needs_abstract_mesh
 def test_logical_to_spec_divisibility_fallback():
     mesh = _amesh((2, 2), ("data", "model"))
     rules = shd.serving_rules(mesh, kv_heads=4)
@@ -112,7 +101,6 @@ def test_logical_to_spec_divisibility_fallback():
                                mesh, cp) == P(None, None, "model")
 
 
-@needs_abstract_mesh
 def test_logical_to_spec_used_axis_dedup_and_compose():
     mesh = _amesh((2, 2), ("data", "model"))
     # one mesh axis is spent on the first logical dim that claims it
@@ -128,7 +116,6 @@ def test_logical_to_spec_used_axis_dedup_and_compose():
     assert shd.logical_to_spec(("tokens",), (6,), mesh, rules) == P("data")
 
 
-@needs_abstract_mesh
 def test_rules_for_shape_batch_ways_flip():
     mesh = _amesh((2, 2), ("data", "model"))
     # plenty of batch: default rules, fsdp shards embed over data

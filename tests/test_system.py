@@ -1,5 +1,6 @@
 """End-to-end behaviour of the FLAME system (paper pipeline composed)."""
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
@@ -70,9 +71,11 @@ def test_served_scores_track_planted_preferences(trained_climber):
 
 
 def test_dryrun_machinery_importable():
-    """dryrun helpers are unit-testable without 512 devices (the module-level
-    XLA flag only matters when dryrun is __main__ before jax init)."""
+    """dryrun helpers are unit-testable without 512 devices: importing the
+    module sets no XLA flags (only running it as __main__ does)."""
+    flags = os.environ.get("XLA_FLAGS")
     from repro.launch.dryrun import _with_layers, should_skip
+    assert os.environ.get("XLA_FLAGS") == flags
     from repro.configs import get_shape
     cfg = get_config("qwen2-72b")
     assert should_skip(cfg, get_shape("long_500k")) is not None
