@@ -1,0 +1,8 @@
+"""``fused_score``'s share of its roofline over the traced window: the
+least time of its calls at the chip's peaks over their summed device time
+(see ``stats.roofline_share``)."""
+from flamebench import stats
+
+
+def read(rec):
+    return stats.roofline_share(rec)
