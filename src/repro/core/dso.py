@@ -64,6 +64,7 @@ fill/padding metrics (``dso_dispatches_decode`` etc.) for free.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 import itertools
@@ -77,6 +78,35 @@ from typing import Callable, Dict, Hashable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# ---------------------------------------------------------------------------
+# stage spans
+# ---------------------------------------------------------------------------
+
+class stage:
+    """``with stage("dso.stack", kind=k) as st:`` opens the profiler span
+    ``flame.dso.stack`` around the body and leaves its elapsed
+    ``time.perf_counter`` seconds in ``st.s`` for the caller to add to the
+    stage's counters (``<prefix>_<stage>_s`` / ``_n``).  Keyword arguments
+    become the span's metadata, which the profiler encodes only while it
+    records.  Spans mark work, never a wait: a span open across a blocking
+    wait would cover the device's idle gaps it is meant to explain."""
+
+    __slots__ = ("_ann", "_t0", "s")
+
+    def __init__(self, name: str, **meta):
+        self._ann = jax.profiler.TraceAnnotation("flame." + name, **meta)
+        self.s = 0.0
+
+    def __enter__(self) -> "stage":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.s = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
 
 
 # ---------------------------------------------------------------------------
@@ -549,6 +579,13 @@ class CoalescingOrchestrator:
         self.queue_delay_count = 0
         self.kind_chunks: Dict[str, int] = {k: 0 for k in self.families}
         self.kind_dispatches: Dict[str, int] = {k: 0 for k in self.families}
+        # host-side stage seconds summed over dispatches (the spans
+        # flame.dso.stack / .launch / .readback / .scatter, and the
+        # unspanned device wait), and launch + wait per family
+        self.stage_s: Dict[str, float] = {
+            k: 0.0 for k in ("stack", "launch", "wait", "readback",
+                             "scatter")}
+        self.kind_run_s: Dict[str, float] = {k: 0.0 for k in self.families}
         # fault tolerance (ISSUE 9): ``fault_hook(kind, bucket)`` runs just
         # before every executor launch (the chaos injection point); a raised
         # exception with a truthy ``.transient`` retries with exponential
@@ -804,11 +841,18 @@ class CoalescingOrchestrator:
 
     def _note_dispatch(self, kind: str, bucket: int, n_chunks: int,
                        rows_used: int, valid: int, saved: int,
-                       cost_s: float, packed: bool, missed: int = 0):
+                       packed: bool, missed: int,
+                       stages: Dict[str, float]):
+        """Count one dispatch; ``stages`` holds its stage seconds (keys of
+        ``stage_s``), whose launch + wait is the cost model's sample."""
         key = (kind, bucket)
+        cost_s = stages["launch"] + stages["wait"]
         with self._stat_lock:
             self.dispatch_count += 1
             self.kind_dispatches[kind] += 1
+            self.kind_run_s[kind] += cost_s
+            for k, v in stages.items():
+                self.stage_s[k] += v
             self.rows_dispatched += n_chunks
             self.dedup_rows_saved += saved
             self.slot_count[key] += rows_used * bucket
@@ -828,22 +872,22 @@ class CoalescingOrchestrator:
         return sum(1 for c in batch
                    if c.deadline is not None and now > c.deadline)
 
-    def _run_executor(self, ex: Executor, stacked) -> Tuple[object, float]:  # flamecheck: host-sync-ok(dispatch boundary: the wait must happen inside the timed region — and inside the dispatch lock when executables are multi-device)
-        """Launch + wait, timed; serialized under the dispatch lock when the
-        executables are multi-device (see ``serialize_dispatch``)."""
-        if self._dispatch_lock is not None:
-            with self._dispatch_lock:
-                t0 = time.perf_counter()
+    def _run_executor(self, ex: Executor, stacked, meta: dict
+                      ) -> Tuple[object, float, float]:  # flamecheck: host-sync-ok(dispatch boundary: the wait must happen inside the timed region — and inside the dispatch lock when executables are multi-device)
+        """Launch (span ``flame.dso.launch``) + wait (timed, no span);
+        returns (out, launch_s, wait_s).  Serialized under the dispatch
+        lock when the executables are multi-device (see
+        ``serialize_dispatch``)."""
+        lock = self._dispatch_lock or contextlib.nullcontext()
+        with lock:
+            with stage("dso.launch", **meta) as launch:
                 out = ex(*stacked)
-                jax.block_until_ready(out)
-                return out, time.perf_counter() - t0
-        t0 = time.perf_counter()
-        out = ex(*stacked)
-        jax.block_until_ready(out)
-        return out, time.perf_counter() - t0
+            t0 = time.perf_counter()
+            jax.block_until_ready(out)
+            return out, launch.s, time.perf_counter() - t0
 
-    def _run_attempts(self, kind: str, bucket: int, ex: Executor, stacked
-                      ) -> Tuple[object, float]:
+    def _run_attempts(self, kind: str, bucket: int, ex: Executor, stacked,
+                      meta: dict) -> Tuple[object, float, float]:
         """Fault-tolerant executor run: fire the chaos hook, then the
         executor; an exception with a truthy ``.transient`` attribute (the
         :class:`serving.faults.FaultInjected` contract — real transient
@@ -856,7 +900,7 @@ class CoalescingOrchestrator:
             try:
                 if self._fault_hook is not None:
                     self._fault_hook(kind, bucket)
-                return self._run_executor(ex, stacked)
+                return self._run_executor(ex, stacked, meta)
             except BaseException as e:  # noqa: BLE001 — classified below
                 if not getattr(e, "transient", False) \
                         or attempt >= self._dispatch_retries:
@@ -870,48 +914,63 @@ class CoalescingOrchestrator:
                   batch: List[_PendingChunk]
                   ):  # flamecheck: host-sync-ok(dispatch boundary: results must land on host to fan back out to per-chunk futures)
         n = len(batch)
+        meta = {"kind": kind, "bucket": bucket, "rows": n}
         try:
             B = self.policy.batch
-            stacked = []
-            n_lead = self._dedup.get(kind, 0)
-            n_uniq = n
-            if n_lead:
-                # identity-dedup the leading args: chunks carrying the SAME
-                # arg objects (one request split across chunks, or requests
-                # sharing a pool entry) stack each unique row once; the
-                # executor gathers per-row views through the idx argument
-                slot_of: Dict[tuple, int] = {}
-                uniq: List[tuple] = []
-                idx = np.zeros(B, np.int32)
-                for i, c in enumerate(batch):
-                    ident = self._ident(c, n_lead)
-                    slot = slot_of.get(ident)
-                    if slot is None:
-                        slot = len(uniq)
-                        slot_of[ident] = slot
-                        uniq.append(c.args[:n_lead])
-                    idx[i] = slot
-                n_uniq = len(uniq)
-                for j in range(n_lead):
-                    stacked.append(self._stack_rows([u[j] for u in uniq], B))
-                stacked.append(idx)
-                rests = [c.args[n_lead:] for c in batch]
-            else:
-                rests = [c.args for c in batch]
-            for j in range(len(rests[0])):
-                stacked.append(self._stack_rows([r[j] for r in rests], B))
-            out, dt = self._run_attempts(kind, bucket, ex, stacked)
+            with stage("dso.stack", **meta) as st_stack:
+                stacked = []
+                n_lead = self._dedup.get(kind, 0)
+                n_uniq = n
+                if n_lead:
+                    # identity-dedup the leading args: chunks carrying the
+                    # SAME arg objects (one request split across chunks, or
+                    # requests sharing a pool entry) stack each unique row
+                    # once; the executor gathers per-row views through the
+                    # idx argument
+                    slot_of: Dict[tuple, int] = {}
+                    uniq: List[tuple] = []
+                    idx = np.zeros(B, np.int32)
+                    for i, c in enumerate(batch):
+                        ident = self._ident(c, n_lead)
+                        slot = slot_of.get(ident)
+                        if slot is None:
+                            slot = len(uniq)
+                            slot_of[ident] = slot
+                            uniq.append(c.args[:n_lead])
+                        idx[i] = slot
+                    n_uniq = len(uniq)
+                    for j in range(n_lead):
+                        stacked.append(
+                            self._stack_rows([u[j] for u in uniq], B))
+                    stacked.append(idx)
+                    rests = [c.args[n_lead:] for c in batch]
+                else:
+                    rests = [c.args for c in batch]
+                for j in range(len(rests[0])):
+                    stacked.append(self._stack_rows([r[j] for r in rests], B))
+            out, launch_s, wait_s = self._run_attempts(kind, bucket, ex,
+                                                       stacked, meta)
+            readback_s = 0.0
             if kind in self._device_output:
                 host = out        # stays device-resident (pool entries)
             else:
-                host = jax.tree.map(np.asarray, out)   # pytree outputs OK
+                with stage("dso.readback", **meta) as st_read:
+                    host = jax.tree.map(np.asarray, out)  # pytree outputs OK
+                readback_s = st_read.s
+            with stage("dso.scatter", **meta) as st_scatter:
+                parts = [jax.tree.map(lambda a: a[i:i + 1], host)
+                         for i in range(n)]
             self._note_dispatch(kind, bucket, n, rows_used=n,
                                 valid=sum(c.valid for c in batch),
-                                saved=n - n_uniq, cost_s=dt, packed=False,
-                                missed=self._count_missed(batch))
-            for i, c in enumerate(batch):
-                c.future.set_result(
-                    jax.tree.map(lambda a: a[i:i + 1], host))
+                                saved=n - n_uniq, packed=False,
+                                missed=self._count_missed(batch),
+                                stages={"stack": st_stack.s,
+                                        "launch": launch_s, "wait": wait_s,
+                                        "readback": readback_s,
+                                        "scatter": st_scatter.s})
+            # riders wake only once their dispatch is counted
+            for c, part in zip(batch, parts):
+                c.future.set_result(part)
         except BaseException as e:  # noqa: BLE001 — fail every rider
             with self._stat_lock:
                 self.dispatch_failure_count += 1
@@ -927,34 +986,46 @@ class CoalescingOrchestrator:
         placements, run the executor, and scatter each segment's exact
         ``[1, valid, ...]`` output slice back to its chunk future."""
         n = len(batch)
+        meta = {"kind": kind, "bucket": bucket, "rows": packer.n_rows}
         try:
             B = self.policy.batch
             n_lead = self._packed[kind]
-            # stack each unique KV identity once, in slot order
-            uniq_args: List[Optional[tuple]] = [None] * packer.n_slots
-            for c in batch:
-                slot = packer.slot_of[self._ident(c, n_lead)]
-                if uniq_args[slot] is None:
-                    uniq_args[slot] = c.args[:n_lead]
-            stacked = [self._stack_rows([u[j] for u in uniq_args], B)
-                       for j in range(n_lead)]
-            rows = self.policy.rows
-            seg_idx = np.zeros((rows, bucket), np.int32)
-            cands = np.full((rows, bucket), -1, np.int32)
-            for c, (row, off, slot) in zip(batch, packer.placements):
-                cands[row, off:off + c.valid] = np.asarray(c.args[n_lead])[0]
-                seg_idx[row, off:off + c.valid] = slot
-            stacked += [seg_idx, cands]
-            out, dt = self._run_attempts(kind, bucket, ex, stacked)
-            host = jax.tree.map(np.asarray, out)
+            with stage("dso.stack", **meta) as st_stack:
+                # stack each unique KV identity once, in slot order
+                uniq_args: List[Optional[tuple]] = [None] * packer.n_slots
+                for c in batch:
+                    slot = packer.slot_of[self._ident(c, n_lead)]
+                    if uniq_args[slot] is None:
+                        uniq_args[slot] = c.args[:n_lead]
+                stacked = [self._stack_rows([u[j] for u in uniq_args], B)
+                           for j in range(n_lead)]
+                rows = self.policy.rows
+                seg_idx = np.zeros((rows, bucket), np.int32)
+                cands = np.full((rows, bucket), -1, np.int32)
+                for c, (row, off, slot) in zip(batch, packer.placements):
+                    cands[row, off:off + c.valid] = np.asarray(
+                        c.args[n_lead])[0]
+                    seg_idx[row, off:off + c.valid] = slot
+                stacked += [seg_idx, cands]
+            out, launch_s, wait_s = self._run_attempts(kind, bucket, ex,
+                                                       stacked, meta)
+            with stage("dso.readback", **meta) as st_read:
+                host = jax.tree.map(np.asarray, out)
+            with stage("dso.scatter", **meta) as st_scatter:
+                parts = [jax.tree.map(
+                    lambda a: a[row:row + 1, off:off + c.valid], host)
+                    for c, (row, off, _) in zip(batch, packer.placements)]
             self._note_dispatch(kind, bucket, n, rows_used=packer.n_rows,
                                 valid=sum(c.valid for c in batch),
-                                saved=n - packer.n_slots, cost_s=dt,
-                                packed=True,
-                                missed=self._count_missed(batch))
-            for c, (row, off, _) in zip(batch, packer.placements):
-                c.future.set_result(jax.tree.map(
-                    lambda a: a[row:row + 1, off:off + c.valid], host))
+                                saved=n - packer.n_slots, packed=True,
+                                missed=self._count_missed(batch),
+                                stages={"stack": st_stack.s,
+                                        "launch": launch_s, "wait": wait_s,
+                                        "readback": st_read.s,
+                                        "scatter": st_scatter.s})
+            # riders wake only once their dispatch is counted
+            for c, part in zip(batch, parts):
+                c.future.set_result(part)
         except BaseException as e:  # noqa: BLE001 — fail every rider
             with self._stat_lock:
                 self.dispatch_failure_count += 1
@@ -982,6 +1053,9 @@ class CoalescingOrchestrator:
                 "padded_fraction": 1.0 - valid / slots if slots else 0.0,
                 "queue_delay_ms": (1e3 * self.queue_delay_total_s
                                    / max(self.queue_delay_count, 1)),
+                "queue_delay_s": self.queue_delay_total_s,
+                "queue_delay_n": self.queue_delay_count,
+                **{f"{k}_s": v for k, v in self.stage_s.items()},
                 "dispatch_retries": self.dispatch_retry_count,
                 "dispatch_failures": self.dispatch_failure_count,
                 "deadline_miss_chunks": sum(
@@ -991,6 +1065,7 @@ class CoalescingOrchestrator:
                 for kind in self.families:
                     out[f"chunks_{kind}"] = self.kind_chunks[kind]
                     out[f"dispatches_{kind}"] = self.kind_dispatches[kind]
+                    out[f"run_s_{kind}"] = self.kind_run_s[kind]
                     out[f"deadline_miss_chunks_{kind}"] = \
                         self.deadline_miss_chunks[kind]
                     out[f"cand_slots_{kind}"] = sum(

@@ -231,7 +231,7 @@ class ServeMetrics:
     first/last-timestamp updates used to race under ``run_workload``'s
     thread pool)."""
 
-    def __init__(self):
+    def __init__(self, stages: Sequence[str] = ()):
         self._lock = threading.Lock()
         self.requests = 0
         self.items = 0
@@ -239,7 +239,10 @@ class ServeMetrics:
         self.last_t = 0.0
         self.latencies: list = []
         self.gauges: Dict[str, float] = {}
-        self.counters: Dict[str, int] = {}
+        self.counters: Dict[str, float] = {}
+        for stage in stages:        # timed stages read 0 before first use
+            self.counters[f"{stage}_s"] = 0.0
+            self.counters[f"{stage}_n"] = 0
 
     def record(self, n_items: int, latency_s: float):
         now = time.perf_counter()
@@ -256,8 +259,8 @@ class ServeMetrics:
         history-KV pool's byte accounting (``pool_bytes_used`` vs its
         configured budget), the DSO's cumulative ``padded_fraction``
         (candidate-slot padding dispatched vs reclaimed by segment
-        packing) and ``queue_delay_ms`` (mean chunk enqueue-to-dispatch
-        delay), updated by the engine as requests flow."""
+        packing) and ``beams_in_flight``, updated by the engine as
+        requests flow."""
         with self._lock:
             self.gauges[name] = float(value)
 
@@ -267,6 +270,19 @@ class ServeMetrics:
         ``ServeRequest.deadline_s`` budget)."""
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + by
+
+    def add_time(self, stage: str, seconds: float):
+        """One timed pass through a host stage: adds ``seconds`` to the
+        counter ``<stage>_s`` and 1 to ``<stage>_n``: ``admit`` (span
+        ``flame.admit``), ``features`` (span ``flame.pda.features``) and
+        ``service`` (a worker's time per request after the admission
+        queue; no span, it holds waits).  Both only grow, so a window's
+        mean is the ratio of their deltas."""
+        with self._lock:
+            self.counters[f"{stage}_s"] = \
+                self.counters.get(f"{stage}_s", 0.0) + seconds
+            self.counters[f"{stage}_n"] = \
+                self.counters.get(f"{stage}_n", 0) + 1
 
     def summary(self) -> Dict[str, float]:
         with self._lock:

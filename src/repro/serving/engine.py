@@ -280,7 +280,7 @@ class _PipelinedEngine:
             bad = set(slo_tier_defaults) - set(SLO_TIERS)
             if bad:
                 raise ValueError(f"unknown SLO tiers in defaults: {bad}")
-        self._metrics = ServeMetrics()
+        self._metrics = ServeMetrics(stages=("admit", "features", "service"))
         self._admission = _AdmissionQueue(max_pending, mode=admission)
         self._shed = shed_policy == "tiered"
         self._tier_defaults = dict(slo_tier_defaults) \
@@ -399,7 +399,9 @@ class _PipelinedEngine:
                 f"{dl * 1e3:.3g} ms already exhausted at admission")
         deadline_abs = (request.arrival_t + dl) if dl else None
         fut = ResponseFuture(request)
-        self._admit_hook(request)
+        with DSO.stage("admit", request_id=request.request_id) as st:
+            self._admit_hook(request)
+        self._metrics.add_time("admit", st.s)
         t_submit = time.perf_counter()
         rec = _AdmissionRecord(self._admission.key_for(deadline_abs, tier),
                                fut, t_submit, tier, deadline_abs)
@@ -436,7 +438,7 @@ class _PipelinedEngine:
 
     def metrics(self) -> Dict[str, float]:
         # engine internals first: _extra_metrics may refresh ServeMetrics
-        # gauges (padded_fraction / queue_delay_ms) that summary() reports
+        # gauges (padded_fraction) that summary() reports
         extra = self._extra_metrics()
         out = self._metrics.summary()
         out["pending"] = self._admission.qsize()
@@ -535,6 +537,7 @@ class _PipelinedEngine:
                 _try_fail(fut, e)
             finally:
                 dt = time.perf_counter() - t_deq
+                self._metrics.add_time("service", dt)
                 with self._ewma_lock:
                     s = self._service_ewma_s
                     self._service_ewma_s = dt if s is None \
@@ -584,12 +587,15 @@ class _SideFeatureMixin:
                 f"with >= n_history={self.n_history} entries, got "
                 f"{req.history.shape}")
 
-    def _side_features(self, history: np.ndarray) -> np.ndarray:
-        feats = self.features.query([int(i) for i in history])
-        got = [v for v in feats.values() if v is not None]
-        if not got:
-            return np.zeros((1, N_SIDE_FEATURES), np.float32)
-        return np.mean(got, axis=0, keepdims=True).astype(np.float32)
+    def _side_features(self, history: np.ndarray,
+                       request_id: int = -1) -> np.ndarray:
+        with DSO.stage("pda.features", request_id=request_id) as st:
+            feats = self.features.query([int(i) for i in history])
+            got = [v for v in feats.values() if v is not None]
+            side = np.mean(got, axis=0, keepdims=True).astype(np.float32) \
+                if got else np.zeros((1, N_SIDE_FEATURES), np.float32)
+        self._metrics.add_time("features", st.s)
+        return side
 
     def _admit_hook(self, request: ServeRequest):
         self.features.prefetch([int(i) for i in request.history])
@@ -921,9 +927,6 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
         self._generate = int(generate)
         self._gen_vocab = int(gen_vocab)
         self._gen_lock = threading.Lock()
-        self._gen_t0: Optional[float] = None
-        self._gen_last = 0.0
-        self._gen_tokens = 0
         self._beams_in_flight = 0
         if self._generate:
             if not history_cache:
@@ -1125,6 +1128,9 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                     jax.ShapeDtypeStruct((batch, 1), jnp.int32))
             else:
                 raise ValueError(kind)
+            # a stable name per executor: its HLO module (and its host
+            # events in a trace) read jit_flame_<kind>_b<bucket>
+            fn.__name__ = fn.__qualname__ = f"flame_{kind}_b{bucket}"
             # declare the packer's bq-alignment contract for the duration
             # of THIS trace: the fused ops module consults it when a 2-D
             # seg index reaches _fused_attention, and the knob is process-
@@ -1391,7 +1397,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                                               _retry=False)
         try:
             t0 = time.perf_counter()
-            side = self._side_features(req.history)
+            side = self._side_features(req.history, req.request_id)
             t1 = time.perf_counter()
             kv_tree, path, refreshes = None, "encode", 0
             if basis is not None and self._extend_buckets:
@@ -1480,7 +1486,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
         cand = np.asarray(req.candidates[None],
                           np.int32)  # flamecheck: host-sync-ok(request arrays arrive as host numpy; dtype canonicalized once at admission)
         if self.history_pool is None:
-            side = self._side_features(req.history)
+            side = self._side_features(req.history, req.request_id)
             t1 = time.perf_counter()
             out = self.dso.score((hist, cand, side), req.m, kind="full",
                                  deadline=deadline, tier=req.slo_tier)
@@ -1552,15 +1558,6 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
             np.array(a) if isinstance(a, np.ndarray) else a
             for a in jax.tree.leaves(
                 kv_tree))  # flamecheck: host-sync-ok(copies host VIEWS out of the padded stacked parent so holding them cannot pin it)
-
-    def _note_gen_tokens(self, n: int):
-        now = time.perf_counter()
-        with self._gen_lock:
-            if self._gen_t0 is None:
-                self._gen_t0 = now
-            self._gen_last = now
-            self._gen_tokens += n
-        self._metrics.incr("gen_tokens", n)
 
     def _shift_beams_in_flight(self, delta: int):
         with self._gen_lock:
@@ -1726,7 +1723,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
             _Beam(tokens=(int(universe[o]),), cum=float(lp[o]),
                   finished=(eos is not None and int(universe[o]) == eos))
             for o in order]
-        self._note_gen_tokens(len(beams))
+        self._metrics.incr("gen_tokens", len(beams))
         parent_leaves = {i: root_leaves for i in range(len(beams))}
         parent_of = {i: i for i in range(len(beams))}
         for step in range(1, steps + 1):
@@ -1807,7 +1804,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                         _Beam(tokens=new_seqs[slot],
                               cum=float(new_cum[slot]),
                               finished=bool(new_fin[slot])))
-                self._note_gen_tokens(grew_n)
+                self._metrics.incr("gen_tokens", grew_n)
                 # the next append round reads each UNFINISHED child's
                 # parent cache: keep those addressable host-side (decode
                 # already fetched live parents; a pool-parked one rides
@@ -1839,7 +1836,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                         cum=beams[i].cum + float(step_lp[i][j]),
                         finished=(eos is not None and tok == eos))
                     appended += 1
-                self._note_gen_tokens(appended)
+                self._metrics.incr("gen_tokens", appended)
         return beams
 
     def _extra_metrics(self):
@@ -1855,16 +1852,6 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
         valid = sum(st.get(f"cand_valid_{k}", 0) for k in ("cached", "full"))
         self._metrics.set_gauge(
             "padded_fraction", 1.0 - valid / slots if slots else 0.0)
-        self._metrics.set_gauge("queue_delay_ms", st["queue_delay_ms"])
-        if self._generate:
-            with self._gen_lock:
-                toks = self._gen_tokens
-                dt = self._gen_last - self._gen_t0 \
-                    if self._gen_t0 is not None else 0.0
-            # first-to-last appended-token wall clock; one lone step
-            # reports 0 rather than a meaningless infinite rate
-            self._metrics.set_gauge(
-                "gen_tokens_per_s", toks / dt if dt > 0 else 0.0)
         # satellite observability for the packed-seg kernel->jnp reroute:
         # the ops-module count is process-wide, so fold in deltas only
         reroutes = packed_reroute_count()
@@ -1927,7 +1914,7 @@ class ImplicitShapeServingEngine(_SideFeatureMixin, _PipelinedEngine):
                  ):  # flamecheck: host-sync-ok(Table-5 Default baseline: per-request jit + sync is the comparison point, not a defect)
         self._check_request(req)
         t0 = time.perf_counter()
-        side = self._side_features(req.history)
+        side = self._side_features(req.history, req.request_id)
         t1 = time.perf_counter()
         with self._seen_lock:
             if req.m not in self._seen:

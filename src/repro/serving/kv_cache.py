@@ -60,6 +60,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.dso import stage
+
 
 @dataclasses.dataclass
 class Slot:
@@ -426,6 +428,10 @@ class HistoryKVPool:
         self.extensions = 0
         self.refresh_reencodes = 0
         self.spill_hits = 0
+        # host seconds and calls of lookup / put (spans flame.pool.lookup
+        # and flame.pool.put)
+        self.time_s: Dict[str, float] = {"lookup": 0.0, "put": 0.0}
+        self.calls: Dict[str, int] = {"lookup": 0, "put": 0}
         self.bytes_used = 0
         self.spill_bytes_used = 0
         self.shard_bytes_used = 0
@@ -477,9 +483,26 @@ class HistoryKVPool:
                 np.asarray, kv)  # flamecheck: host-sync-ok(host-placement pools hand out host arrays by contract)
         return kv
 
+    def _add_time(self, name: str, seconds: float):
+        """One timed ``lookup`` or ``put``: ``<name>_s`` / ``<name>_n``."""
+        with self._lock:
+            self.time_s[name] += seconds
+            self.calls[name] += 1
+
     def lookup(self, key: Hashable, fingerprint: Hashable, *,
                want_basis: bool = False, raw: bool = False,
                raw_basis: bool = False):
+        """One counted probe (:meth:`_lookup`) in the span
+        ``flame.pool.lookup``, timed into ``lookup_s`` / ``lookup_n``."""
+        with stage("pool.lookup") as st:
+            out = self._lookup(key, fingerprint, want_basis=want_basis,
+                               raw=raw, raw_basis=raw_basis)
+        self._add_time("lookup", st.s)
+        return out
+
+    def _lookup(self, key: Hashable, fingerprint: Hashable, *,
+                want_basis: bool = False, raw: bool = False,
+                raw_basis: bool = False):
         """One counted probe; see the class docstring.  Checks the primary
         tier, then the spill tier (promoting on a spill hit).  Counter
         bookkeeping happens under the lock; dequantization runs after
@@ -624,6 +647,19 @@ class HistoryKVPool:
             hist_window: Optional[np.ndarray] = None,
             refreshes: int = 0, *, prequantized: bool = False,
             compute_dtype=None) -> bool:
+        """:meth:`_put` in the span ``flame.pool.put``, timed into
+        ``put_s`` / ``put_n``."""
+        with stage("pool.put") as st:
+            out = self._put(key, fingerprint, kv, hist_window, refreshes,
+                            prequantized=prequantized,
+                            compute_dtype=compute_dtype)
+        self._add_time("put", st.s)
+        return out
+
+    def _put(self, key: Hashable, fingerprint: Hashable, kv,
+             hist_window: Optional[np.ndarray] = None,
+             refreshes: int = 0, *, prequantized: bool = False,
+             compute_dtype=None) -> bool:
         """Quantize + admit; returns False when the entry was rejected for
         exceeding ``budget_bytes`` on its own.  ``refreshes`` records how
         many incremental extensions are layered on this entry since its
@@ -786,4 +822,6 @@ class HistoryKVPool:
                 "spill_entries": len(self._spill),
                 "spill_bytes": self.spill_bytes_used,
                 "spill_hits": self.spill_hits,
+                **{f"{k}_s": v for k, v in self.time_s.items()},
+                **{f"{k}_n": v for k, v in self.calls.items()},
             }

@@ -411,12 +411,11 @@ def test_generate_request_validation(engines):
 
 def test_generate_metrics_surface(engines):
     """After the suites above, the decode observability must be populated:
-    decode rounds counted, generation rate derived, no beams left behind."""
+    decode rounds and generated tokens counted, no beams left behind."""
     eng, _ = engines
     m = eng.metrics()
     assert m["decode_steps"] > 0
     assert m["gen_tokens"] > 0
-    assert m["gen_tokens_per_s"] > 0
     assert m["beams_in_flight"] == 0
     assert m.get("dso_dispatches_decode", 0) > 0
     assert m.get("dso_dispatches_append", 0) > 0
