@@ -161,6 +161,51 @@ class Executor:
         return self.compiled(*args)
 
 
+def per_row_signature(fn: Callable, shapes: Sequence, n_kv: int,
+                      split: bool, layout: Optional[Callable] = None
+                      ) -> Tuple[Callable, tuple]:
+    """Executor half of the orchestrator's per-row contract.
+
+    ``fn(params, *args)`` is written against stacked operands ``shapes``
+    whose first ``n_kv`` are history-KV rows with leading axis ``B``.  The
+    returned function instead takes those rows one per slot — ``B`` groups
+    of ``n_kv`` ``[1, ...]`` arrays, slot-major — and concatenates them as
+    its first op, so the copy runs inside the executable's one launch
+    rather than as eager device ops on the dispatch thread.  With ``split``
+    the output comes back as a tuple of ``B`` per-row pytrees (leading axis
+    1 each), so the dispatcher hands rows out by Python index.  ``layout``
+    (shape -> sharding or None, mesh executors) pins each stacked output
+    leaf before the split, so every shard slices its rows locally instead
+    of exchanging them.  Returns the function and its AOT shapes;
+    ``n_kv == 0`` and no ``split`` is ``fn`` unchanged."""
+    shapes = tuple(shapes)
+    if n_kv:
+        slots = shapes[0].shape[0]
+        row = tuple(jax.ShapeDtypeStruct((1,) + s.shape[1:], s.dtype)
+                    for s in shapes[:n_kv])
+        shapes = row * slots + shapes[n_kv:]
+    if not n_kv and not split:
+        return fn, shapes
+
+    def per_row(params, *args):
+        if n_kv:
+            rows, rest = args[:slots * n_kv], args[slots * n_kv:]
+            args = tuple(jnp.concatenate(rows[j::n_kv], axis=0)
+                         for j in range(n_kv)) + rest
+        out = fn(params, *args)
+        if split:
+            if layout is not None:
+                out = jax.tree.map(
+                    lambda a: a if layout(a.shape) is None else
+                    jax.lax.with_sharding_constraint(a, layout(a.shape)),
+                    out)
+            b = jax.tree.leaves(out)[0].shape[0]
+            out = tuple(jax.tree.map(lambda a: a[i:i + 1], out)
+                        for i in range(b))
+        return out
+    return per_row, shapes
+
+
 class ExecutorPool:
     """Per-bucket executor index queues (paper Fig 10).
 
@@ -463,12 +508,18 @@ class CoalescingOrchestrator:
     ``(kind, bucket)`` — the history-cache serving path registers separate
     executor families for full-pass, candidate-only (pool hit) and
     history-encode (pool miss) dispatches, each with its own bucket list and
-    coalescing queues.  With families, ``build_fn(kind, bucket, batch)``
-    builds each executor, ``submit(..., kind=...)`` routes, and
+    coalescing queues.  With families, ``build_fn(kind, bucket, batch,
+    signature)`` builds each executor, ``submit(..., kind=...)`` routes, and
     ``pad_slice_fn(request, chunk, kind)`` / ``gather_fn(rows, chunks, m,
-    kind)`` slice and reassemble.  Executor outputs may be arbitrary pytrees
-    (the encode family returns a HistoryKV dict); rows are scattered back
-    leaf-wise.
+    kind)`` slice and reassemble.  ``signature(fn, shapes, layout=None)``
+    is the orchestrator's own per-row wrapper for that kind
+    (:func:`per_row_signature`, bound to the kind's declared KV count and
+    output split, below): ``build_fn`` writes ``fn`` against stacked
+    ``[batch, ...]`` operands ``shapes``, applies ``signature`` and compiles
+    what it returns, so the dispatch and the executor read one contract.
+    Executor outputs may be arbitrary pytrees (the encode family returns a
+    HistoryKV dict); host outputs are scattered back leaf-wise, and a
+    ``device_output_kinds`` executor returns its rows already split.
 
     Per (kind, bucket) there are ``n_streams`` worker threads, each owning
     one executor (the CUDA-stream analogue).  A worker that pops the first
@@ -477,55 +528,60 @@ class CoalescingOrchestrator:
     deadlines — waiting longer would overrun the earliest deadline given
     the (kind, bucket) EWMA dispatch-cost estimate.  Pending chunks pop in
     EDF order (ties: shortest remaining work, then FIFO).  The collected
-    args are stacked along the batch axis (ONE device transfer per argument
-    per dispatch — the PDA packed-transfer insight applied at dispatch
-    granularity), run through the executor once, and result rows scatter
-    back to the per-chunk futures.  Rows are independent under XLA, so
-    coalesced scores are bitwise-identical to solo dispatches (asserted in
-    tests).
+    host args are stacked along the batch axis (ONE device transfer per
+    argument per dispatch — the PDA packed-transfer insight applied at
+    dispatch granularity), KV rows are handed over per slot, the executor
+    runs once, and result rows scatter back to the per-chunk futures.
+    Rows are independent under XLA, so coalesced scores are
+    bitwise-identical to solo dispatches (asserted in tests).
 
-    PDA v2 device-residency hooks:
+    Per-row KV contract (one launch per dispatch, no eager device ops):
 
-    * **Device-aware stacking** — a chunk argument that is already a JAX
-      device array (a device-resident pool entry) is stacked with
-      ``jnp.concatenate`` on device instead of round-tripping through host
-      numpy; host numpy args keep the v1 one-transfer-per-arg path.
-    * **Device-resident outputs** — kinds listed in ``device_output_kinds``
-      (the history encode/extend families) keep their outputs on device:
-      rows are scattered as device slices, so an encoded entry flows
-      dispatcher -> pool -> next dispatch without ever visiting host
-      memory.
-    * **KV-row dedup** — ``dedup_kinds`` maps a kind to the number of
-      leading args that are identity-deduped per dispatch: chunks whose
-      leading args are the *same objects* (the chunks of one multi-chunk
-      request) or that carry the same ``dedup_token`` through ``submit``
-      (co-batched requests hitting one pool entry — quantized pools
-      dequantize to fresh arrays per lookup, so object identity alone
-      would miss them) are stacked **once**, and the executor receives an
-      extra ``[B] int32`` row-index argument (inserted after the deduped
-      args) to gather each row's view.  The executor must be built for
-      that signature; how it consumes the index is its business — the
-      framework executors materialize ``kv[idx]`` inside the jit, while
-      the FKE (``impl="fused"``) executors forward the index into the
-      fused kernel's KV block reads, making the gather free.  Saved
-      restacks are reported as ``dedup_rows_saved``.
-
-    DSO v2 segment packing:
-
-    * ``packed_kinds`` maps a kind to its number of leading KV args, like
-      ``dedup_kinds`` — but the dispatcher additionally packs partial
-      chunks from different requests into shared rows: ``pad_slice_fn``
-      must return the chunk's candidate slice UNPADDED (``(1, valid)``,
-      last arg), and the executor signature becomes ``(*kv_rows,
-      seg_index [B, bucket] int32, candidates [B, bucket] int32)`` where
-      ``seg_index`` maps every candidate slot to its KV row (padding slots
-      point at row 0 and carry the ``-1`` candidate sentinel).  Each
-      chunk's future resolves to the exact ``[1, valid, ...]`` slice of
-      its segment.  Packing subsumes dedup (same-identity chunks share a
-      KV slot; savings still count into ``dedup_rows_saved``); a kind may
-      not be registered in both maps."""
+    * **In-graph stacking** — ``kv_kinds`` maps a kind to ``(n_kv,
+      mode)``: its number of leading history-KV args and how they fill the
+      dispatch's KV slots (``mode`` below).  The dispatcher never stacks
+      those args: it hands the executor one group of ``[1, ...]`` row
+      arrays per KV slot, slot-major, ``B`` slots (the compiled batch,
+      ``policy.batch``), and fills unused slots with slot 0's arrays (the
+      same objects again — nothing is allocated, and no row index points
+      at a padding slot).  The executor concatenates them as its first op,
+      so a pool entry reaches the launch as the stored array itself.  The
+      remaining host numpy args keep the v1 single ``np.concatenate`` and
+      one transfer per argument; host-numpy KV rows (a host-placed pool)
+      are one transfer per row.
+    * **In-graph splitting** — kinds listed in ``device_output_kinds``
+      (the history encode/extend/append families) have executors that
+      return a tuple of ``B`` per-row output pytrees; the dispatcher hands
+      chunk ``i`` element ``i``, so an encoded entry flows dispatcher ->
+      pool -> next dispatch as its own device buffers, with no slicing on
+      the host.  Other kinds read their stacked output back once and
+      slice it in numpy.
+    * ``mode="row"`` — one KV slot per chunk.
+    * ``mode="dedup"`` — KV-row dedup: chunks whose leading args are the
+      *same objects* (the chunks of one multi-chunk request) or that carry
+      the same ``dedup_token`` through ``submit`` (co-batched requests
+      hitting one pool entry — quantized pools dequantize to fresh arrays
+      per lookup, so object identity alone would miss them) take **one**
+      KV slot, and the executor receives an extra ``[B] int32`` row-index
+      argument (after the per-slot rows) to gather each row's view.  How
+      the executor consumes the index is its business — the framework
+      executors materialize ``kv[idx]`` inside the jit, while the FKE
+      (``impl="fused"``) executors forward the index into the fused
+      kernel's KV block reads, making the gather free.  Saved slots are
+      reported as ``dedup_rows_saved``.
+    * ``mode="packed"`` — DSO v2 segment packing: dedup, and the
+      dispatcher additionally packs partial chunks from different requests
+      into shared rows: ``pad_slice_fn`` must return the chunk's candidate
+      slice UNPADDED (``(1, valid)``, last arg), and the executor signature
+      becomes ``(*kv_rows (per slot), seg_index [rows, bucket] int32,
+      candidates [rows, bucket] int32)`` where ``seg_index`` maps every
+      candidate slot to its KV row (padding slots point at row 0 and carry
+      the ``-1`` candidate sentinel).  Each chunk's future resolves to the
+      exact ``[1, valid, ...]`` slice of its segment.  Same-identity
+      chunks share a KV slot; savings count into ``dedup_rows_saved``."""
 
     _DEFAULT_KIND = "default"
+    KV_MODES = ("row", "dedup", "packed")   # how KV args fill slots
     _COST_EWMA = 0.3          # per-(kind, bucket) dispatch-cost smoothing
 
     def __init__(self, build_fn: Callable,
@@ -534,9 +590,8 @@ class CoalescingOrchestrator:
                  policy: CoalescePolicy = CoalescePolicy(),
                  n_streams: int = 2,
                  families: Optional[Dict[str, Sequence[int]]] = None,
-                 dedup_kinds: Optional[Dict[str, int]] = None,
+                 kv_kinds: Optional[Dict[str, Tuple[int, str]]] = None,
                  device_output_kinds: Sequence[str] = (),
-                 packed_kinds: Optional[Dict[str, int]] = None,
                  serialize_dispatch: bool = False,
                  fault_hook: Optional[Callable[[str, int], None]] = None,
                  dispatch_retries: int = 2,
@@ -550,7 +605,7 @@ class CoalescingOrchestrator:
                                  " or families")
             families = {self._DEFAULT_KIND: buckets}
             _build, _pad, _gather = build_fn, pad_slice_fn, gather_fn
-            build_fn = lambda kind, b, batch: _build(b, batch)  # noqa: E731
+            build_fn = lambda kind, b, batch, sig: _build(b, batch)  # noqa: E731
             pad_slice_fn = lambda req, c, kind: _pad(req, c)    # noqa: E731
             gather_fn = lambda rows, cs, m, kind: _gather(rows, cs, m)  # noqa: E731
         self.families: Dict[str, List[int]] = {
@@ -562,12 +617,19 @@ class CoalescingOrchestrator:
         self.pad_slice = pad_slice_fn
         self.gather = gather_fn
 
-        self._dedup: Dict[str, int] = dict(dedup_kinds or {})
-        self._packed: Dict[str, int] = dict(packed_kinds or {})
-        overlap = set(self._dedup) & set(self._packed)
-        if overlap:
-            raise ValueError(f"kinds {sorted(overlap)} registered as both "
-                             f"dedup and packed — packing subsumes dedup")
+        kv_kinds = dict(kv_kinds or {})
+        bad = sorted(k for k, (_, mode) in kv_kinds.items()
+                     if mode not in self.KV_MODES)
+        if bad:
+            raise ValueError(f"kinds {bad}: KV slot mode not one of "
+                             f"{self.KV_MODES}")
+        # leading history-KV args per kind, handed to the executor per slot
+        self._kv_rows: Dict[str, int] = {k: n for k, (n, _) in
+                                         kv_kinds.items()}
+        self._dedup = frozenset(k for k, (_, mode) in kv_kinds.items()
+                                if mode == "dedup")
+        self._packed = frozenset(k for k, (_, mode) in kv_kinds.items()
+                                 if mode == "packed")
         self._device_output = frozenset(device_output_kinds)
         self.chunk_count = 0
         self.dispatch_count = 0
@@ -575,6 +637,7 @@ class CoalescingOrchestrator:
         self.dedup_rows_saved = 0      # restacks avoided by dedup/packing
         self.packed_rows = 0           # rows carrying >= 1 packed segment
         self.packed_segments = 0       # segments dispatched via packing
+        self.ingraph_dispatches = 0    # KV rows handed over per slot
         self.queue_delay_total_s = 0.0
         self.queue_delay_count = 0
         self.kind_chunks: Dict[str, int] = {k: 0 for k in self.families}
@@ -636,7 +699,8 @@ class CoalescingOrchestrator:
                 self._cond[(kind, b)] = threading.Condition()
                 self.slot_count[(kind, b)] = 0
                 self.valid_count[(kind, b)] = 0
-                compiled = build_fn(kind, b, policy.batch)
+                compiled = build_fn(kind, b, policy.batch,
+                                    self._signature(kind))
                 self.compiled[(kind, b)] = compiled
                 for s in range(n_streams):
                     ex = Executor(b, compiled, eid=len(self._threads))
@@ -647,6 +711,16 @@ class CoalescingOrchestrator:
         self.build_time_s = time.perf_counter() - t0
         for th in self._threads:
             th.start()
+
+    def _signature(self, kind: str) -> Callable:
+        """The per-row wrapper ``build_fn`` applies for ``kind``: the
+        kind's declared KV count and whether its rows come back split."""
+        n_kv = self._kv_rows.get(kind, 0)
+        split = kind in self._device_output
+
+        def signature(fn, shapes, layout=None):
+            return per_row_signature(fn, shapes, n_kv, split, layout)
+        return signature
 
     # ---- submission ----
     def submit(self, request, m: int, kind: Optional[str] = None,
@@ -724,10 +798,10 @@ class CoalescingOrchestrator:
         (``CoalescePolicy.tier_windows``) and capped by the degradation
         override (``set_window_override``)."""
         pol = self.policy
-        n_lead = self._packed.get(kind)
+        n_lead = self._kv_rows.get(kind, 0)
         packer = SegmentPacker(bucket, pol.rows, pol.batch,
                                align=pol.pack_align) \
-            if n_lead is not None else None
+            if kind in self._packed else None
 
         def take() -> bool:
             """Place the earliest-deadline pending chunk that FITS this
@@ -829,15 +903,21 @@ class CoalescingOrchestrator:
                 self._dispatch(kind, bucket, ex, batch)
 
     @staticmethod
-    def _stack_rows(rows: List, batch: int):
-        """Stack per-chunk rows (leading axis 1) along the batch axis, padded
-        with zero rows to the compiled batch size.  Device arrays stack via
-        jnp (no host round-trip); host numpy keeps the v1 single-transfer
-        path."""
-        xp = jnp if isinstance(rows[0], jax.Array) else np
+    def _stack_rows(rows: List, batch: int) -> np.ndarray:
+        """Stack per-chunk host rows (leading axis 1) along the batch axis,
+        padded with zero rows to the compiled batch size: one array, so
+        one transfer per argument."""
         if len(rows) < batch:
-            rows = list(rows) + [xp.zeros_like(rows[0])] * (batch - len(rows))
-        return xp.concatenate(rows, axis=0)
+            rows = list(rows) + [np.zeros_like(rows[0])] * (batch - len(rows))
+        return np.concatenate(rows, axis=0)
+
+    @staticmethod
+    def _slot_args(slots: List[tuple], batch: int) -> list:
+        """Per-slot KV args, slot-major, for a :func:`per_row_signature`
+        executor: ``batch`` slots, the unused ones filled with slot 0's
+        arrays (the same objects — nothing is allocated or copied)."""
+        slots = list(slots) + [slots[0]] * (batch - len(slots))
+        return [a for slot in slots for a in slot]
 
     def _note_dispatch(self, kind: str, bucket: int, n_chunks: int,
                        rows_used: int, valid: int, saved: int,
@@ -850,6 +930,8 @@ class CoalescingOrchestrator:
         with self._stat_lock:
             self.dispatch_count += 1
             self.kind_dispatches[kind] += 1
+            if kind in self._kv_rows:
+                self.ingraph_dispatches += 1
             self.kind_run_s[kind] += cost_s
             for k, v in stages.items():
                 self.stage_s[k] += v
@@ -878,13 +960,22 @@ class CoalescingOrchestrator:
         returns (out, launch_s, wait_s).  Serialized under the dispatch
         lock when the executables are multi-device (see
         ``serialize_dispatch``)."""
-        lock = self._dispatch_lock or contextlib.nullcontext()
-        with lock:
+        with self.serialized():
             with stage("dso.launch", **meta) as launch:
                 out = ex(*stacked)
             t0 = time.perf_counter()
             jax.block_until_ready(out)
             return out, launch.s, time.perf_counter() - t0
+
+    def serialized(self):
+        """Context under which device work runs in one process-wide order
+        with the executor launches: the dispatch lock when the executables
+        are multi-device (``serialize_dispatch``), else a no-op.  Callers
+        that issue their own eager multi-device ops on executor outputs
+        (the pool quantizing and publishing a fresh entry) hold it too —
+        an eager op racing a launch deadlocks a forced-host CPU mesh just
+        as two launches do."""
+        return self._dispatch_lock or contextlib.nullcontext()
 
     def _run_attempts(self, kind: str, bucket: int, ex: Executor, stacked,
                       meta: dict) -> Tuple[object, float, float]:
@@ -918,48 +1009,50 @@ class CoalescingOrchestrator:
         try:
             B = self.policy.batch
             with stage("dso.stack", **meta) as st_stack:
-                stacked = []
-                n_lead = self._dedup.get(kind, 0)
+                n_kv = self._kv_rows.get(kind, 0)
                 n_uniq = n
-                if n_lead:
+                if kind in self._dedup:
                     # identity-dedup the leading args: chunks carrying the
                     # SAME arg objects (one request split across chunks, or
-                    # requests sharing a pool entry) stack each unique row
-                    # once; the executor gathers per-row views through the
-                    # idx argument
+                    # requests sharing a pool entry) take one KV slot; the
+                    # executor gathers per-row views through the idx
+                    # argument
                     slot_of: Dict[tuple, int] = {}
                     uniq: List[tuple] = []
                     idx = np.zeros(B, np.int32)
                     for i, c in enumerate(batch):
-                        ident = self._ident(c, n_lead)
+                        ident = self._ident(c, n_kv)
                         slot = slot_of.get(ident)
                         if slot is None:
                             slot = len(uniq)
                             slot_of[ident] = slot
-                            uniq.append(c.args[:n_lead])
+                            uniq.append(c.args[:n_kv])
                         idx[i] = slot
                     n_uniq = len(uniq)
-                    for j in range(n_lead):
-                        stacked.append(
-                            self._stack_rows([u[j] for u in uniq], B))
-                    stacked.append(idx)
-                    rests = [c.args[n_lead:] for c in batch]
+                    stacked = self._slot_args(uniq, B) + [idx]
+                elif n_kv:
+                    stacked = self._slot_args([c.args[:n_kv] for c in batch],
+                                              B)
                 else:
-                    rests = [c.args for c in batch]
+                    stacked = []
+                rests = [c.args[n_kv:] for c in batch]
                 for j in range(len(rests[0])):
                     stacked.append(self._stack_rows([r[j] for r in rests], B))
             out, launch_s, wait_s = self._run_attempts(kind, bucket, ex,
                                                        stacked, meta)
             readback_s = 0.0
             if kind in self._device_output:
-                host = out        # stays device-resident (pool entries)
+                # the executor returned its rows split (per_row_signature):
+                # device-resident pool entries, handed out by index
+                with stage("dso.scatter", **meta) as st_scatter:
+                    parts = list(out[:n])
             else:
                 with stage("dso.readback", **meta) as st_read:
                     host = jax.tree.map(np.asarray, out)  # pytree outputs OK
                 readback_s = st_read.s
-            with stage("dso.scatter", **meta) as st_scatter:
-                parts = [jax.tree.map(lambda a: a[i:i + 1], host)
-                         for i in range(n)]
+                with stage("dso.scatter", **meta) as st_scatter:
+                    parts = [jax.tree.map(lambda a: a[i:i + 1], host)
+                             for i in range(n)]
             self._note_dispatch(kind, bucket, n, rows_used=n,
                                 valid=sum(c.valid for c in batch),
                                 saved=n - n_uniq, packed=False,
@@ -981,24 +1074,24 @@ class CoalescingOrchestrator:
     def _dispatch_packed(self, kind: str, bucket: int, ex: Executor,
                          batch: List[_PendingChunk], packer: SegmentPacker
                          ):  # flamecheck: host-sync-ok(dispatch boundary: seg-index planes are built host-side and results fan back out to futures)
-        """One packed dispatch: stack each unique KV identity once, build
-        the ``[B, bucket]`` seg-index and candidate planes from the packer's
-        placements, run the executor, and scatter each segment's exact
-        ``[1, valid, ...]`` output slice back to its chunk future."""
+        """One packed dispatch: hand each unique KV identity over once, in
+        its slot, build the ``[rows, bucket]`` seg-index and candidate
+        planes from the packer's placements, run the executor, and scatter
+        each segment's exact ``[1, valid, ...]`` output slice back to its
+        chunk future."""
         n = len(batch)
         meta = {"kind": kind, "bucket": bucket, "rows": packer.n_rows}
         try:
             B = self.policy.batch
-            n_lead = self._packed[kind]
+            n_lead = self._kv_rows[kind]
             with stage("dso.stack", **meta) as st_stack:
-                # stack each unique KV identity once, in slot order
+                # each unique KV identity's args once, in slot order
                 uniq_args: List[Optional[tuple]] = [None] * packer.n_slots
                 for c in batch:
                     slot = packer.slot_of[self._ident(c, n_lead)]
                     if uniq_args[slot] is None:
                         uniq_args[slot] = c.args[:n_lead]
-                stacked = [self._stack_rows([u[j] for u in uniq_args], B)
-                           for j in range(n_lead)]
+                stacked = self._slot_args(uniq_args, B)
                 rows = self.policy.rows
                 seg_idx = np.zeros((rows, bucket), np.int32)
                 cands = np.full((rows, bucket), -1, np.int32)
@@ -1048,6 +1141,7 @@ class CoalescingOrchestrator:
                 "dedup_rows_saved": self.dedup_rows_saved,
                 "packed_rows": self.packed_rows,
                 "packed_segments": self.packed_segments,
+                "ingraph_dispatches": self.ingraph_dispatches,
                 "cand_slots": slots,
                 "cand_valid": valid,
                 "padded_fraction": 1.0 - valid / slots if slots else 0.0,

@@ -41,10 +41,16 @@ Executors are AOT-compiled per ``(kind, bucket)``:
 
 ``_pad_slice(request, chunk, kind)`` produces one chunk's host/device args
 (leading axis 1); ``_gather(rows, chunks, m, kind)`` reassembles per-request
-outputs.  Pool fingerprint/staleness semantics live in
-``serving/kv_cache.py``; the history window is fingerprinted over the FULL
-upstream array (side features average all of it), and stale entries become
-extension bases instead of pure losses when ``incremental_history`` is on.
+outputs.  Families that carry history-KV rows (cached, extend, decode,
+append) declare their leading KV arg count to the DSO and are compiled for
+its per-row signature (``core/dso.py::per_row_signature``): one ``[1, ...]``
+array per KV slot, concatenated in graph; encode/extend/append return their
+output rows already split, so a dispatch is one launch.
+
+Pool fingerprint/staleness semantics live in ``serving/kv_cache.py``; the
+history window is fingerprinted over the FULL upstream array (side features
+average all of it), and stale entries become extension bases instead of pure
+losses when ``incremental_history`` is on.
 """
 from __future__ import annotations
 
@@ -974,7 +980,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
         decode_row_shapes = lambda batch: _batched(  # noqa: E731
             getattr(self, "_decode_row_specs", ()), batch)
 
-        def build_fn(kind: str, bucket: int, batch: int):
+        def build_fn(kind: str, bucket: int, batch: int, signature):
             # every executor takes the model params as its FIRST argument
             # (bound at build time, see _ParamsBound): params closed over
             # by the traced function would be embedded in each program as
@@ -1128,6 +1134,13 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                     jax.ShapeDtypeStruct((batch, 1), jnp.int32))
             else:
                 raise ValueError(kind)
+            # the DSO's per-row contract for this kind: KV rows arrive one
+            # [1, ...] array per slot and concatenate in graph; device-
+            # output kinds return their rows split, from the pool's layout
+            # under a mesh (the publish all-gather)
+            fn, shapes = signature(
+                fn, shapes,
+                layout=None if self.mesh is None else self._arg_sharding)
             # a stable name per executor: its HLO module (and its host
             # events in a trace) read jit_flame_<kind>_b<bucket>
             fn.__name__ = fn.__qualname__ = f"flame_{kind}_b{bucket}"
@@ -1167,34 +1180,35 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
         # the bucket key gains a hit/miss dimension: candidate-only
         # ("cached") executors serve pool traffic, "encode" repopulates the
         # pool on miss, "extend" refreshes a stale entry from its cached
-        # prefix, "full" is the monolithic path when the pool is off
-        dedup_kinds = None
-        packed_kinds = None
+        # prefix, "full" is the monolithic path when the pool is off.
+        # Every family that carries history-KV rows declares how many
+        # leading args they are and how they share KV slots (DSO kv_kinds);
+        # the DSO hands them over per slot and binds build_fn's signature
+        kv_kinds: Dict[str, Tuple[int, str]] = {}
         device_output_kinds: tuple = ()
         if history_cache:
+            n_kv = len(self._cached_row_specs)
             families = {"cached": tuple(buckets), "encode": (n_history,)}
             if self._extend_buckets:
                 families["extend"] = self._extend_buckets
-            if self._pack_tails:
-                # packing subsumes KV-row dedup: same-user segments share
-                # one stacked KV slot inside the packer
-                packed_kinds = {"cached": len(self._cached_row_specs)}
-            elif kv_dedup:
-                dedup_kinds = {"cached": len(self._cached_row_specs)}
+                kv_kinds["extend"] = (n_kv, "row")
+            # packing subsumes KV-row dedup: same-user segments share one
+            # KV slot inside the packer
+            kv_kinds["cached"] = (n_kv, "packed" if self._pack_tails else
+                                  "dedup" if kv_dedup else "row")
             if self._generate:
                 families["decode"] = tuple(buckets)
                 families["append"] = (1,)
-                if self._pack_tails:
-                    # the beam's valid length packs alongside its KV leaves
-                    # (one lead-arg tuple per unique beam -> one stacked
-                    # slot), so a packed row mixes beams at different
-                    # lengths without padding any of them
-                    packed_kinds["decode"] = len(self._cached_row_specs) + 1
-            if pool_placement == "device" and jax.default_backend() != "cpu":
-                # encode/extend outputs feed the pool: keep them on device.
-                # On the CPU backend host and device memory coincide, so the
-                # numpy scatter path is the same placement without the
-                # per-row device-slice dispatch overhead.
+                kv_kinds["append"] = (n_kv, "row")
+                # packed: the beam's valid length packs alongside its KV
+                # leaves (one lead-arg tuple per unique beam -> one slot),
+                # so a packed row mixes beams at different lengths without
+                # padding any of them
+                kv_kinds["decode"] = (n_kv + 1, "packed") \
+                    if self._pack_tails else (n_kv, "row")
+            if pool_placement == "device":
+                # encode/extend outputs feed the pool: keep them on device,
+                # split per row inside the executor
                 device_output_kinds = ("encode", "extend")
                 if self._generate:
                     device_output_kinds += ("append",)
@@ -1209,8 +1223,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
         self.dso = DSO.CoalescingOrchestrator(
             build_fn, pad_slice_fn=self._pad_slice, gather_fn=self._gather,
             policy=policy, n_streams=n_streams, families=families,
-            dedup_kinds=dedup_kinds, packed_kinds=packed_kinds,
-            device_output_kinds=device_output_kinds,
+            kv_kinds=kv_kinds, device_output_kinds=device_output_kinds,
             # multi-device executables must not overlap their collectives
             # (XLA rendezvous has no cross-computation ordering — see
             # CoalescingOrchestrator); a 1x1 mesh stays fully concurrent
@@ -1274,7 +1287,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 return      # pool hit ahead: side features never consumed
         super()._admit_hook(request)
 
-    # ---- chunk plumbing (host-side; the dispatcher stacks + transfers) ----
+    # ---- chunk plumbing (host-side; the dispatcher assembles the args) ----
     @staticmethod
     def _slice_candidates(candidates, chunk: DSO.Chunk):
         sl = candidates[:, chunk.start:chunk.start + chunk.valid]
@@ -1425,9 +1438,9 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 kv_tree = self.dso.score((hist, side), self.n_history,
                                          kind="encode", deadline=deadline,
                                          tier=req.slo_tier)
-            # device-resident rows arrive as fresh device buffers (XLA
-            # slices of the stacked dispatch output); host rows are numpy
-            # VIEWS into the (max_batch, ...) stacked parent — copy those so
+            # device-placed pools get the executor's own per-row output
+            # buffers; host rows (pool_placement="host") are numpy VIEWS
+            # into the (max_batch, ...) stacked parent — copy those so
             # pooling them doesn't pin the padded parent or make pool_bytes
             # under-report
             kv = tuple(np.array(a) if isinstance(a, np.ndarray) else a
@@ -1440,15 +1453,15 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 # pass) and score from the very same leaves — hit, wait,
                 # encode and extend paths all share one representation
                 # without the raw read-back the un-fused flow needs
-                self.history_pool.put(
+                self._pool_put(
                     key, fp, jax.tree.unflatten(self._cached_treedef,
                                                 list(kv)),
                     hist_window=hist[0], refreshes=refreshes,
                     prequantized=True,
                     compute_dtype=self._kv_compute_dtype)
             else:
-                self.history_pool.put(key, fp, kv, hist_window=hist[0],
-                                      refreshes=refreshes)
+                self._pool_put(key, fp, kv, hist_window=hist[0],
+                               refreshes=refreshes)
             self._metrics.set_gauge("pool_bytes_used",
                                     self.history_pool.bytes_used)
             for i, b in enumerate(self.history_pool.shard_bytes()):
@@ -1589,6 +1602,13 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
             leaves = self._copy_kv_rows(kv_tree)
         return leaves
 
+    def _pool_put(self, *args, **kwargs) -> bool:
+        """``history_pool.put`` of executor outputs, in the DSO's launch
+        order: a put's quantize and layout publish are eager multi-device
+        ops under a mesh, and must not race a dispatch's collectives."""
+        with self.dso.serialized():
+            return self.history_pool.put(*args, **kwargs)
+
     def _park_beam(self, req, slot: int, beam: _Beam, leaves: tuple,
                    hist_fp) -> None:
         """Hand a beam's cache to the pool (key = (\"g\", request id, beam
@@ -1602,12 +1622,12 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
             # the appended cache is already the stored representation
             # (climber's append epilogue quantizes the new token against
             # the root scales in-graph) — park it without re-quantizing
-            accepted = self.history_pool.put(
+            accepted = self._pool_put(
                 key, fp, jax.tree.unflatten(self._cached_treedef,
                                             list(leaves)),
                 prequantized=True, compute_dtype=self._kv_compute_dtype)
         else:
-            accepted = self.history_pool.put(key, fp, leaves)
+            accepted = self._pool_put(key, fp, leaves)
         if accepted:
             beam.pool_key, beam.pool_fp, beam.leaves = key, fp, None
         else:

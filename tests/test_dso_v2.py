@@ -17,7 +17,11 @@ Layers of coverage:
      the same engine's sequential scores (the coalescing contract; one
      executable, placement-invariant), packed-vs-unpacked engines agree
      at the cross-AOT-executable tolerance with ``padded_fraction``
-     reduced, and the quantized extend basis ships raw (no host dequant).
+     reduced, and the quantized extend basis ships raw (no host dequant);
+  5. per-row dispatch — KV rows are handed to the executor one array per
+     slot and concatenated in graph, device-output families return their
+     rows split, so one dispatch is one launch with no eager device op,
+     and the pool's stored arrays reach the launch themselves.
 """
 import dataclasses
 import threading
@@ -30,14 +34,16 @@ import pytest
 from tests._propcheck import given, settings, st
 
 from repro.configs import get_config
+from repro.core import dso as dso_mod
 from repro.core.dso import (CoalescePolicy, CoalescingOrchestrator,
-                            SegmentPacker, _PendingChunk)
+                            SegmentPacker, _PendingChunk, per_row_signature)
 from repro.core.pda import RemoteFeatureStore
 from repro.models import build_model
 from repro.serving import (DeadlineExceeded, FlameEngine, ServeMetrics,
                            ServeRequest)
-from repro.serving.kv_cache import (HistoryKVPool, dequantize_kv,
-                                    quantize_kv, raw_kv_view)
+from repro.serving import engine as engine_mod
+from repro.serving.kv_cache import (HistoryKVPool, _stored_arrays,
+                                    dequantize_kv, quantize_kv, raw_kv_view)
 from repro.serving.scheduler import (TrafficConfig, generate_traffic,
                                      run_workload_async)
 from repro.types import ClimberConfig
@@ -434,3 +440,284 @@ def test_fused_incremental_engine_extends_from_raw_basis(climber_setup):
     assert m["pool_extensions"] > 0
     assert all(np.isfinite(o).all() for o in outs)
     eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# 6. per-row dispatch: in-graph stacking and splitting
+# ---------------------------------------------------------------------------
+
+def test_per_row_signature_stacks_and_splits_in_graph():
+    """The executor half of the contract: B slot groups of [1, ...] KV rows
+    concatenate in graph to the stacked operand, the host rest passes
+    through, and a split executor returns B per-row outputs."""
+    def fn(params, k, v, x):
+        return {"y": k * params + v, "z": x}
+    B = 3
+    shapes = (jax.ShapeDtypeStruct((B, 2, 4), jnp.float32),
+              jax.ShapeDtypeStruct((B, 2, 4), jnp.float32),
+              jax.ShapeDtypeStruct((B, 5), jnp.int32))
+    same, same_shapes = per_row_signature(fn, shapes, 0, split=False)
+    assert same is fn and same_shapes == shapes
+    wrapped, row_shapes = per_row_signature(fn, shapes, 2, split=True)
+    assert [s.shape for s in row_shapes] == [(1, 2, 4)] * 2 * B + [(B, 5)]
+    rng = np.random.default_rng(0)
+    ks = [rng.standard_normal((1, 2, 4)).astype(np.float32)
+          for _ in range(B)]
+    vs = [rng.standard_normal((1, 2, 4)).astype(np.float32)
+          for _ in range(B)]
+    x = rng.integers(0, 9, (B, 5)).astype(np.int32)
+    ex = jax.jit(wrapped).lower(2.0, *row_shapes).compile()
+    out = ex(2.0, *[a for kv in zip(ks, vs) for a in kv], x)
+    ref = jax.jit(fn)(2.0, np.concatenate(ks), np.concatenate(vs), x)
+    assert isinstance(out, tuple) and len(out) == B
+    for i, row in enumerate(out):
+        assert row["y"].shape == (1, 2, 4) and row["z"].shape == (1, 5)
+        np.testing.assert_array_equal(np.asarray(row["y"]),
+                                      np.asarray(ref["y"])[i:i + 1])
+        np.testing.assert_array_equal(np.asarray(row["z"]), x[i:i + 1])
+
+
+def test_orchestrator_hands_kv_rows_per_slot():
+    """A ``mode="row"`` family: ``build_fn`` gets the orchestrator's own
+    per-row signature, co-riders' KV rows reach the executor per slot,
+    unused slots repeat slot 0's objects, the split output is handed out by
+    index, and every dispatch counts as in-graph."""
+    B, seen = 4, []
+
+    def build(kind, bucket, batch, signature):
+        def fn(params, kv, x):
+            return kv.sum(axis=(1, 2))[:, None] + x
+        wrapped, shapes = signature(
+            fn, (jax.ShapeDtypeStruct((batch, 3, 2), jnp.float32),
+                 jax.ShapeDtypeStruct((batch, bucket), jnp.float32)))
+        ex = jax.jit(wrapped).lower(0.0, *shapes).compile()
+
+        def run(*args):
+            seen.append(args)
+            return ex(0.0, *args)
+        return run
+
+    dso = CoalescingOrchestrator(
+        build, pad_slice_fn=lambda req, c, kind: req,
+        gather_fn=lambda rows, cs, m, kind: rows[0],
+        policy=CoalescePolicy(enabled=True, max_batch=B, window_s=0.05),
+        n_streams=1, families={"k": [4]}, kv_kinds={"k": (1, "row")},
+        device_output_kinds=("k",))
+    rng = np.random.default_rng(1)
+    reqs = [(rng.standard_normal((1, 3, 2)).astype(np.float32),
+             rng.standard_normal((1, 4)).astype(np.float32))
+            for _ in range(2)]
+    with dso._cond[("k", 4)]:     # both chunks ride one dispatch
+        futs = [dso.submit(r, 4, kind="k") for r in reqs]
+    outs = [f.result() for f in futs]
+    st = dso.stats()
+    dso.shutdown()
+    assert st["dispatches"] == st["ingraph_dispatches"] == len(seen) == 1
+    args = seen[0]
+    assert len(args) == B + 1
+    assert args[0] is reqs[0][0] and args[1] is reqs[1][0]
+    assert args[2] is reqs[0][0] and args[3] is reqs[0][0]
+    for (kv, x), out in zip(reqs, outs):
+        assert out.shape == (1, 4)
+        np.testing.assert_allclose(np.asarray(out),
+                                   kv.sum(axis=(1, 2))[:, None] + x,
+                                   rtol=1e-6)
+
+
+def _session_steps(seed=7, n_users=4, steps=3):
+    """Fixed mixed session: one request per user a step; step 0 misses
+    (encode), step 1 hits, step 2 grows even users' histories (extend)
+    while odd users hit again.  Slates span one and two chunks."""
+    rng = np.random.default_rng(seed)
+    hists = {u: rng.integers(0, 10_000, 80).astype(np.int32)
+             for u in range(n_users)}
+    out = []
+    for step in range(steps):
+        wave = []
+        for u in range(n_users):
+            if step == 2 and u % 2 == 0:
+                hists[u] = np.concatenate(
+                    [hists[u], rng.integers(0, 10_000, 3).astype(np.int32)])
+            m = int(rng.integers(1, 48))
+            wave.append((hists[u].copy(),
+                         rng.integers(0, 10_000, m).astype(np.int32), u))
+        out.append(wave)
+    return out
+
+
+def _fused_int8(bundle, params):
+    return _flame(bundle, params, pack_tails=True, impl="fused",
+                  pool_dtype="int8", incremental_history=True)
+
+
+class _NoJnp:
+    """Stand-in for the DSO module's ``jnp``: any use fails (and is
+    recorded, in case a dispatch thread swallows the error)."""
+
+    def __init__(self):
+        self.touched = []
+
+    def __getattr__(self, name):
+        self.touched.append(name)
+        raise AssertionError(f"eager jnp.{name} on the DSO dispatch path")
+
+
+@pytest.fixture(scope="module")
+def ingraph_session(climber_setup):
+    """One fused int8 packed engine serving the fixed session one request
+    at a time, with every executor call recorded (kind, args, output) and
+    the DSO's ``jnp`` replaced by a sentinel while it serves."""
+    cfg, bundle, params = climber_setup
+    eng = _fused_int8(bundle, params)
+    kind_of = {id(ex): kind for (kind, _), ex in eng.dso.compiled.items()}
+    calls = []
+    real = engine_mod._ParamsBound.__call__
+
+    def record(self, *args):
+        out = real(self, *args)
+        calls.append((kind_of[id(self)], args, out))
+        return out
+
+    no_jnp = _NoJnp()
+    m0 = eng.metrics()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod._ParamsBound, "__call__", record)
+        mp.setattr(dso_mod, "jnp", no_jnp)
+        outs = [np.asarray(eng.serve(h, c, user_id=u))
+                for wave in _session_steps() for h, c, u in wave]
+    m1 = eng.metrics()
+    yield {"eng": eng, "calls": calls, "no_jnp": no_jnp, "outs": outs,
+           "delta": {k: v - m0.get(k, 0) for k, v in m1.items()
+                     if isinstance(v, (int, float))}}
+    eng.shutdown()
+
+
+def test_ingraph_mixed_session_concurrent_bitwise_matches_solo(
+        climber_setup, ingraph_session):
+    """Fused impl, int8 pool, packed tails: a session of hits, misses and
+    extends served a wave at a time concurrently (co-riders share
+    dispatches) scores bitwise equal to the same session served one request
+    at a time."""
+    cfg, bundle, params = climber_setup
+    eng = _fused_int8(bundle, params)
+    concurrent = []
+    for wave in _session_steps():
+        futs = [eng.submit(ServeRequest(history=h, candidates=c, user_id=u))
+                for h, c, u in wave]
+        concurrent += [np.asarray(f.result(timeout=120).output)
+                       for f in futs]
+    m = eng.metrics()
+    eng.shutdown()
+    assert m["pool_hits"] > 0 and m["pool_misses"] > 0
+    assert m["pool_extensions"] > 0
+    solo = ingraph_session["outs"]
+    assert len(solo) == len(concurrent)
+    for s, c in zip(solo, concurrent):
+        np.testing.assert_array_equal(s, c)
+
+
+def test_dispatch_is_one_launch_without_eager_jnp(ingraph_session):
+    """Every dispatch of the session makes exactly one executor call, and
+    the dispatch path never touches the DSO module's ``jnp``."""
+    calls, d = ingraph_session["calls"], ingraph_session["delta"]
+    assert d["dso_dispatches"] > 0
+    assert len(calls) == d["dso_dispatches"]
+    assert {k for k, _, _ in calls} == {"encode", "extend", "cached"}
+    assert ingraph_session["no_jnp"].touched == []
+    assert all(np.isfinite(o).all() for o in ingraph_session["outs"])
+
+
+def test_cached_dispatch_receives_pool_arrays(ingraph_session):
+    """A pool hit's ``cached`` dispatch gets the pool's stored arrays
+    themselves as its KV arguments, and its padding slots repeat them."""
+    eng = ingraph_session["eng"]
+    n_kv = eng.dso._kv_rows["cached"]
+    B = eng.dso.policy.batch
+    cached = [args for k, args, _ in ingraph_session["calls"]
+              if k == "cached"]
+    for u in (1, 3):       # odd users: entries untouched since step 0
+        stored = _stored_arrays(
+            eng.history_pool._entries[("u", u)].payload)
+        assert len(stored) == n_kv
+        hits = [args for args in cached
+                if all(a is b for a, b in zip(args[:n_kv], stored))]
+        assert hits, f"no cached dispatch got user {u}'s stored arrays"
+        for args in hits:
+            assert all(args[j] is args[j % n_kv] for j in range(B * n_kv))
+
+
+def test_encode_extend_rows_reach_pool_split(ingraph_session):
+    """``encode`` / ``extend`` executors return B per-row outputs; the pool
+    holds separate [1, ...] arrays (no view into a stacked parent), and
+    ``pool_bytes_used`` is the entries' stored bytes exactly."""
+    eng = ingraph_session["eng"]
+    B = eng.dso.policy.batch
+    for kind, _, out in ingraph_session["calls"]:
+        if kind in ("encode", "extend"):
+            assert isinstance(out, tuple) and len(out) == B
+            assert all(a.shape[0] == 1 for row in out
+                       for a in jax.tree.leaves(row))
+    entries = list(eng.history_pool._entries.values())
+    assert len(entries) == 4
+    for e in entries:
+        for a in _stored_arrays(e.payload):
+            assert a.shape[0] == 1
+            base = getattr(a, "base", None)
+            while isinstance(base, np.ndarray):
+                assert base.nbytes == a.nbytes, "a view into a stacked row"
+                base = base.base
+    per_entry = sum(int(np.prod(s.shape)) * jnp.dtype(s.dtype).itemsize
+                    for s in eng._cached_row_specs)
+    assert eng.metrics()["pool_bytes_used"] == len(entries) * per_entry
+
+
+def test_ingraph_dispatch_counter_counts_non_encode(ingraph_session):
+    """``dso_ingraph_dispatches`` counts exactly the dispatches whose KV
+    rows went per slot: every dispatch but ``encode``."""
+    d = ingraph_session["delta"]
+    assert d["dso_dispatches_encode"] > 0
+    assert d["dso_ingraph_dispatches"] > 0
+    assert d["dso_ingraph_dispatches"] == \
+        d["dso_dispatches"] - d["dso_dispatches_encode"]
+
+
+def test_kv_kinds_reject_unknown_slot_mode():
+    """``kv_kinds`` modes are one of row / dedup / packed; anything else is
+    refused when the orchestrator is built, before any executor compiles."""
+    built = []
+    with pytest.raises(ValueError, match="KV slot mode"):
+        CoalescingOrchestrator(
+            lambda kind, b, batch, sig: built.append(kind),
+            pad_slice_fn=lambda req, c, kind: req,
+            gather_fn=lambda rows, cs, m, kind: rows[0],
+            families={"k": [4]}, kv_kinds={"k": (1, "shared")})
+    assert built == []
+
+
+def test_pool_put_runs_in_dispatch_order(climber_setup):
+    """A fresh entry's pool put (the int8 quantize and layout publish of a
+    device-resident encode output) runs under the DSO's dispatch lock, the
+    lock multi-device launches hold: an eager multi-device op racing a
+    dispatch deadlocks a host mesh."""
+    cfg, bundle, params = climber_setup
+    eng = _flame(bundle, params, impl="chunked", pool_dtype="int8")
+    eng.dso._dispatch_lock = lock = threading.Lock()  # as a mesh sets it
+    held = []
+    real_put = eng.history_pool.put
+
+    def put(*args, **kwargs):
+        held.append(lock.locked())
+        return real_put(*args, **kwargs)
+
+    eng.history_pool.put = put
+    rng = np.random.default_rng(5)
+    try:
+        for u in range(2):
+            out = eng.serve(rng.integers(0, 10_000, 64).astype(np.int32),
+                            rng.integers(0, 10_000, 9).astype(np.int32),
+                            user_id=u)
+            assert np.isfinite(np.asarray(out)).all()
+    finally:
+        eng.shutdown()
+    assert held == [True, True]
+    assert not lock.locked()

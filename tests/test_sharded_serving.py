@@ -21,7 +21,12 @@ Layers of coverage:
      chunked impls over an int8 pool; a (2,2) tensor-parallel mesh agrees
      to f32-reassociation tolerance, halves the per-shard pool bytes, and
      no executor's compiled HLO contains a cross-shard reshard collective
-     (all-to-all / collective-permute) on the steady-state hot path.
+     (all-to-all / collective-permute) on the steady-state hot path;
+  5. subprocess (4 forced host devices) — the per-row executor signature
+     under a mesh, served concurrently: encode/extend executors return
+     their rows split in the pool's head-sharded layout, misses, hits and
+     extends racing pool publishes against dispatches finish (no
+     deadlock), and scores match the single-device engine.
 """
 import os
 import subprocess
@@ -246,6 +251,86 @@ def test_sharded_serving_multi_device_subprocess():
     res = subprocess.run([sys.executable, "-c", SUBPROCESS_SCRIPT],
                          cwd=os.path.join(os.path.dirname(__file__), ".."),
                          env=env, capture_output=True, text=True, timeout=900)
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert "OK" in res.stdout
+
+
+CONCURRENT_MESH_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+sys.path.insert(0, "src")
+import dataclasses, numpy as np, jax
+from repro.configs import get_config
+from repro.models import build_model
+from repro.types import ClimberConfig
+from repro.launch.mesh import make_serving_mesh
+from repro.serving import create_engine
+from repro.serving.scheduler import run_workload_async
+
+cfg = dataclasses.replace(get_config("climber"), vocab_size=5000, d_model=64,
+                          d_ff=256, n_heads=4, n_kv_heads=4, head_dim=16,
+                          climber=ClimberConfig(num_blocks=2,
+                                                layers_per_block=2))
+bundle = build_model(cfg)
+params, _ = bundle.init(jax.random.key(0))
+rr = np.random.default_rng(3)
+hists = [rr.integers(0, 5000, 72).astype(np.int32) for _ in range(8)]
+grown = [np.append(h, rr.integers(0, 5000, 2).astype(np.int32))
+         for h in hists]
+waves = [[{"history": h, "user_id": u,
+           "candidates": rr.integers(0, 5000, int(rr.integers(5, 30)))
+           .astype(np.int32)} for u, h in enumerate(hs)]
+         for hs in (hists, hists, grown)]   # misses, hits, extends
+
+
+def serve(mesh):
+    eng = create_engine("flame", bundle, params, n_history=64,
+                        buckets=(16,), history_cache=True, pool_slots=32,
+                        pool_dtype="int8", impl="chunked",
+                        incremental_history=True, mesh=mesh)
+    outs = []
+    for wave in waves:
+        res = run_workload_async(eng, wave, result_timeout_s=240)
+        assert res["resolved"] == len(wave), res
+        outs += [np.asarray(o, np.float32).ravel() for o in res["outputs"]]
+    return eng, np.concatenate(outs)
+
+
+base_eng, base = serve(None)
+base_eng.shutdown()
+eng, out = serve(make_serving_mesh("2,2"))
+m = eng.metrics()
+assert m["pool_hits"] > 0 and m["pool_misses"] > 0, m
+assert m["pool_extensions"] > 0, m
+assert m["dso_ingraph_dispatches"] == \
+    m["dso_dispatches"] - m["dso_dispatches_encode"], m
+B = eng.dso.policy.batch
+for kind in ("encode", "extend"):
+    exes = [ex for (k, _), ex in eng.dso.compiled.items() if k == kind]
+    assert exes, kind
+    for ex in exes:
+        rows, shardings = ex.out_info, ex.output_shardings
+        assert isinstance(rows, tuple) and len(rows) == B, (kind, rows)
+        for info, sh in zip(jax.tree.leaves(rows),
+                            jax.tree.leaves(shardings)):
+            assert info.shape[0] == 1, (kind, info.shape)
+            assert sh == eng._arg_sharding(info.shape), (kind, sh)
+        # 5-d KV leaves split over the model axis, as the pool stores them
+        assert any(len(sh.spec) > 3 and sh.spec[3] == "model"
+                   for sh in jax.tree.leaves(shardings)), kind
+eng.shutdown()
+assert np.allclose(base, out, atol=5e-3), float(np.abs(base - out).max())
+print("OK")
+"""
+
+
+def test_concurrent_mesh_serving_per_row_outputs_subprocess():
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run([sys.executable, "-c", CONCURRENT_MESH_SCRIPT],
+                         cwd=os.path.join(os.path.dirname(__file__), ".."),
+                         env=env, capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-3000:]
     assert "OK" in res.stdout
 
