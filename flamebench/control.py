@@ -1,14 +1,16 @@
-"""The control of a cell's correctness check: the plain reference put in
-the program's place, in the precision below the served one (float8 e4m3
-operands for every matrix product, against bfloat16 served).  It must come
-out not correct; the benchmark's own runs never run it.
+"""The control of a cell's correctness check: the family's plain reference
+put in the program's place, in the precision below the served one
+(``reference_scores(..., lowp=True)``: float8 e4m3 operands for every
+matrix product, against bfloat16 served).  It must come out not correct;
+the benchmark's own runs never run it.
 
     python3 flamebench/control.py --workload <cell> --seeds 1,2,3
 
 For each seed: the cell's weights and traffic from that seed, a sample of
 the window's requests as large as a run checks (the largest slate among
 them), and the check's number with the control's answers: the widest gap
-between float8 and float32 task probabilities.  One JSON line per seed.
+between the lower-precision and the float32 answers.  One JSON line per
+seed.
 """
 import argparse
 import json
@@ -43,15 +45,15 @@ def reading(cell: str, seed: int, *, root: str = ROOT, conf=None,
     from flamebench import harness, traffic as T, weights as W
 
     bench, c, centry = harness.load_cell(cell, root)
-    conf = conf or harness.load_json(root, centry["file"])
+    conf, fam = harness.config_and_family(centry, root, conf)
     mix = mix or T.load(c["traffic"], os.path.join(root, "flamebench"))
     model = conf["model"]
-    params = jax.block_until_ready(W.make_params(model, seed))
+    params = jax.block_until_ready(W.make_params(fam.layout(model), seed))
     tr = T.Traffic(mix, n_history=conf["n_history"],
                    vocab=model["vocab_size"], seed=seed,
                    seconds=float(bench["run_seconds"]))
     picked = window_sample(tr, mix, seed)
-    value = harness.score_gap(params, model, conf["n_history"], picked,
+    value = harness.score_gap(fam, params, model, conf["n_history"], picked,
                               int(conf["max_slate"]), lowp=True)
     return {"workload": cell, "seed": seed, "check": "score_gap",
             "value": value, "requests": len(picked)}

@@ -1,12 +1,14 @@
 """Run one cell of ``BENCHMARK.json`` once.
 
 Everything a cell needs is found by name: its configuration
-(``configs/<config>.json``), its traffic mix (``traffic/<traffic>.json``),
+(``configs/<config>.json``), the model family that configuration names
+(``families/<family>.py``), its traffic mix (``traffic/<traffic>.json``),
 its correctness limits (``limits/<cell>.json``) and one reader per metric
-(``metrics/<metric>.py``).  A run builds the weights from the seed, builds
-the engine through ``create_engine("flame", ...)``, sends the mix's warm
-traffic untimed, measures for ``seconds``, checks what the window served
-against the plain reference, and returns the result line.
+(``metrics/<metric>.py``).  A run builds the weights of the family's layout
+from the seed, builds the engine through ``create_engine("flame", ...)``,
+sends the mix's warm traffic untimed, measures for ``seconds``, checks what
+the window served against the family's plain reference, and returns the
+result line.
 """
 from __future__ import annotations
 
@@ -14,6 +16,7 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import sys
 import tempfile
 import threading
@@ -22,7 +25,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from flamebench import reference, traffic as T, weights as W, work
+from flamebench import families, traffic as T, weights as W, work
 from flamebench import trace as TR
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -84,21 +87,41 @@ def reader(metric: str, root: str = ROOT
     return mod.read
 
 
+def family(conf: dict, where: str, root: str = ROOT):
+    """The module ``families/<family>.py`` that the configuration ``conf``
+    (read from ``where``) names by its ``"family"`` key; a missing key, a
+    missing file or a module short of a role is an error naming them."""
+    name = conf.get("family")
+    if not isinstance(name, str) or not re.fullmatch(r"[A-Za-z0-9_]+", name):
+        raise ValueError(f"{where}: no model family: the configuration "
+                         f"needs a \"family\" key naming a file "
+                         f"flamebench/families/<family>.py, has "
+                         f"{name!r}")
+    path = os.path.join(root, "flamebench", "families", f"{name}.py")
+    if not os.path.isfile(path):
+        raise ValueError(f"{where}: family {name!r} has no module {path}")
+    spec = importlib.util.spec_from_file_location(
+        "flamebench_family_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [r for r in families.ROLES if not hasattr(mod, r)]
+    if missing:
+        raise ValueError(f"{where}: family module {path} lacks {missing}")
+    return mod
+
+
+def config_and_family(centry: dict, root: str = ROOT,
+                      conf: Optional[dict] = None):
+    """(configuration, family module) of a ``configs`` entry of
+    BENCHMARK.json; ``conf``, where given, stands in for its file."""
+    where = "the configuration passed in" if conf else centry["file"]
+    conf = conf or load_json(root, centry["file"])
+    return conf, family(conf, where, root)
+
+
 # ---------------------------------------------------------------------------
-# the model and the engine
+# the engine
 # ---------------------------------------------------------------------------
-
-def model_config(conf: dict):
-    """The program's ModelConfig for a configuration file's ``model``."""
-    import dataclasses
-
-    from repro.configs import climber
-    from repro.types import ClimberConfig
-
-    m = dict(conf["model"])
-    blocks = ClimberConfig(**m.pop("climber"))
-    return dataclasses.replace(climber.CONFIG, climber=blocks, **m)
-
 
 def build_engine(conf: dict, params, bundle):
     from repro.serving import create_engine
@@ -254,39 +277,29 @@ def sample(done: List[dict], n: int, seed: int, key) -> List[dict]:
     return [done[big]] + [done[i] for i in rest]
 
 
-def _ref_rows(params, model, rows, max_slate, *,
-             lowp=False) -> List[np.ndarray]:
-    """Reference probabilities for rows of (hist, side, cands), run in
-    blocks of REF_BLOCK rows padded to one shape (``max_slate``
-    candidates), so every run reuses one program."""
+def _ref_rows(fam, params, model, rows, max_slate, *,
+              lowp=False) -> List[np.ndarray]:
+    """The family's reference answers for its rows, run in blocks of
+    REF_BLOCK rows (the last filled up with its first row) at
+    ``max_slate`` candidates, so every run reuses one program."""
     out = []
     for i in range(0, len(rows), REF_BLOCK):
         blk = rows[i:i + REF_BLOCK]
         blk = blk + [blk[0]] * (REF_BLOCK - len(blk))
-        hist = np.stack([r[0] for r in blk])
-        side = np.stack([r[1] for r in blk])
-        gen = np.zeros((REF_BLOCK, 1), np.int32)
-        glen = np.zeros(REF_BLOCK, np.int32)
-        cands = np.zeros((REF_BLOCK, max_slate), np.int32)
-        for j, r in enumerate(blk):
-            cands[j, :len(r[2])] = r[2]
-        p = reference.scores(params, model, hist, side, gen, glen, cands,
-                             lowp=lowp)
-        out += [p[j, :len(r[2])] for j, r in enumerate(blk)]
+        out += fam.reference_scores(params, model, blk, max_slate,
+                                    lowp=lowp)
     return out[:len(rows)]
 
 
-def score_gap(params, model, n_history, served, max_slate, *,
+def score_gap(fam, params, model, n_history, served, max_slate, *,
               lowp=False) -> float:
-    """Widest |served - reference| task probability over the requests;
-    with ``lowp`` the lower-precision reference stands in for the served
-    answers (the control)."""
-    rows = [(s["req"].history[:n_history],
-             reference.side_features(s["req"].history),
-             s["req"].candidates) for s in served]
-    refs = _ref_rows(params, model, rows, max_slate)
+    """Widest |served - reference| answer over the requests; with ``lowp``
+    the lower-precision reference stands in for the served answers (the
+    control)."""
+    rows = [fam.reference_row(s["req"], n_history) for s in served]
+    refs = _ref_rows(fam, params, model, rows, max_slate)
     if lowp:
-        got = _ref_rows(params, model, rows, max_slate, lowp=True)
+        got = _ref_rows(fam, params, model, rows, max_slate, lowp=True)
     else:
         got = [np.asarray(s["out"], np.float32) for s in served]
     return max(float(np.abs(a - b).max()) for a, b in zip(got, refs))
@@ -308,7 +321,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     from repro.models import build_model
 
     bench, cell, centry = load_cell(cell_name, root)
-    conf = conf or load_json(root, centry["file"])
+    conf, fam = config_and_family(centry, root, conf)
     mix = mix or T.load(cell["traffic"], os.path.join(root, "flamebench"))
     limits = limits or load_json(root, "flamebench", "limits",
                                  f"{cell_name}.json")
@@ -318,10 +331,9 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     n_history = conf["n_history"]
     model = conf["model"]
 
-    cfg = model_config(conf)
-    bundle = build_model(cfg)
+    bundle = build_model(fam.program_config(conf))
     t = time.perf_counter()
-    params = jax.block_until_ready(W.make_params(model, seed))
+    params = jax.block_until_ready(W.make_params(fam.layout(model), seed))
     W.check_layout(params, jax.eval_shape(lambda k: bundle.init(k)[0],
                                           jax.random.key(0)))
     log(f"weights from seed {seed} in {time.perf_counter() - t:.2f}s")
@@ -338,15 +350,16 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         gc.collect()
 
         win = measure(eng, tr, mix, seconds, trace=trace,
-                      trace_seconds=trace_seconds, keep_trace=keep_trace)
+                      trace_seconds=trace_seconds, keep_trace=keep_trace,
+                      kernels=fam.KERNELS)
         peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
     finally:
         eng.shutdown()
     del eng
     gc.collect()
 
-    record = dict(win, cell=cell_name, config=conf, mix=mix, model=model,
-                  n_history=n_history, seconds=seconds,
+    record = dict(win, cell=cell_name, config=conf, family=fam, mix=mix,
+                  model=model, n_history=n_history, seconds=seconds,
                   setup_s=win["t0"] - t_start,
                   peaks=work.peaks(dev.device_kind)
                   if dev.platform == "tpu" else None)
@@ -366,7 +379,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     done = [r for r in window if r["ok"]]
     t = time.perf_counter()
     picked = sample(done, int(mix["check"]), seed, key=lambda r: r["m"])
-    gap = score_gap(params, model, n_history, picked,
+    gap = score_gap(fam, params, model, n_history, picked,
                     int(conf["max_slate"])) if picked else float("inf")
     log(f"reference over {len(picked)} requests in "
         f"{time.perf_counter() - t:.2f}s")
@@ -403,12 +416,14 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
 def measure(eng, tr: T.Traffic, mix: dict, seconds: float, *,
             trace: bool = False, trace_seconds: Optional[float] = None,
-            keep_trace: Optional[str] = None) -> dict:
+            keep_trace: Optional[str] = None,
+            kernels: Optional[dict] = None) -> dict:
     """Drive the window: open-loop arrivals on their schedule, or a closed
     loop at the mix's concurrency, for ``seconds``; then wait (at most
     DRAIN_LIMIT_S) for the requests due in it.  Returns the request log of
     the window, its bounds, the counters' window delta, compilations seen
-    inside it and, with ``trace``, the reduced trace of its middle."""
+    inside it and, with ``trace``, the reduced trace of its middle, with
+    the calls of ``kernels`` (the family's ``KERNELS``)."""
     client = Client(eng)
     stop = threading.Event()
     before = counters(eng)
@@ -426,7 +441,8 @@ def measure(eng, tr: T.Traffic, mix: dict, seconds: float, *,
     if trace:
         d = trace_seconds or min(3.0, seconds / 2)
         tracer = threading.Thread(target=_trace_window, args=(
-            eng, t0 + (seconds - d) / 2, d, traced, keep_trace))
+            eng, t0 + (seconds - d) / 2, d, traced, keep_trace,
+            kernels or {}))
     while time.perf_counter() < t0:
         time.sleep(0.001)
     th.start()
@@ -480,7 +496,7 @@ class _CompileCounter:
 
 
 def _trace_window(eng, at: float, d: float, out: dict,
-                  keep: Optional[str]) -> None:
+                  keep: Optional[str], kernels: dict) -> None:
     """Trace ``d`` seconds from ``at``: profiler on, a host annotation
     marking the window, profiler off; then reduce the trace."""
     import shutil
@@ -506,7 +522,8 @@ def _trace_window(eng, at: float, d: float, out: dict,
         files += [os.path.join(dirpath, n) for n in names
                   if n.endswith(".xplane.pb")]
     try:
-        out["summary"] = TR.reduce(max(files, key=os.path.getmtime))
+        out["summary"] = TR.reduce(max(files, key=os.path.getmtime),
+                                   kernels)
         out["counters"] = delta(c0, c1)
         out["window"] = (w0, w1)
     except (ValueError, OSError) as e:

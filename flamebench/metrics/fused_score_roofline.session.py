@@ -1,8 +1,9 @@
 """``fused_score``'s share of its roofline over the traced window: the
 least time of its calls at the chip's peaks over their summed device time
-(see ``stats.roofline_share``)."""
+(see ``stats.roofline_share``); nothing where the cell's family runs no
+``fused_score``."""
 from flamebench import stats
 
 
 def read(rec):
-    return stats.roofline_share(rec)
+    return stats.roofline_share(rec, "fused_score")

@@ -1,11 +1,10 @@
 """Reductions the metric readers share: which requests a window counts,
-latency percentiles, the kernel's roofline share and step MFU."""
+latency percentiles, a kernel's roofline share and step MFU (the last two
+through the record's model ``family``)."""
 from __future__ import annotations
 
 import math
 from typing import List, Optional
-
-from flamebench import work
 
 
 def completed_in_window(rec: dict) -> List[dict]:
@@ -42,61 +41,48 @@ def idle_share(rec: dict) -> Optional[float]:
     return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
 
 
-def roofline_share(rec: dict) -> Optional[float]:
-    """``fused_score``'s least time at the chip's peaks over its device
-    time, in percent, over the traced window.
+def roofline_share(rec: dict, kernel: str) -> Optional[float]:
+    """Kernel ``kernel``'s least time at the chip's peaks over its device
+    time, in percent, over the traced window; None where the cell's family
+    declares no such kernel or the window traced no call of it.
 
-    Calls and their shapes come from the kernel's HLO text in the trace
-    (``trace.kernel_call``): rows and query rows as dispatched, heads, the
-    padded history length and the stored K/V dtype.  Logical extents then
-    replace the padding: head_dim from the config (64, not 128 lanes) and
-    history positions ``min(padded, window/blocks + 1)``.  Distinct pool
-    rows read per call are the traced window's stacked KV rows of the
-    kernel families (DSO rows dispatched less rows deduped, less the
-    encode/extend/append rows) per ``cached`` and ``decode`` dispatch,
-    held between 1 and what the call's shapes allow."""
+    The least time of a call is the larger of its FLOPs over the bf16 peak
+    and its bytes over the HBM bandwidth, both from the family's
+    ``Kernel.work`` on the call's extents (parsed from its HLO text), the
+    model, the history window and the traced window's counters."""
     t = rec.get("trace")
-    if t is None or not t["kernels"] or rec.get("peaks") is None:
+    k = rec["family"].KERNELS.get(kernel)
+    if t is None or k is None or rec.get("peaks") is None:
+        return None
+    calls = [c for c in t["kernels"] if c["kernel"] == kernel]
+    if not calls:
         return None
     model, pk = rec["model"], rec["peaks"]
     c = rec["trace_counters"] or {}
-    s0 = rec["n_history"] // model["climber"]["num_blocks"] + 1
-    other = sum(c.get(f"dso_chunks_{k}", 0.0)
-                for k in ("encode", "extend", "append"))
-    stacked = c.get("dso_rows_dispatched", 0.0) \
-        - c.get("dso_dedup_rows_saved", 0.0) - other
-    calls = c.get("dso_dispatches_cached", 0.0) \
-        + c.get("dso_dispatches_decode", 0.0)
-    per_call = stacked / calls if calls > 0 else 1.0
     least = spent = 0.0
-    for k in t["kernels"]:
-        cap = min(k["pool_rows"], k["rows"] * max(1, k["q_rows"] // 8))
-        flops, nbytes = work.kernel_work(
-            rows=k["rows"], q_rows=k["q_rows"], heads=k["heads"],
-            head_dim=model["head_dim"], s_hist=min(k["s_pad"], s0),
-            unique_rows=min(max(per_call, 1.0), cap),
-            kv_bytes=k["kv_bytes"])
+    for call in calls:
+        flops, nbytes = k.work(call, model, rec["n_history"], c)
         least += max(flops / pk["bf16_flops_per_s"],
                      nbytes / pk["hbm_bytes_per_s"])
-        spent += k["seconds"]
+        spent += call["seconds"]
     return 100.0 * least / spent if spent > 0 else None
 
 
 def step_mfu(rec: dict) -> Optional[float]:
     """Model FLOPs that the requests completed in the traced window needed
-    (``work.request_flops``, from the traffic), over the device's busy
-    time in that window times the bf16 peak, in percent."""
+    (the family's ``request_flops``, from the traffic), over the device's
+    busy time in that window times the bf16 peak, in percent."""
     t = rec.get("trace")
     if t is None or t["busy_s"] <= 0 or rec.get("peaks") is None:
         return None
     w0, w1 = rec["trace_window"]
+    count = rec["family"].request_flops
     flops = 0.0
     for r in rec["requests"]:
         if r["ok"] and w0 <= r["done"] <= w1:
             q = r["req"]
-            flops += work.request_flops(
-                rec["model"], rec["n_history"], r["m"], new_user=q.new_user,
-                grew=q.grew)
+            flops += count(rec["model"], rec["n_history"], r["m"],
+                           new_user=q.new_user, grew=q.grew)
     if flops == 0:
         return None
     return 100.0 * flops / (t["busy_s"] * rec["peaks"]["bf16_flops_per_s"])

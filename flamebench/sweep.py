@@ -101,12 +101,12 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         harness.log("needs a TPU chip")
         return 3
-    conf = harness.load_json(ROOT, centry["file"])
+    conf, fam = harness.config_and_family(centry, ROOT)
     mix = T.load(cell["traffic"], HERE)
     enable_compile_cache()
     model = conf["model"]
-    bundle = build_model(harness.model_config(conf))
-    params = W.make_params(model, args.seed)
+    bundle = build_model(fam.program_config(conf))
+    params = W.make_params(fam.layout(model), args.seed)
     eng = harness.build_engine(conf, params, bundle)
     try:
         first = T.Traffic(mix, n_history=conf["n_history"],

@@ -9,7 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flamebench import harness, reference, weights as W
+from flamebench import weights as W
+from flamebench.families import climber
+from flamebench.families import climber_reference as reference
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 
@@ -21,8 +23,8 @@ def setup():
     with open(os.path.join(DATA, "tiny.json")) as f:
         conf = json.load(f)
     model = conf["model"]
-    bundle = build_model(harness.model_config(conf))
-    params = W.make_params(model, 2**31 + 11)
+    bundle = build_model(climber.program_config(conf))
+    params = W.make_params(climber.layout(model), 2**31 + 11)
     W.check_layout(params, jax.eval_shape(lambda k: bundle.init(k)[0],
                                           jax.random.key(0)))
     rng = np.random.default_rng(0)

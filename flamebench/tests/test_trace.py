@@ -9,6 +9,7 @@ import shutil
 import pytest
 
 from flamebench import stats, trace as TR
+from flamebench.families import climber
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 
@@ -25,14 +26,14 @@ def reduced(tmp_path_factory):
     with gzip.open(os.path.join(DATA, "trace_small.xplane.pb.gz")) as f, \
             open(path, "wb") as g:
         shutil.copyfileobj(f, g)
-    return TR.reduce(str(path))
+    return TR.reduce(str(path), climber.KERNELS)
 
 
 def test_kernel_call_shapes_from_hlo_text():
-    assert TR.kernel_call(OP) == {"rows": 1, "heads": 4, "q_rows": 32,
-                                  "pool_rows": 4, "s_pad": 384,
-                                  "kv_bytes": 1}
-    assert TR.kernel_call("%fusion.1 = bf16[4] fusion()") is None
+    assert climber.kernel_call(OP) == {"rows": 1, "heads": 4,
+                                       "q_rows": 32, "pool_rows": 4,
+                                       "s_pad": 384, "kv_bytes": 1}
+    assert climber.kernel_call("%fusion.1 = bf16[4] fusion()") is None
 
 
 def test_window_busy_and_kernel_time_are_pinned(reduced):
@@ -61,14 +62,14 @@ def test_breakdown_lists_top_ops_and_labelled_gaps(reduced):
 
 
 def test_roofline_share_of_the_small_trace(reduced):
-    rec = {"trace": reduced, "model": {"head_dim": 64,
-                                       "climber": {"num_blocks": 2}},
+    rec = {"trace": reduced, "family": climber,
+           "model": {"head_dim": 64, "climber": {"num_blocks": 2}},
            "n_history": 512,
            "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
            "trace_counters": {"dso_rows_dispatched": 10.0,
                               "dso_dispatches_cached": 6.0}}
-    share = stats.roofline_share(rec)
+    share = stats.roofline_share(rec, "fused_score")
     assert 0.1 < share < 5.0
     # one distinct pool row per call is the least the calls can read
     rec["trace_counters"] = {}
-    assert stats.roofline_share(rec) <= share
+    assert stats.roofline_share(rec, "fused_score") <= share

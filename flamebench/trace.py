@@ -1,26 +1,27 @@
 """Reduce a profiler trace (``.xplane.pb``) to the numbers the benchmark
-reads: the traced window, device busy time, each ``fused_score`` kernel
-call with its shapes, the device ops that took most time, and the longest
-idle gaps labelled by what the host was doing in them.
+reads: the traced window, device busy time, each call of the kernels a
+model family declares (``families.Kernel``) with its extents, the device ops
+that took most time, and the longest idle gaps labelled by what the host
+was doing in them.
 
 Layout read here (JAX 0.9 on TPU v5e): one plane per chip named
 ``/device:TPU:<n>`` whose line ``XLA Ops`` holds every HLO op run on the
-chip, named by its HLO text (``%_fused_kernel_call = bf16[B,H,Mp,128]
-custom-call(...)`` for the kernel); host threads are lines of the plane
-``/host:CPU``.  The benchmark marks the traced window with a host
-``TraceAnnotation`` named :data:`WINDOW`, on the same clock.
+chip, named by its HLO text (``%<op> = bf16[...] custom-call(...)`` for a
+kernel); host threads are lines of the plane ``/host:CPU``.  The benchmark
+marks the traced window with a host ``TraceAnnotation`` named
+:data:`WINDOW`, on the same clock.
 """
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Mapping, Tuple
 
 WINDOW = "flamebench.window"
-KERNEL = "%_fused_kernel_call"
 # control-flow ops cover the ops they run: never a device op of their own
 _CONTAINERS = ("%while", "%conditional", "%call")
 _SHAPE = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
-_BYTES = {"s8": 1, "u8": 1, "bf16": 2, "f16": 2, "f32": 4, "s32": 4}
+#: bytes of one element of an HLO dtype
+DTYPE_BYTES = {"s8": 1, "u8": 1, "bf16": 2, "f16": 2, "f32": 4, "s32": 4}
 
 
 def shapes(op_text: str) -> List[Tuple[str, Tuple[int, ...]]]:
@@ -28,22 +29,6 @@ def shapes(op_text: str) -> List[Tuple[str, Tuple[int, ...]]]:
     first, then the operands in order."""
     return [(dt, tuple(int(x) for x in dims.split(",") if x))
             for dt, dims in _SHAPE.findall(op_text)]
-
-
-def kernel_call(op_text: str) -> Optional[dict]:
-    """Shapes of one ``fused_score`` call from its HLO text: result
-    [B,H,Mp,Dp]; operands idx, lens, k/v scales, q, k/v history
-    [U,Hkv,Sp,Dp], k/v candidates."""
-    sh = shapes(op_text)
-    if len(sh) < 7:
-        return None
-    out = sh[0][1]
-    kv_dtype, kh = sh[6]
-    if len(out) != 4 or len(kh) != 4:
-        return None
-    return {"rows": out[0], "heads": out[1], "q_rows": out[2],
-            "pool_rows": kh[0], "s_pad": kh[2],
-            "kv_bytes": _BYTES.get(kv_dtype, 2)}
 
 
 def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -60,8 +45,12 @@ def _short(name: str) -> str:
     return name.split(" = ", 1)[0].strip()
 
 
-def reduce(path: str, *, n_gaps: int = 10, n_ops: int = 10) -> dict:
-    """Numbers of one trace file; times in seconds."""
+def reduce(path: str, kernels: Mapping[str, Any], *, n_gaps: int = 10,
+           n_ops: int = 10) -> dict:
+    """Numbers of one trace file; times in seconds.  ``kernels`` maps a
+    kernel's name to its ``families.Kernel``; each call of one is listed
+    under ``kernels`` with its ``kernel`` name, its parsed extents and its
+    ``seconds``."""
     from jax._src.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -84,7 +73,7 @@ def reduce(path: str, *, n_gaps: int = 10, n_ops: int = 10) -> dict:
         raise ValueError(f"{path}: no host event named {WINDOW!r}")
     w0, w1 = win
     busy_total = 0.0
-    kernels: List[dict] = []
+    calls: List[dict] = []
     op_time: Dict[str, float] = {}
     gaps: List[Tuple[float, float]] = []
     for plane in devices:
@@ -102,11 +91,14 @@ def reduce(path: str, *, n_gaps: int = 10, n_ops: int = 10) -> dict:
                     continue
                 short = _short(name)
                 op_time[short] = op_time.get(short, 0.0) + (b - a)
-                if name.startswith(KERNEL):
-                    call = kernel_call(name)
-                    if call is not None:
-                        call["seconds"] = (b - a) * 1e-9
-                        kernels.append(call)
+                for kname, k in kernels.items():
+                    if name.startswith(k.op):
+                        call = k.parse(name)
+                        if call is not None:
+                            call["kernel"] = kname
+                            call["seconds"] = (b - a) * 1e-9
+                            calls.append(call)
+                        break
         merged = _union(iv)
         busy_total += sum(b - a for a, b in merged)
         edges = [w0] + [x for ab in merged for x in ab] + [w1]
@@ -133,7 +125,7 @@ def reduce(path: str, *, n_gaps: int = 10, n_ops: int = 10) -> dict:
         "window_s": (w1 - w0) * 1e-9,
         "busy_s": busy_total * 1e-9 / n_dev,
         "devices": len(devices),
-        "kernels": kernels,
+        "kernels": calls,
         "device_ops": [[n, t * 1e-9 / n_dev] for n, t in top_ops],
         "idle_gaps": idle,
     }

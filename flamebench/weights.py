@@ -1,59 +1,18 @@
-"""Climber weights made by the benchmark from ``--seed``.
+"""Weights made by the benchmark from ``--seed``.
 
-One jitted call draws every leaf on the device, in the type it is served
-in (bfloat16; the per-layer temperatures in float32).  The program under
-test and the plain reference both read this tree, so the reference never
-takes weights the program made.  :func:`check_layout` holds the tree to the
-program's own parameter layout, so a program whose layout moved fails the
-run instead of serving a tree it does not read.
+One jitted call draws every leaf of a family's layout (``layout(model)`` of
+``flamebench/families/<family>.py``) on the device, in the type it is
+served in.  The program under test and the plain reference both read this
+tree, so the reference never takes weights the program made.
+:func:`check_layout` holds the tree to the program's own parameter layout,
+so a program whose layout moved fails the run instead of serving a tree it
+does not read.
 """
 from __future__ import annotations
 
 import zlib
 
 import numpy as np
-
-N_SIDE_FEATURES = 12
-POS_TABLE = 8192
-
-
-def layout(model: dict) -> dict:
-    """Leaf path -> (shape, dtype name, init rule) for a Climber config."""
-    d, f, h = model["d_model"], model["d_ff"], model["n_heads"]
-    hkv, hd = model["n_kv_heads"], model["head_dim"]
-    c = model["climber"]
-    nl, nb, e, t = c["layers_per_block"], c["num_blocks"], \
-        c["num_experts_head"], c["num_tasks"]
-    out = {
-        "embed/embedding": ((model["vocab_size"], d), "bfloat16", 0.02),
-        "pos_embed": ((POS_TABLE, d), "bfloat16", 0.02),
-        "side_proj": ((N_SIDE_FEATURES, d), "bfloat16", "fan_in"),
-        "gate_w": ((nb, d), "bfloat16", 0.02),
-        "gate_b": ((nb, d), "bfloat16", "bias"),
-        "out_norm/scale": ((d,), "bfloat16", "scale"),
-        "out_norm/bias": ((d,), "bfloat16", "bias"),
-        "experts_w1": ((e, d, d), "bfloat16", 1 / np.sqrt(d)),
-        "experts_w2": ((e, d, d), "bfloat16", 1 / np.sqrt(d)),
-        "task_gates": ((t, d, e), "bfloat16", 1 / np.sqrt(d)),
-        "task_towers": ((t, d), "bfloat16", 1 / np.sqrt(d)),
-    }
-    for i in range(nb):
-        b = f"blocks/b{i}"
-        out.update({
-            f"{b}/norm1/scale": ((nl, d), "bfloat16", "scale"),
-            f"{b}/norm1/bias": ((nl, d), "bfloat16", "bias"),
-            f"{b}/norm2/scale": ((nl, d), "bfloat16", "scale"),
-            f"{b}/norm2/bias": ((nl, d), "bfloat16", "bias"),
-            f"{b}/attn/wq": ((nl, d, h, hd), "bfloat16", 1 / np.sqrt(d)),
-            f"{b}/attn/wk": ((nl, d, hkv, hd), "bfloat16", 1 / np.sqrt(d)),
-            f"{b}/attn/wv": ((nl, d, hkv, hd), "bfloat16", 1 / np.sqrt(d)),
-            f"{b}/attn/wo": ((nl, h, hd, d), "bfloat16",
-                             1 / np.sqrt(h * hd)),
-            f"{b}/ffn/w_up": ((nl, d, f), "bfloat16", 1 / np.sqrt(d)),
-            f"{b}/ffn/w_down": ((nl, f, d), "bfloat16", 1 / np.sqrt(f)),
-            f"{b}/temp": ((nl, 1), "float32", "temp"),
-        })
-    return out
 
 
 def _nest(flat: dict) -> dict:
@@ -67,12 +26,15 @@ def _nest(flat: dict) -> dict:
     return tree
 
 
-def make_params(model: dict, seed: int):
-    """The weight tree on the default device, from ``seed`` (any integer)."""
+def make_params(lay: dict, seed: int):
+    """The weight tree of layout ``lay`` (leaf path -> (shape, dtype name,
+    init rule)) on the default device, from ``seed`` (any integer).  A rule
+    is a standard deviation, or ``"scale"`` (1 + 0.1 z), ``"bias"``
+    (0.1 z), ``"temp"`` (0.55 + 0.1 z) or ``"fan_in"`` (z / sqrt of the
+    leading dimension)."""
     import jax
     import jax.numpy as jnp
 
-    lay = layout(model)
     s = int(seed) % 2**64
 
     def draw(lo, hi):
