@@ -29,7 +29,7 @@ from repro.core import sumi
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models.ffn import ffn_init, ffn_apply
-from repro.models.model import ModelBundle
+from repro.models.model import HostPlane, ModelBundle
 from repro.types import ModelConfig, ShapeConfig
 
 N_SIDE_FEATURES = 12   # "a dozen pieces of side information" (paper §4.1)
@@ -624,4 +624,7 @@ def build_climber(cfg: ModelConfig) -> ModelBundle:
                        history_kv_specs=history_kv_specs_fn,
                        extend_history=extend_history_fn,
                        decode_logits=decode_logits_fn,
-                       append_token=append_token_fn)
+                       append_token=append_token_fn,
+                       host_planes=(HostPlane("history", "window"),
+                                    HostPlane("side", "side_features",
+                                              N_SIDE_FEATURES, "float32")))

@@ -289,6 +289,38 @@ class FeatureQueryEngine:
 
 
 # ---------------------------------------------------------------------------
+# synthetic user-history service: per-action timestamps
+# ---------------------------------------------------------------------------
+
+#: the serving clock: every request is scored at this time (seconds), and
+#: action times are seconds on the same clock
+REQUEST_TIME = 1_700_000_000
+#: action ages are log-uniform over [1, MAX_AGE_S) seconds (about 2 years)
+MAX_AGE_S = 1 << 26
+
+
+def action_times(user_id: Optional[int], items: np.ndarray) -> np.ndarray:
+    """Timestamps (int32 seconds) of a user's actions on ``items``, as the
+    synthetic user-history service keeps them: a splitmix64 hash of
+    (user id, item id) picks each action's age, log-uniform below
+    MAX_AGE_S, before REQUEST_TIME.  One vectorized pass; the time of an
+    action never changes, so a cached prefix stays valid as the history
+    grows."""
+    with np.errstate(over="ignore"):
+        x = np.asarray(  # flamecheck: host-sync-ok(history ids arrive as host numpy; the hash runs on the host)
+            items).astype(np.uint64) ^ (
+            np.uint64((user_id or 0) & 0xFFFFFFFFFFFFFFFF)
+            * np.uint64(0x9E3779B97F4A7C15))
+        x = x + np.uint64(0x9E3779B97F4A7C15)
+        x = (x ^ (x >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+        x = (x ^ (x >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+        x = x ^ (x >> np.uint64(31))
+    u = (x >> np.uint64(11)).astype(np.float64) * 2.0 ** -53
+    age = np.floor(np.exp(u * np.log(MAX_AGE_S))).astype(np.int64)
+    return (REQUEST_TIME - age).astype(np.int32)
+
+
+# ---------------------------------------------------------------------------
 # packed transfer (pinned-memory analogue)
 # ---------------------------------------------------------------------------
 

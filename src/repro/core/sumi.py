@@ -334,3 +334,17 @@ def cached_flops_per_request(n_history: int, n_candidates: int, n_blocks: int,
     attn_pairs = n_candidates * (n_hist_b + 1)
     per_layer = n_candidates * per_tok_proj + 2 * 2 * attn_pairs * d_model
     return n_blocks * layers_per_block * per_layer
+
+
+def pointwise_flops_per_request(n_history: int, n_candidates: int,
+                                n_layers: int, d_model: int,
+                                attn_width: int) -> float:
+    """Analytic FLOPs of one pointwise-attention (HSTU) forward under the
+    SUMI / M-FALCON mask: per token and layer the ``d -> 4 * attn_width``
+    and ``attn_width -> d`` projections, QK and AV over the causal history
+    pairs and each candidate's history plus itself; no FFN."""
+    per_tok_proj = 2 * (d_model * 4 * attn_width + attn_width * d_model)
+    attn_pairs = n_history * (n_history + 1) / 2 \
+        + n_candidates * (n_history + 1)
+    return n_layers * ((n_history + n_candidates) * per_tok_proj
+                       + 2 * 2 * attn_pairs * attn_width)

@@ -46,6 +46,14 @@ The per-(row, head) scales and the per-row lengths ride in scalar
 prefetch (SMEM) next to the ``row_index``; accumulators (m, l, acc) live
 in VMEM scratch across the sequential innermost grid axis, exactly like
 ``kernels/flash_attention``.
+
+The **pointwise variant** (:func:`hstu_score_kernel`, HSTU's attention)
+keeps the grid, the ``row_index`` pool-row gather, the in-register
+dequant and the packed-segment steering, and replaces the online-softmax
+update with ``acc += SiLU(s + bias) @ v`` and a final ``/ N``.  Its bias
+arrives precomputed: one row per pool row on the cached path (every
+candidate of a row sits at the same position and time), one row per
+query on the extend path (``bias_kernel.py`` builds it there).
 """
 from __future__ import annotations
 
@@ -109,12 +117,7 @@ def _fused_kernel(idx_ref, lens_ref, ks_ref, vs_ref, q_ref, kh_ref, vh_ref,
         _online_update(s, (rows < sq) & (cols < lens_ref[row]), v,
                        vs_ref[row, kvh])
 
-    if mode == "cached":
-        self_guard = kj == hist_steps
-    else:                                    # extend: causal suffix blocks
-        self_guard = (kj >= hist_steps) & (kj - hist_steps <= qi)
-
-    @pl.when(self_guard)
+    @pl.when(_self_guard(mode, qi, kj, hist_steps))
     def _self_step():
         # fresh candidate / suffix block, full precision, no scale
         cj = qi if mode == "cached" else kj - hist_steps
@@ -135,6 +138,48 @@ def _fused_kernel(idx_ref, lens_ref, ks_ref, vs_ref, q_ref, kh_ref, vh_ref,
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _self_guard(mode: str, qi, kj, hist_steps: int):
+    """Whether grid step ``kj`` of q block ``qi`` is a candidate/suffix
+    step: the one diagonal block (cached), or a suffix block at or below
+    the diagonal (extend: causal)."""
+    if mode == "cached":
+        return kj == hist_steps
+    return (kj >= hist_steps) & (kj - hist_steps <= qi)
+
+
+def _index_maps(mode: str, h: int, g: int, nq: int, hist_steps: int):
+    """BlockSpec index maps both kernels share, over the grid (B*H, nq,
+    steps) with the four scalar-prefetch refs trailing: the row index, then
+    three the maps do not read (scales, and lengths or the self bias):
+    query/output blocks, pooled-history blocks and candidate/suffix
+    blocks."""
+
+    def q_map(bh, qi, kj, idx_ref, pf1, pf2, pf3):
+        return (bh // h, bh % h, qi, 0)
+
+    def kh_map(bh, qi, kj, idx_ref, pf1, pf2, pf3):
+        # the dedup/packing gather, folded into the block read: q block qi
+        # of batch row b pulls the blocks of pool row idx_ref[b, qi]
+        # (clamped for self steps, whose loaded block is unused)
+        return (idx_ref[bh // h, qi], (bh % h) // g,
+                jnp.minimum(kj, hist_steps - 1),
+                0)  # flamecheck: kernel-ok(pure scalar clamp of a grid index; Python min fails on the traced kj)
+
+    def kc_map(bh, qi, kj, idx_ref, pf1, pf2, pf3):
+        return (bh // h, (bh % h) // g, _self_block(mode, qi, kj, nq,
+                                                     hist_steps), 0)
+
+    return q_map, kh_map, kc_map
+
+
+def _self_block(mode: str, qi, kj, nq: int, hist_steps: int):
+    """Candidate/suffix block read at grid step ``kj``: the q block's own
+    (cached), or the suffix block ``kj - hist_steps`` clamped (extend)."""
+    if mode == "cached":
+        return qi
+    return jnp.clip(kj - hist_steps, 0, nq - 1)
 
 
 def fused_score_kernel(row_index, lengths, k_scale, v_scale, q, k_hist,
@@ -169,25 +214,7 @@ def fused_score_kernel(row_index, lengths, k_scale, v_scale, q, k_hist,
         s_hist=s_hist, hist_steps=hist_steps, steps=steps)
 
     grid = (b * h, nq, steps)
-
-    def q_map(bh, qi, kj, idx_ref, lens_ref, ks_ref, vs_ref):
-        return (bh // h, bh % h, qi, 0)
-
-    def kh_map(bh, qi, kj, idx_ref, lens_ref, ks_ref, vs_ref):
-        # the dedup/packing gather, folded into the block read: q block qi
-        # of batch row b pulls the blocks of pool row idx_ref[b, qi]
-        # (clamped for self steps, whose loaded block is unused)
-        return (idx_ref[bh // h, qi], (bh % h) // g,
-                jnp.minimum(kj, hist_steps - 1),
-                0)  # flamecheck: kernel-ok(pure scalar clamp of a grid index; Python min fails on the traced kj)
-
-    def kc_map(bh, qi, kj, idx_ref, lens_ref, ks_ref, vs_ref):
-        if mode == "cached":
-            cj = qi
-        else:
-            cj = jnp.clip(kj - hist_steps, 0,
-                          nq - 1)  # flamecheck: kernel-ok(pure scalar clamp of a grid index; Python min/max fail on the traced kj)
-        return (bh // h, (bh % h) // g, cj, 0)
+    q_map, kh_map, kc_map = _index_maps(mode, h, g, nq, hist_steps)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,       # row_index, lengths, k_scale, v_scale
@@ -213,3 +240,135 @@ def fused_score_kernel(row_index, lengths, k_scale, v_scale, q, k_hist,
         interpret=default_interpret() if interpret is None else interpret,
     )(row_index, lengths, k_scale, v_scale, q, k_hist, v_hist, k_cand,
       v_cand)
+
+
+# ---------------------------------------------------------------------------
+# pointwise variant (HSTU): SiLU(s + bias) / N in place of the softmax
+# ---------------------------------------------------------------------------
+
+def _pointwise_kernel(idx_ref, ks_ref, vs_ref, sb_ref, q_ref, kh_ref, vh_ref,
+                      bh_ref, kc_ref, vc_ref, *rest, mode: str, h: int,
+                      g: int, bq: int, bk: int, sq: int, s_hist: int,
+                      hist_steps: int, steps: int, n_norm: float):
+    """Same grid, gather and dequant as :func:`_fused_kernel`; each step
+    adds ``SiLU(s + bias) @ v`` to the accumulator (no running max, no
+    denominator) and the last step divides by ``n_norm``.  ``bh_ref`` is
+    the history bias block, one row broadcast over the q block (cached) or
+    one row per query (extend); the self bias is the scalar ``sb_ref[0]``
+    (cached) or the suffix bias block (extend, first of ``rest``)."""
+    if mode == "extend":
+        bc_ref, o_ref, acc_ref = rest
+    else:
+        o_ref, acc_ref = rest
+    bh = pl.program_id(0)
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
+    row = idx_ref[bh // h, qi]               # pool row of this q block
+    kvh = (bh % h) // g                      # kv head of this q head
+
+    @pl.when(kj == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def _accumulate(s, msk, v, v_scale):
+        p = jnp.where(msk, jax.nn.silu(s), 0.0)
+        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+        if v_scale is not None:
+            pv = pv * v_scale
+        acc_ref[...] = acc_ref[...] + pv
+
+    @pl.when(kj < hist_steps)
+    def _history_step():
+        q = q_ref[0, 0].astype(jnp.float32)                  # [bq, D]
+        k = kh_ref[0, 0].astype(jnp.float32)                 # [bk, D]
+        v = vh_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        s = s * ks_ref[row, kvh] + bh_ref[0]
+        rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        _accumulate(s, (rows < sq) & (cols < s_hist), v, vs_ref[row, kvh])
+
+    @pl.when(_self_guard(mode, qi, kj, hist_steps))
+    def _self_step():
+        cj = qi if mode == "cached" else kj - hist_steps
+        q = q_ref[0, 0].astype(jnp.float32)
+        k = kc_ref[0, 0].astype(jnp.float32)                 # [bq, D]
+        v = vc_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
+        cols = cj * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1)
+        ok = (rows < sq) & (cols < sq)
+        if mode == "cached":
+            s = s + sb_ref[0]
+            msk = ok & (rows == cols)        # self key only (SUMI)
+        else:
+            s = s + bc_ref[0]
+            msk = ok & (cols <= rows)        # causal within the suffix
+        _accumulate(s, msk, v, None)
+
+    @pl.when(kj == steps - 1)
+    def _finalize():
+        o_ref[0, 0] = (acc_ref[...] / n_norm).astype(o_ref.dtype)
+
+
+def hstu_score_kernel(row_index, k_scale, v_scale, self_bias, q, k_hist,
+                      v_hist, bias_hist, k_cand, v_cand, bias_cand=None, *,
+                      mode: str, sq: int, s_hist: int, n_norm: float,
+                      bq: int = 128, bk: int = 128,
+                      interpret: bool | None = None):
+    """Pointwise attention over the same operands as
+    :func:`fused_score_kernel` (q not pre-scaled): ``bias_hist``
+    [U, R, Sp] f32 with R = 1 (cached: one row per pool row, broadcast
+    over its candidates) or R = Mp (extend: one row per query);
+    ``self_bias`` [1] f32, the cached self key's bias; ``bias_cand``
+    [B, Mp, Mp] f32, the extend suffix's causal bias (None in cached
+    mode)."""
+    if mode not in ("cached", "extend"):
+        raise ValueError(mode)
+    b, h, mp, d = q.shape
+    hkv = k_hist.shape[1]
+    g = h // hkv
+    sp = k_hist.shape[2]
+    nq = mp // bq
+    hist_steps = sp // bk
+    steps = hist_steps + (1 if mode == "cached" else nq)
+    per_query = bias_hist.shape[1] > 1
+
+    kernel = functools.partial(
+        _pointwise_kernel, mode=mode, h=h, g=g, bq=bq, bk=bk, sq=sq,
+        s_hist=s_hist, hist_steps=hist_steps, steps=steps,
+        n_norm=float(n_norm))
+    q_map, kh_map, kc_map = _index_maps(mode, h, g, nq, hist_steps)
+
+    def bh_map(bh, qi, kj, idx_ref, ks_ref, vs_ref, sb_ref):
+        row, _, kblk, _ = kh_map(bh, qi, kj, idx_ref, ks_ref, vs_ref, sb_ref)
+        return (row, qi if per_query else 0, kblk)
+
+    in_specs = [
+        pl.BlockSpec((1, 1, bq, d), q_map),
+        pl.BlockSpec((1, 1, bk, d), kh_map),
+        pl.BlockSpec((1, 1, bk, d), kh_map),
+        pl.BlockSpec((1, bq if per_query else 1, bk), bh_map),
+        pl.BlockSpec((1, 1, bq, d), kc_map),
+        pl.BlockSpec((1, 1, bq, d), kc_map),
+    ]
+    args = [q, k_hist, v_hist, bias_hist, k_cand, v_cand]
+    if mode == "extend":
+        def bc_map(bh, qi, kj, idx_ref, ks_ref, vs_ref, sb_ref):
+            return (bh // h, qi, _self_block(mode, qi, kj, nq, hist_steps))
+        in_specs.append(pl.BlockSpec((1, bq, bq), bc_map))
+        args.append(bias_cand)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,     # row_index, k_scale, v_scale, self_bias
+        grid=(b * h, nq, steps),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],    # acc
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=default_interpret() if interpret is None else interpret,
+    )(row_index, k_scale, v_scale, self_bias, *args)
+

@@ -10,6 +10,10 @@ Entry points (model layout, [B,S,H,D]):
   ``fused_decode_attention``  generative-decode candidate scoring: cached
                               mode against PADDED growing beam caches,
                               bounded per pool row by ``lengths`` (FKE v2)
+  ``hstu_cached_attention``   the pointwise variant (HSTU): SiLU(s + bias)
+  ``hstu_extend_attention``   / N over the same operands, no softmax
+  ``hstu_relative_bias``      HSTU's position + time-bucket bias of a
+                              query block over its keys
   ``block_epilogue``          out-projection + residual + norm + FFN for
                               one transformer-block layer step, reusing
                               ``kernels/fused_ffn`` on TPU
@@ -45,7 +49,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import default_interpret
-from repro.kernels.fused_score.kernel import fused_score_kernel
+from repro.kernels.fused_score.bias_kernel import (LANES,
+                                                   TIME_BUCKET_WIDTH,
+                                                   time_bias_kernel)
+from repro.kernels.fused_score.kernel import (fused_score_kernel,
+                                              hstu_score_kernel)
 from repro.kernels.fused_score.ref import dequantize_values
 
 
@@ -78,7 +86,7 @@ def _note_packed_reroute():
             "Pallas kernel to the jnp formulation — packed segments are not "
             "bq-aligned yet (ROADMAP: packer `align` knob). Pass "
             "path='kernel' only with block-aligned segments.",
-            RuntimeWarning, stacklevel=4)
+            RuntimeWarning, stacklevel=5)
 
 
 def packed_reroute_count() -> int:
@@ -298,19 +306,18 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "bq", "bk",
-                                             "interpret"))
-def _fused_kernel_call(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
-                       row_index, lengths, mode: str, bq: int, bk: int,
-                       interpret: bool | None):
+def _kernel_layout(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
+                   row_index, bq: int, bk: int):
+    """Operands of both kernels from model-layout ones: block sizes fitted
+    to the extents, [B,S,H,D] -> [B,H,S,D] padded to block and 128-lane
+    multiples, unit scales where the pool stores no scale, and the
+    per-q-block pool-row index.  Returns (padded q, k_hist, v_hist,
+    k_cand, v_cand), (k_scale, v_scale), idx, bq, bk."""
     b, m, h, d = q.shape
     u, s_hist, hkv, _ = k_hist.shape
     bq = min(bq, max(8, 1 << (m - 1).bit_length()))
     bk = min(bk, max(8, 1 << (s_hist - 1).bit_length()))
-    scale = 1.0 / np.sqrt(d)
-    # model layout [B,S,H,D] -> kernel layout [B,H,S,D], pad S to block
-    # multiples and D to the 128-lane width
-    qp = _pad_to(_pad_to(jnp.swapaxes(q * scale, 1, 2), 2, bq), 3, 128)
+    qp = _pad_to(_pad_to(jnp.swapaxes(q, 1, 2), 2, bq), 3, 128)
     khp = _pad_to(_pad_to(jnp.swapaxes(k_hist, 1, 2), 2, bk), 3, 128)
     vhp = _pad_to(_pad_to(jnp.swapaxes(v_hist, 1, 2), 2, bk), 3, 128)
     kcp = _pad_to(_pad_to(jnp.swapaxes(k_cand, 1, 2), 2, bq), 3, 128)
@@ -334,6 +341,20 @@ def _fused_kernel_call(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
         full = jnp.pad(row_index.astype(jnp.int32),
                        ((0, 0), (0, nq * bq - m)), mode="edge")
         idx = full[:, ::bq]
+    return (qp, khp, vhp, kcp, vcp), (ks, vs), idx, bq, bk
+
+
+@functools.partial(jax.jit, static_argnames=("mode", "bq", "bk",
+                                             "interpret"))
+def _fused_kernel_call(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
+                       row_index, lengths, mode: str, bq: int, bk: int,
+                       interpret: bool | None):
+    b, m, h, d = q.shape
+    u, s_hist, hkv, _ = k_hist.shape
+    scale = 1.0 / np.sqrt(d)
+    (qp, khp, vhp, kcp, vcp), (ks, vs), idx, bq, bk = _kernel_layout(
+        q * scale, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
+        row_index, bq, bk)
     lens = (jnp.full((u,), s_hist, jnp.int32) if lengths is None
             else jnp.asarray(lengths, jnp.int32))
     out = fused_score_kernel(idx, lens, ks, vs, qp.astype(q.dtype), khp,
@@ -343,18 +364,212 @@ def _fused_kernel_call(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
 
 
 # ---------------------------------------------------------------------------
-# public entry points
+# pointwise variant (HSTU): SiLU(s + bias) / N, no softmax
 # ---------------------------------------------------------------------------
 
-def _fused_attention(q, k_hist, v_hist, k_cand, v_cand, *, mode: str,
-                     k_scale=None, v_scale=None, row_index=None,
-                     lengths=None, temperature=None, path: str = "auto",
-                     interpret=None):
-    if temperature is not None:
-        q = q / jnp.asarray(temperature, q.dtype)
+@functools.partial(jax.jit, static_argnames=("mode", "n_norm"))
+def _hstu_jnp(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
+              bias_hist, bias_cand, self_bias, row_index, mode: str,
+              n_norm: float):
+    """The kernel's pointwise attention as one XLA graph: the history
+    segment fully visible with its bias (one row per pool row, or one per
+    query), the self segment the diagonal (cached) or a causal suffix with
+    its bias (extend), scales folded after the dots as in the kernel."""
+    b, m, h, d = q.shape
+    hkv = k_cand.shape[2]
+    g = h // hkv
+    f32 = jnp.float32
+    qf = q.astype(f32).reshape(b, m, hkv, g, d)
+    seg = row_index is not None and row_index.ndim == 2
+    if row_index is not None:
+        # gathers run on the stored values; a 2-D (packed) index gives
+        # every candidate its own pool row: [B,M,S,Hkv,D] operands
+        k_hist = jnp.take(k_hist, row_index, axis=0)
+        v_hist = jnp.take(v_hist, row_index, axis=0)
+        bias_hist = jnp.take(bias_hist, row_index, axis=0)
+        if k_scale is not None:
+            k_scale = jnp.take(k_scale, row_index, axis=0)
+        if v_scale is not None:
+            v_scale = jnp.take(v_scale, row_index, axis=0)
+    if seg:
+        s_hist = jnp.einsum("bmhgd,bmshd->bhgms", qf, k_hist.astype(f32))
+        if k_scale is not None:
+            s_hist = s_hist * jnp.moveaxis(
+                k_scale, 2, 1)[:, :, None, :, None]      # [b,hkv,1,m,1]
+        s_hist = s_hist + bias_hist[:, :, 0][:, None, None]
+        p_hist = jax.nn.silu(s_hist)
+        o = jnp.einsum("bhgms,bmshd->bmhgd", p_hist, v_hist.astype(f32))
+        if v_scale is not None:
+            o = o * v_scale[:, :, :, None, None]         # [b,m,hkv,1,1]
+    else:
+        s_hist = _segment_scores(qf, k_hist, k_scale)    # [b,hkv,g,m,S]
+        s_hist = s_hist + bias_hist[:, None, None]       # rows 1 or m
+        p_hist = jax.nn.silu(s_hist)
+        o = jnp.einsum("bhgms,bshd->bmhgd", p_hist, v_hist.astype(f32))
+        if v_scale is not None:
+            o = o * v_scale[:, None, :, None, None]
+    if mode == "cached":
+        s_self = jnp.einsum("bmhgd,bmhd->bhgm", qf,
+                            k_cand.astype(f32)) + self_bias[0]
+        o = o + jnp.einsum("bhgm,bmhd->bmhgd", jax.nn.silu(s_self),
+                           v_cand.astype(f32))
+    else:                                            # extend (causal)
+        s_suf = jnp.einsum("bmhgd,bshd->bhgms", qf, k_cand.astype(f32))
+        s_suf = s_suf + bias_cand[:, None, None]
+        causal = (jnp.arange(m)[None, :] <= jnp.arange(m)[:, None])
+        p_suf = jnp.where(causal[None, None, None], jax.nn.silu(s_suf), 0.0)
+        o = o + jnp.einsum("bhgms,bshd->bmhgd", p_suf, v_cand.astype(f32))
+    return (o / n_norm).reshape(b, m, h, d).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("mode", "bq", "bk", "n_norm",
+                                             "interpret"))
+def _hstu_kernel_call(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
+                      bias_hist, bias_cand, self_bias, row_index, mode: str,
+                      bq: int, bk: int, n_norm: float,
+                      interpret: bool | None):
+    b, m, h, d = q.shape
+    s_hist = k_hist.shape[1]
+    (qp, khp, vhp, kcp, vcp), (ks, vs), idx, bq, bk = _kernel_layout(
+        q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale, row_index,
+        bq, bk)
+    bias_hist = _pad_to(jnp.asarray(bias_hist, jnp.float32), 2, bk)
+    if bias_hist.shape[1] > 1:
+        bias_hist = _pad_to(bias_hist, 1, bq)
+    if bias_cand is not None:
+        bias_cand = _pad_to(_pad_to(jnp.asarray(bias_cand, jnp.float32),
+                                    1, bq), 2, bq)
+    out = hstu_score_kernel(
+        idx, ks, vs, jnp.asarray(self_bias, jnp.float32).reshape(1), qp,
+        khp, vhp, bias_hist, kcp, vcp, bias_cand, mode=mode, sq=m,
+        s_hist=s_hist, n_norm=n_norm, bq=bq, bk=bk, interpret=interpret)
+    return jnp.swapaxes(out[:, :, :m, :d], 1, 2)
+
+
+def time_bucket(dt, max_bucket: int):
+    """HSTU's time bucket of int32 gaps ``dt``: floor(ln |dt| / 0.301),
+    gaps under a second in bucket 0, capped at ``max_bucket``."""
+    gap = jnp.maximum(jnp.abs(dt), 1).astype(jnp.float32)
+    b = jnp.floor(jnp.log(gap) / TIME_BUCKET_WIDTH)
+    return jnp.minimum(b, max_bucket).astype(jnp.int32)
+
+
+def _position_bias(w_pos, n_q: int, n_k: int, offset: int):
+    """[n_q, n_k] ``w_pos[clip(offset + i - j, 0, len - 1)]``, a Toeplitz
+    matrix, without a gather (an XLA gather runs one index at a time on the
+    TPU): the offsets it reads are one run of the table, sliced statically
+    (clipped ends repeat the end entries), whose sliding windows come from
+    a broadcast read with a row stride one longer than the run."""
+    p = w_pos.shape[0]
+    run = n_q + n_k - 1                   # offsets offset-(n_k-1) .. +n_q-1
+    lo = offset - (n_k - 1)
+    z = jnp.concatenate([
+        jnp.full((max(0, -lo),), w_pos[0]),
+        w_pos[max(lo, 0):min(lo + run, p)],
+        jnp.full((max(0, lo + run - p),), w_pos[p - 1])])
+    # windows of the reversed run: row r holds zr[r : r + n_k]
+    zr = z[::-1]
+    flat = jnp.broadcast_to(zr, (n_q + 1, run)).reshape(-1)
+    windows = flat[:n_q * (run + 1)].reshape(n_q, run + 1)[:, :n_k]
+    return windows[::-1]
+
+
+@functools.partial(jax.jit, static_argnames=("offset", "path",
+                                             "interpret"))
+def hstu_relative_bias(w_pos, w_time, t_q, t_k, *, offset: int,
+                       path: str = "auto", interpret=None):
+    """HSTU's relative attention bias [B, Q, K] f32 of queries at
+    positions ``offset + i`` with times ``t_q`` [B, Q] over keys at
+    positions ``j`` with times ``t_k`` [B, K]:
+    ``w_pos[clip(offset + i - j, 0, len - 1)] + w_time[bucket(t_q[i] -
+    t_k[j])]``.  The position part is a Toeplitz matrix of row slices; the
+    time part a lane-gather kernel (``path="kernel"``), or an XLA gather
+    (``"jnp"``, the CPU's; ``"auto"`` picks as the attention does).  The
+    kernel's table is one 128-lane row: int32 gaps never pass bucket 71
+    (ln 2**31 / 0.301 < 72), so its cap at 127 reads as the table's."""
+    f32 = jnp.float32
+    b, n_q = t_q.shape
+    n_k = t_k.shape[1]
+    w_time = jnp.asarray(w_time, f32)
+    max_bucket = w_time.shape[0] - 1
+    pos = _position_bias(jnp.asarray(w_pos, f32), n_q, n_k, offset)
+    if path == "auto":
+        path = _auto_path()
+    if path == "jnp":
+        tb = time_bucket(t_q[:, :, None] - t_k[:, None, :], max_bucket)
+        return pos[None] + w_time[tb]
+    if path != "kernel":
+        raise ValueError(f"path must be auto|kernel|jnp, got {path!r}")
+    bq = min(256, max(8, 1 << (n_q - 1).bit_length()))
+    table = _pad_to(w_time[:LANES][None], 1, LANES)
+    out = time_bias_kernel(
+        _pad_to(t_q.astype(jnp.int32)[:, :, None], 1, bq),
+        _pad_to(t_k.astype(jnp.int32)[:, None, :], 2, LANES), table,
+        _pad_to(_pad_to(pos, 0, bq), 1, LANES),
+        max_bucket=min(max_bucket, LANES - 1), bq=bq, interpret=interpret)
+    return out[:, :n_q, :n_k]
+
+
+def _pointwise_attention(q, k_hist, v_hist, k_cand, v_cand, bias_hist, *,
+                         mode: str, n_norm: float, self_bias=None,
+                         bias_cand=None, k_scale=None, v_scale=None,
+                         row_index=None, path: str = "auto", bk: int = 128,
+                         interpret=None):
     u, hkv = k_hist.shape[0], k_hist.shape[2]
     ks = _norm_scale(k_scale, u, hkv)
     vs = _norm_scale(v_scale, u, hkv)
+    if self_bias is None:
+        self_bias = jnp.zeros((1,), jnp.float32)
+    self_bias = jnp.asarray(self_bias, jnp.float32).reshape(1)
+    path, bq = _route(q, k_hist, row_index, mode, path)
+    if path == "kernel":
+        return _hstu_kernel_call(q, k_hist, v_hist, k_cand, v_cand, ks, vs,
+                                 bias_hist, bias_cand, self_bias, row_index,
+                                 mode, bq, bk, float(n_norm), interpret)
+    return _hstu_jnp(q, k_hist, v_hist, k_cand, v_cand, ks, vs, bias_hist,
+                     bias_cand, self_bias, row_index, mode, float(n_norm))
+
+
+def hstu_cached_attention(q, k_hist, v_hist, k_cand, v_cand, bias_hist,
+                          self_bias, *, n_norm: float, k_scale=None,
+                          v_scale=None, row_index=None, path: str = "auto",
+                          bk: int = 128, interpret=None):
+    """HSTU candidate scoring against pooled history K/V: every candidate
+    adds ``SiLU(q.k_j + bias_hist[row, j]) v_j`` over its pool row's
+    history and ``SiLU(q.k_self + self_bias) v_self``, over ``n_norm``.
+
+    Operands as :func:`fused_cached_attention` (1-D dedup and 2-D packed
+    ``row_index`` welcome); ``bias_hist`` [U, 1, S] f32 (one row per pool
+    row, shared by its candidates and heads); ``self_bias`` a scalar."""
+    return _pointwise_attention(
+        q, k_hist, v_hist, k_cand, v_cand, bias_hist, mode="cached",
+        n_norm=n_norm, self_bias=self_bias, k_scale=k_scale,
+        v_scale=v_scale, row_index=row_index, path=path, bk=bk,
+        interpret=interpret)
+
+
+def hstu_extend_attention(q, k_prefix, v_prefix, k_suffix, v_suffix,
+                          bias_prefix, bias_suffix, *, n_norm: float,
+                          k_scale=None, v_scale=None, path: str = "auto",
+                          bk: int = 128, interpret=None):
+    """HSTU causal suffix attention against pooled prefix K/V:
+    ``bias_prefix`` [B, M, P] and ``bias_suffix`` [B, M, M] f32, one row
+    per suffix query (entries above the diagonal are never read)."""
+    return _pointwise_attention(
+        q, k_prefix, v_prefix, k_suffix, v_suffix, bias_prefix,
+        mode="extend", n_norm=n_norm, bias_cand=bias_suffix,
+        k_scale=k_scale, v_scale=v_scale, path=path, bk=bk,
+        interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# public entry points
+# ---------------------------------------------------------------------------
+
+def _route(q, k_hist, row_index, mode: str, path: str):
+    """(path, bq) of one call: a 2-D (segment-packed) index takes the
+    kernel only under a declared alignment, with q blocks that size;
+    ``auto`` is the kernel where it compiles for real."""
     bq = 128
     if row_index is not None and row_index.ndim == 2:
         if mode != "cached":
@@ -388,12 +603,25 @@ def _fused_attention(q, k_hist, v_hist, k_cand, v_cand, *, mode: str,
                          "impls in core/sumi.py)")
     if path == "auto":
         path = _auto_path()
+    if path not in ("kernel", "jnp"):
+        raise ValueError(f"path must be auto|kernel|jnp, got {path!r}")
+    return path, bq
+
+
+def _fused_attention(q, k_hist, v_hist, k_cand, v_cand, *, mode: str,
+                     k_scale=None, v_scale=None, row_index=None,
+                     lengths=None, temperature=None, path: str = "auto",
+                     interpret=None):
+    if temperature is not None:
+        q = q / jnp.asarray(temperature, q.dtype)
+    u, hkv = k_hist.shape[0], k_hist.shape[2]
+    ks = _norm_scale(k_scale, u, hkv)
+    vs = _norm_scale(v_scale, u, hkv)
+    path, bq = _route(q, k_hist, row_index, mode, path)
     if path == "kernel":
         return _fused_kernel_call(q, k_hist, v_hist, k_cand, v_cand,
                                   ks, vs, row_index, lengths, mode, bq,
                                   128, interpret)
-    if path != "jnp":
-        raise ValueError(f"path must be auto|kernel|jnp, got {path!r}")
     return _fused_jnp(q, k_hist, v_hist, k_cand, v_cand, ks, vs,
                       row_index, lengths, mode)
 

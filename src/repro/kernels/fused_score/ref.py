@@ -122,3 +122,54 @@ def extend_reference(q, k_prefix, v_prefix, k_suffix, v_suffix, *,
     k = jnp.concatenate([kp, k_suffix.astype(dtype)], axis=1)
     v = jnp.concatenate([vp, v_suffix.astype(dtype)], axis=1)
     return A.reference_attention(q, k, v, "causal", q_offset=p0)
+
+
+def pointwise_reference(q, k_hist, v_hist, k_cand, v_cand, bias_hist, *,
+                        n_norm: float, mode: str = "cached", self_bias=0.0,
+                        bias_cand=None, k_scale=None, v_scale=None,
+                        row_index=None):
+    """Pointwise (HSTU) oracle: dequantize -> gather (1-D per batch row or
+    2-D per candidate) -> one materialized [.., S + M] score row per query
+    with its bias and mask -> ``SiLU(s + bias) / n_norm`` weights ->
+    ``@ v``.  ``bias_hist`` [U, 1 or M, S]; cached mode sees the history
+    and the query's own key (``self_bias``), extend mode the history and
+    the causal suffix (``bias_cand`` [B, M, M])."""
+    f32 = jnp.float32
+    kh = dequantize_values(k_hist, k_scale, f32)
+    vh = dequantize_values(v_hist, v_scale, f32)
+    bh = jnp.asarray(bias_hist, f32)
+    b, m, h, d = q.shape
+    hkv = k_cand.shape[2]
+    g = h // hkv
+    if row_index is not None:
+        kh = jnp.take(kh, row_index, axis=0)
+        vh = jnp.take(vh, row_index, axis=0)
+        bh = jnp.take(bh, row_index, axis=0)
+    if kh.ndim == 4:                    # shared per batch row -> [B,M,...]
+        kh = jnp.broadcast_to(kh[:, None], (b, m) + kh.shape[1:])
+        vh = jnp.broadcast_to(vh[:, None], (b, m) + vh.shape[1:])
+        bh = jnp.broadcast_to(bh, (b, m, bh.shape[-1]))
+    else:
+        bh = bh[:, :, 0]
+    s = kh.shape[2]
+    qf = q.astype(f32).reshape(b, m, hkv, g, d)
+    kc = k_cand.astype(f32)
+    vc = v_cand.astype(f32)
+    s_hist = jnp.einsum("bmhgd,bmshd->bmhgs", qf, kh) \
+        + bh[:, :, None, None, :]
+    if mode == "cached":
+        s_self = jnp.einsum("bmhgd,bmhd->bmhg", qf, kc) + self_bias
+        w_self = jax.nn.silu(s_self) / n_norm
+        o = jnp.einsum("bmhgs,bmshd->bmhgd", jax.nn.silu(s_hist) / n_norm,
+                       vh)
+        o = o + w_self[..., None] * vc[:, :, :, None, :]
+    else:
+        s_suf = jnp.einsum("bmhgd,bshd->bmhgs", qf, kc) \
+            + jnp.asarray(bias_cand, f32)[:, :, None, None, :]
+        causal = jnp.arange(m)[None, :] <= jnp.arange(m)[:, None]
+        w_suf = jnp.where(causal[None, :, None, None, :],
+                          jax.nn.silu(s_suf), 0.0) / n_norm
+        o = jnp.einsum("bmhgs,bmshd->bmhgd", jax.nn.silu(s_hist) / n_norm,
+                       vh)
+        o = o + jnp.einsum("bmhgs,bshd->bmhgd", w_suf, vc)
+    return o.reshape(b, m, h, d).astype(q.dtype)

@@ -8,8 +8,9 @@
     bundle.input_specs(shape_cfg) / bundle.cache_init(...)   # dry-run stand-ins
 
 Families: text decoders (dense/moe/hybrid/ssm/vlm) share one implementation
-(vlm prepends stub patch embeddings); audio is encoder-decoder; climber (the
-paper's GR model) is provided by repro.core.climber and dispatched here.
+(vlm prepends stub patch embeddings); audio is encoder-decoder; the GR
+models climber (the paper's) and hstu are provided by repro.core.climber
+and repro.core.hstu and dispatched here.
 """
 from __future__ import annotations
 
@@ -26,6 +27,24 @@ from repro.models import encdec as E
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.types import ModelConfig, ShapeConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class HostPlane:
+    """One host-side input of the split serving surface, one row per
+    request: the batch key the surface reads it under, where the serving
+    engine takes it from, and its row's width (0: one value per history
+    window position) and dtype.
+
+    Sources: ``window`` (the request's history window ids),
+    ``side_features`` (the PDA side-feature mean over the history) and
+    ``action_times`` (the per-action timestamps: the request's own, else
+    the PDA user-history service's)."""
+
+    name: str
+    source: str
+    width: int = 0
+    dtype: str = "int32"
 
 
 @dataclasses.dataclass
@@ -51,6 +70,9 @@ class ModelBundle:
     # score_candidates(M=V) at the beam's current length plus append_token
     decode_logits: Optional[Callable] = None    # (params, kv, cand, lengths) -> probs [B,M,T]
     append_token: Optional[Callable] = None     # (params, kv, tok, lengths) -> HistoryKV
+    # the host planes the split surface's batches carry, in executor
+    # argument order (encode/extend/full executors take one array each)
+    host_planes: Tuple[HostPlane, ...] = ()
 
 
 def cross_entropy(logits, targets, mask):
@@ -245,6 +267,9 @@ def build_model(cfg: ModelConfig) -> ModelBundle:
     if cfg.family == "climber":
         from repro.core.climber import build_climber
         return build_climber(cfg)
+    if cfg.family == "hstu":
+        from repro.core.hstu import build_hstu
+        return build_hstu(cfg)
     if cfg.enc_dec:
         return _build_audio(cfg)
     return _build_text(cfg)

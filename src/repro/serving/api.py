@@ -100,6 +100,11 @@ class ServeRequest:
     ``deadline_misses`` metric; ``None`` defers to the engine's default
     budget (which may be "no deadline").
 
+    ``timestamps`` optionally gives the time (int seconds, on the clock
+    of ``core/pda.py``'s ``REQUEST_TIME``) of each history action, aligned
+    with ``history``; models that read action times (HSTU) take them from
+    here, else from PDA's user-history service.
+
     ``slo_tier`` (one of :data:`SLO_TIERS`) places the request on a service
     tier: tier-aware engines derive a default deadline from it (when
     ``deadline_s`` is None), order EDF admission ties by tier, shed
@@ -117,6 +122,7 @@ class ServeRequest:
     # ``output`` is then ``[width, steps]`` generated item ids, best-first.
     generate: Optional[object] = None
     user_id: Optional[int] = None
+    timestamps: Optional[np.ndarray] = None
     deadline_s: Optional[float] = None
     slo_tier: str = "standard"
     request_id: int = dataclasses.field(
@@ -274,8 +280,8 @@ class ServeMetrics:
     def add_time(self, stage: str, seconds: float):
         """One timed pass through a host stage: adds ``seconds`` to the
         counter ``<stage>_s`` and 1 to ``<stage>_n``: ``admit`` (span
-        ``flame.admit``), ``features`` (span ``flame.pda.features``) and
-        ``service`` (a worker's time per request after the admission
+        ``flame.admit``), ``features`` (span ``flame.pda.features``),
+        ``times`` (span ``flame.pda.times``) and ``service`` (a worker's time per request after the admission
         queue; no span, it holds waits).  Both only grow, so a window's
         mean is the ratio of their deltas."""
         with self._lock:

@@ -12,7 +12,8 @@ and differs only in the execute stage:
                              query, coalescing DSO over batch-axis AOT
                              executors (chunks from *different* in-flight
                              requests share one dispatch), SUMI-masked
-                             Climber forward, per-candidate task scores;
+                             forward of the bundle's model (Climber,
+                             HSTU), per-candidate task scores;
   ImplicitShapeServingEngine Table 5 "Default" — plain jit over the full
                              model, retrace+recompile per novel M, wrapped
                              in the same pipeline for A/B comparison;
@@ -38,6 +39,11 @@ Executors are AOT-compiled per ``(kind, bucket)``:
   ("extend", prefix_len) PDA v2 incremental path: re-encode only the window
                          suffix + side token against a stale entry's cached
                          prefix K/V (bucket = trusted prefix length)
+
+Executors take the host planes the bundle declares
+(``ModelBundle.host_planes``), in order: Climber's ``(history, side)``,
+HSTU's ``(history, times)``; the engine fills each from the request, PDA's
+side-feature query or PDA's user-history service.
 
 ``_pad_slice(request, chunk, kind)`` produces one chunk's host/device args
 (leading axis 1); ``_gather(rows, chunks, m, kind)`` reassembles per-request
@@ -72,7 +78,6 @@ import numpy as np
 from repro import sharding as shd
 from repro.core import dso as DSO
 from repro.core import pda as PDA
-from repro.core.climber import N_SIDE_FEATURES
 from repro.models.model import ModelBundle
 from repro.serving.api import (SLO_TIERS, TIER_RANK, AdmissionQueueFull,
                                DeadlineExceeded, DegradedError,
@@ -286,7 +291,8 @@ class _PipelinedEngine:
             bad = set(slo_tier_defaults) - set(SLO_TIERS)
             if bad:
                 raise ValueError(f"unknown SLO tiers in defaults: {bad}")
-        self._metrics = ServeMetrics(stages=("admit", "features", "service"))
+        self._metrics = ServeMetrics(
+            stages=("admit", "features", "times", "service"))
         self._admission = _AdmissionQueue(max_pending, mode=admission)
         self._shed = shed_policy == "tiered"
         self._tier_defaults = dict(slo_tier_defaults) \
@@ -553,16 +559,64 @@ class _PipelinedEngine:
 
 
 def _make_features(feature_mode: str, store, cache_capacity: int,
-                   cache_ttl_s: float):
-    store = store or PDA.RemoteFeatureStore(feature_dim=N_SIDE_FEATURES)
+                   cache_ttl_s: float, feature_dim: int):
+    store = store or PDA.RemoteFeatureStore(feature_dim=feature_dim)
     cache = None if feature_mode == "off" else PDA.BucketedLRUCache(
         cache_capacity, cache_ttl_s)
     return store, PDA.FeatureQueryEngine(store, cache, mode=feature_mode)
 
 
-class _SideFeatureMixin:
-    """PDA in action: fetch item features for the history, aggregate into
-    the request's side-feature vector (user-profile style)."""
+class _HostPlaneMixin:
+    """The host planes a bundle declares (``ModelBundle.host_planes``),
+    filled per request: the history window, PDA in action (item features
+    of the history aggregated into the side-feature vector, user-profile
+    style) and per-action timestamps (the request's own, else PDA's
+    user-history service)."""
+
+    def _init_planes(self, bundle: ModelBundle, feature_mode: str, store,
+                     cache_capacity: int, cache_ttl_s: float):
+        self._planes = tuple(bundle.host_planes)
+        unknown = {p.source for p in self._planes} - {
+            "window", "side_features", "action_times"}
+        if not self._planes or unknown:
+            raise ValueError(
+                f"model {bundle.cfg.name!r} declares host planes "
+                f"{self._planes}: every split-surface bundle declares its "
+                f"planes, from window / side_features / action_times")
+        side = [p for p in self._planes if p.source == "side_features"]
+        self._side_width = side[0].width if side else 0
+        self.store, self.features = _make_features(
+            feature_mode, store, cache_capacity, cache_ttl_s,
+            self._side_width)
+
+    def _plane_specs(self, batch: int) -> tuple:
+        """AOT argument specs of the planes for ``batch`` rows."""
+        return tuple(jax.ShapeDtypeStruct(
+            (batch, p.width or self.n_history), jnp.dtype(p.dtype))
+            for p in self._planes)
+
+    def _host_planes(self, req: ServeRequest, hist: np.ndarray) -> tuple:
+        """Each declared plane of one request, [1, width] in its dtype;
+        ``hist`` is the request's [1, n_history] window."""
+        out = []
+        for p in self._planes:
+            if p.source == "window":
+                out.append(hist)
+            elif p.source == "side_features":
+                out.append(self._side_features(req.history, req.request_id))
+            else:
+                out.append(self._action_times(req, hist))
+        return tuple(out)
+
+    def _plane_batch(self, planes) -> dict:
+        """The surface's batch dict of the declared planes, in order."""
+        return {p.name: a for p, a in zip(self._planes, planes)}
+
+    def _window(self, planes: tuple) -> np.ndarray:
+        """What a pool entry encoded, position by position: the window ids
+        [n], or with per-action planes besides (action times) [P, n]."""
+        rows = [a[0] for a, p in zip(planes, self._planes) if not p.width]
+        return rows[0] if len(rows) == 1 else np.stack(rows)
 
     def _check_request(self, req: ServeRequest):
         """Reject malformed requests before their chunks reach the shared
@@ -592,6 +646,11 @@ class _SideFeatureMixin:
                 f"request {req.request_id}: history must be a 1-D id array "
                 f"with >= n_history={self.n_history} entries, got "
                 f"{req.history.shape}")
+        if req.timestamps is not None and \
+                req.timestamps.shape != req.history.shape:
+            raise ValueError(
+                f"request {req.request_id}: timestamps must align with the "
+                f"history {req.history.shape}, got {req.timestamps.shape}")
 
     def _side_features(self, history: np.ndarray,
                        request_id: int = -1) -> np.ndarray:
@@ -599,12 +658,26 @@ class _SideFeatureMixin:
             feats = self.features.query([int(i) for i in history])
             got = [v for v in feats.values() if v is not None]
             side = np.mean(got, axis=0, keepdims=True).astype(np.float32) \
-                if got else np.zeros((1, N_SIDE_FEATURES), np.float32)
+                if got else np.zeros((1, self._side_width), np.float32)
         self._metrics.add_time("features", st.s)
         return side
 
+    def _action_times(self, req: ServeRequest, hist: np.ndarray
+                      ) -> np.ndarray:  # flamecheck: host-sync-ok(request timestamps arrive as host numpy; the service's lookup is host numpy)
+        """[1, n] int32 times of the window's actions: the request's own
+        ``timestamps``, else PDA's user-history service."""
+        with DSO.stage("pda.times", request_id=req.request_id) as st:
+            if req.timestamps is not None:
+                t = np.asarray(req.timestamps[None, :hist.shape[1]],
+                               np.int32)
+            else:
+                t = PDA.action_times(req.user_id, hist[0])[None]
+        self._metrics.add_time("times", st.s)
+        return t
+
     def _admit_hook(self, request: ServeRequest):
-        self.features.prefetch([int(i) for i in request.history])
+        if self._side_width:
+            self.features.prefetch([int(i) for i in request.history])
 
 
 class _Beam:
@@ -648,8 +721,9 @@ class _ParamsBound:
 
 
 @register_engine("flame")
-class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
-    """PDA -> coalescing DSO -> Climber, per the paper's Fig 1/Fig 4.
+class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
+    """PDA -> coalescing DSO -> the bundle's GR model (Climber, HSTU), per
+    the paper's Fig 1/Fig 4.
 
     Executors are AOT-compiled with a real batch axis ``(max_batch,
     bucket)``; the DSO dispatcher merges same-bucket chunks from different
@@ -850,8 +924,8 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 "steers each candidate segment to its own user's POOLED "
                 "history KV — the monolithic full-pass family has no "
                 "per-user KV rows to steer to")
-        self.store, self.features = _make_features(
-            feature_mode, store, cache_capacity, cache_ttl_s)
+        self._init_planes(bundle, feature_mode, store, cache_capacity,
+                          cache_ttl_s)
 
         self.history_pool: Optional[HistoryKVPool] = None
         self._extend_buckets: tuple = ()
@@ -947,8 +1021,10 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                     "would reshard on every append")
             if bundle.decode_logits is None or bundle.append_token is None:
                 raise ValueError(
-                    "generate>0 needs a bundle with the decode_logits/"
-                    "append_token generative serving surface")
+                    f"generate>0 needs a bundle with the decode_logits/"
+                    f"append_token generative serving surface; model "
+                    f"{bundle.cfg.name!r} (family {bundle.cfg.family!r}) "
+                    f"has none")
             # decode/append executors speak PADDED beam caches: the cached
             # row specs with ``generate`` extra slots on the sequence axis,
             # filled one per appended token (valid prefix = lengths).
@@ -968,10 +1044,6 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
         # module count is process-wide and may predate this engine
         self._reroutes_seen = packed_reroute_count()
 
-        hist_spec = lambda batch: jax.ShapeDtypeStruct(  # noqa: E731
-            (batch, n_history), jnp.int32)
-        side_spec = lambda batch: jax.ShapeDtypeStruct(  # noqa: E731
-            (batch, N_SIDE_FEATURES), jnp.float32)
         _batched = lambda specs, batch: tuple(  # noqa: E731
             jax.ShapeDtypeStruct((batch,) + s.shape[1:], s.dtype)
             for s in specs)
@@ -986,15 +1058,14 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
             # by the traced function would be embedded in each program as
             # HLO constants — a copy of the item embedding per executable
             if kind == "full":
-                def fn(params, history, candidates, side):
-                    b = {"history": history,
-                         # -1 chunk-padding sentinel -> a real (ignored) row
-                         "candidates": jnp.maximum(candidates, 0),
-                         "side": side}
+                def fn(params, *args):
+                    *planes, candidates = args
+                    # -1 chunk-padding sentinel -> a real (ignored) row
+                    b = dict(self._plane_batch(planes),
+                             candidates=jnp.maximum(candidates, 0))
                     return bundle.prefill(params, b, impl=self.impl)
-                shapes = (hist_spec(batch),
-                          jax.ShapeDtypeStruct((batch, bucket), jnp.int32),
-                          side_spec(batch))
+                shapes = self._plane_specs(batch) + (
+                    jax.ShapeDtypeStruct((batch, bucket), jnp.int32),)
             elif kind == "encode":
                 # Under the fused impl the executor quantizes IN-EPILOGUE
                 # (FKE v2): its output is the pool's stored representation
@@ -1002,33 +1073,31 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 # miss pools what it just computed via put(prequantized=
                 # True) and scores from the same leaves, with no separate
                 # quantize pass and no raw read-back
-                def fn(params, history, side):
+                def fn(params, *planes):
                     kv = bundle.encode_history(
-                        params, {"history": history, "side": side},
-                        impl=self.impl)
+                        params, self._plane_batch(planes), impl=self.impl)
                     if self._fused:
                         kv = quantize_kv_graph(kv, self.history_pool.dtype)
                     return kv
-                shapes = (hist_spec(batch), side_spec(batch))
+                shapes = self._plane_specs(batch)
             elif kind == "extend":
                 # bucket = trusted prefix length: re-encode window positions
-                # >= bucket (plus the side token) against the cached prefix.
+                # >= bucket (plus any side token) against the cached prefix.
                 # Under the fused impl the basis arrives RAW (the pool's
                 # stored int8/bf16 leaves + scales, 4x fewer dispatch bytes
                 # for int8) and dequantizes in-graph inside extend_history
                 def fn(params, *args):
-                    *kv_leaves, history, side = args
+                    n_kv = len(self._cached_row_specs)
                     kv = jax.tree.unflatten(self._cached_treedef,
-                                            list(kv_leaves))
+                                            list(args[:n_kv]))
                     out = bundle.extend_history(
-                        params, kv, {"history": history, "side": side},
+                        params, kv, self._plane_batch(args[n_kv:]),
                         prefix_len=bucket, impl=self.impl)
                     if self._fused:
                         # in-epilogue re-quantize: same contract as encode
                         out = quantize_kv_graph(out, self.history_pool.dtype)
                     return out
-                shapes = cached_row_shapes(batch) + (hist_spec(batch),
-                                                     side_spec(batch))
+                shapes = cached_row_shapes(batch) + self._plane_specs(batch)
             elif kind == "cached":
                 if self._pack_tails:
                     # DSO v2 segment-packed signature: one row may carry
@@ -1268,7 +1337,8 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
 
     def _pool_key(self, request: ServeRequest
                   ):  # flamecheck: host-sync-ok(admission-time canonicalization: histories arrive as host numpy and the content hash must read host bytes)
-        fp = self._fingerprint(np.asarray(request.history, np.int32))
+        fp = self._fingerprint(np.asarray(request.history, np.int32),
+                               request.timestamps)
         key = ("u", int(request.user_id)) \
             if request.user_id is not None else ("h", fp)
         return key, fp
@@ -1299,14 +1369,14 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
 
     def _pad_slice(self, request, chunk: DSO.Chunk, kind: str):
         if kind == "encode":
-            history, side = request
-            return history, side
+            return tuple(request)                # the host planes
         if kind == "extend":
-            kv_leaves, history, side = request
-            return tuple(kv_leaves) + (history, side)
+            kv_leaves, planes = request
+            return tuple(kv_leaves) + tuple(planes)
         if kind == "full":
-            history, candidates, side = request
-            return history, self._slice_candidates(candidates, chunk), side
+            planes, candidates = request
+            return tuple(planes) + (self._slice_candidates(candidates,
+                                                           chunk),)
         if kind == "append":
             kv_leaves, lengths, tokens = request
             return tuple(kv_leaves) + (lengths, tokens)
@@ -1335,22 +1405,29 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
 
     # ---- history-KV pool ----
     @staticmethod
-    def _fingerprint(history: np.ndarray) -> str:
+    def _fingerprint(history: np.ndarray,
+                     timestamps: Optional[np.ndarray] = None) -> str:
         """Content hash of the FULL history array — the model truncates to
         n_history, but side features average over every entry, so a
-        tail-only change must read as stale too (full-pass parity)."""
-        return hashlib.blake2b(np.ascontiguousarray(history).tobytes(),
-                               digest_size=16).hexdigest()
+        tail-only change must read as stale too (full-pass parity) — and
+        of the request's own action times, where it gives them."""
+        h = hashlib.blake2b(np.ascontiguousarray(history).tobytes(),
+                            digest_size=16)
+        if timestamps is not None:
+            h.update(np.ascontiguousarray(timestamps, np.int32).tobytes())
+        return h.hexdigest()
 
     @staticmethod
     def _shared_prefix(cached: Optional[np.ndarray], new: np.ndarray
                        ) -> int:  # flamecheck: host-sync-ok(prefix diff of two host-resident id windows; no device arrays involved)
-        """Length of the common leading run of two history windows (-1 when
-        no basis window is available)."""
+        """Length of the common leading run of two history windows, [n] ids
+        or [P, n] per-action planes (-1 when no basis window is
+        available)."""
         if cached is None or cached.shape != new.shape:
             return -1
-        neq = np.nonzero(np.asarray(cached) != np.asarray(new))[0]
-        return int(neq[0]) if neq.size else int(new.shape[0])
+        diff = np.asarray(cached) != np.asarray(new)
+        neq = np.nonzero(diff.reshape(-1, new.shape[-1]).any(axis=0))[0]
+        return int(neq[0]) if neq.size else int(new.shape[-1])
 
     def _cached_rows(self, kv) -> tuple:
         """Flatten a pool lookup result into the cached-executor arg order.
@@ -1410,13 +1487,14 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                                               _retry=False)
         try:
             t0 = time.perf_counter()
-            side = self._side_features(req.history, req.request_id)
+            planes = self._host_planes(req, hist)
+            window = self._window(planes)
             t1 = time.perf_counter()
             kv_tree, path, refreshes = None, "encode", 0
             if basis is not None and self._extend_buckets:
                 # stale hit sharing a window prefix with the dropped entry:
-                # re-encode only the suffix + side token against its K/V
-                shared = self._shared_prefix(basis.hist_window, hist[0])
+                # re-encode only the suffix (+ side token) against its K/V
+                shared = self._shared_prefix(basis.hist_window, window)
                 bucket = max((b for b in self._extend_buckets if b <= shared),
                              default=None)
                 if bucket is not None and self._extend_refresh_limit and \
@@ -1427,7 +1505,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                     self.history_pool.count_refresh_reencode()
                 if bucket is not None:
                     basis_leaves = tuple(jax.tree.leaves(basis.kv))
-                    kv_tree = self.dso.score((basis_leaves, hist, side),
+                    kv_tree = self.dso.score((basis_leaves, planes),
                                              bucket, kind="extend",
                                              deadline=deadline,
                                              tier=req.slo_tier)
@@ -1435,7 +1513,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                     refreshes = basis.refreshes + 1
                     self.history_pool.count_extension()
             if kv_tree is None:
-                kv_tree = self.dso.score((hist, side), self.n_history,
+                kv_tree = self.dso.score(planes, self.n_history,
                                          kind="encode", deadline=deadline,
                                          tier=req.slo_tier)
             # device-placed pools get the executor's own per-row output
@@ -1456,11 +1534,11 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
                 self._pool_put(
                     key, fp, jax.tree.unflatten(self._cached_treedef,
                                                 list(kv)),
-                    hist_window=hist[0], refreshes=refreshes,
+                    hist_window=window, refreshes=refreshes,
                     prequantized=True,
                     compute_dtype=self._kv_compute_dtype)
             else:
-                self._pool_put(key, fp, kv, hist_window=hist[0],
+                self._pool_put(key, fp, kv, hist_window=window,
                                refreshes=refreshes)
             self._metrics.set_gauge("pool_bytes_used",
                                     self.history_pool.bytes_used)
@@ -1499,9 +1577,9 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
         cand = np.asarray(req.candidates[None],
                           np.int32)  # flamecheck: host-sync-ok(request arrays arrive as host numpy; dtype canonicalized once at admission)
         if self.history_pool is None:
-            side = self._side_features(req.history, req.request_id)
+            planes = self._host_planes(req, hist)
             t1 = time.perf_counter()
-            out = self.dso.score((hist, cand, side), req.m, kind="full",
+            out = self.dso.score((planes, cand), req.m, kind="full",
                                  deadline=deadline, tier=req.slo_tier)
             t2 = time.perf_counter()
             return out[0], {"features_s": t1 - t0, "execute_s": t2 - t1}
@@ -1904,7 +1982,7 @@ class FlameEngine(_SideFeatureMixin, _PipelinedEngine):
 
 
 @register_engine("implicit")
-class ImplicitShapeServingEngine(_SideFeatureMixin, _PipelinedEngine):
+class ImplicitShapeServingEngine(_HostPlaneMixin, _PipelinedEngine):
     """Table 5 "Default" — plain jit over the full model: every novel
     candidate count M retraces + recompiles in-band (the XLA analogue of
     TensorRT implicit-shape dynamic (re)allocation).  Same pipeline and
@@ -1920,10 +1998,11 @@ class ImplicitShapeServingEngine(_SideFeatureMixin, _PipelinedEngine):
         self.params = params
         self.n_history = n_history
         self.impl = impl
-        self.store, self.features = _make_features(
-            feature_mode, store, cache_capacity, cache_ttl_s)
-        self._fn = jax.jit(lambda h, c, s: bundle.prefill(
-            params, {"history": h, "candidates": c, "side": s}, impl=impl))
+        self._init_planes(bundle, feature_mode, store, cache_capacity,
+                          cache_ttl_s)
+        self._fn = jax.jit(lambda planes, c: bundle.prefill(
+            params, dict(self._plane_batch(planes), candidates=c),
+            impl=impl))
         self.compiles = 0
         self._seen: set = set()
         self._seen_lock = threading.Lock()
@@ -1934,15 +2013,15 @@ class ImplicitShapeServingEngine(_SideFeatureMixin, _PipelinedEngine):
                  ):  # flamecheck: host-sync-ok(Table-5 Default baseline: per-request jit + sync is the comparison point, not a defect)
         self._check_request(req)
         t0 = time.perf_counter()
-        side = self._side_features(req.history, req.request_id)
+        planes = self._host_planes(
+            req, np.asarray(req.history[None, :self.n_history], np.int32))
         t1 = time.perf_counter()
         with self._seen_lock:
             if req.m not in self._seen:
                 self._seen.add(req.m)
                 self.compiles += 1
-        hist = jnp.asarray(req.history[None, :self.n_history], jnp.int32)
         cand = jnp.asarray(req.candidates[None], jnp.int32)
-        out = self._fn(hist, cand, jnp.asarray(side))
+        out = self._fn(tuple(jnp.asarray(a) for a in planes), cand)
         jax.block_until_ready(out)
         t2 = time.perf_counter()
         return np.asarray(out)[0], {"features_s": t1 - t0,

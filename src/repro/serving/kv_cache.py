@@ -43,7 +43,8 @@ dropping, and a later hit promotes back (counted as ``spill_hits``) —
 
 *Quantization.*  ``dtype`` selects the stored precision: ``"native"``
 (compute dtype), ``"bf16"``, or ``"int8"`` with a per-(layer, head)
-absmax scale.  Dequantization happens at lookup (on device under device
+absmax scale.  Only floating leaves (K/V) are quantized: integer leaves
+(an entry's action times) are stored as they are.  Dequantization happens at lookup (on device under device
 placement), so executor input signatures never change; int8 roughly
 quadruples users-per-budget vs f32 at a bounded score drift (asserted in
 tests/test_pda_v2.py, measured in BENCH_serving.json).
@@ -135,12 +136,19 @@ def _scale_axes(ndim: int) -> Tuple[int, ...]:
     return tuple(range(ndim))                # fallback: one global scale
 
 
+def _stored_as_is(a, dtype: str) -> bool:
+    """Whether a leaf keeps its own dtype in the pool: every leaf of a
+    ``native`` pool, and integer leaves (action times) of any pool."""
+    return dtype == "native" or not jnp.issubdtype(a.dtype, jnp.floating)
+
+
 def quantize_leaf(a, dtype: str):
     """Quantize one KV leaf to the pool's stored precision.
 
-    Returns the stored representation: the array itself for ``native``, a
-    bf16 cast for ``bf16``, or a :class:`_QuantLeaf` for ``int8``."""
-    if dtype == "native":
+    Returns the stored representation: the array itself for ``native`` (or
+    an integer leaf), a bf16 cast for ``bf16``, or a :class:`_QuantLeaf`
+    for ``int8``."""
+    if _stored_as_is(a, dtype):
         return a
     a = jnp.asarray(a)
     if dtype == "bf16":
@@ -191,6 +199,8 @@ def quantize_kv_graph(kv, dtype: str):
 
     def one(a):
         a = jnp.asarray(a)
+        if _stored_as_is(a, dtype):
+            return a
         if dtype == "bf16":
             return (a.astype(jnp.bfloat16), None)
         if dtype == "int8":
@@ -230,7 +240,7 @@ def quantized_nbytes(
     total = 0
     for a in jax.tree.leaves(kv):
         n = _shard_elems(a.shape, shard_spec)
-        if dtype == "native":
+        if dtype in POOL_DTYPES and _stored_as_is(a, dtype):
             total += n * jnp.dtype(a.dtype).itemsize
         elif dtype == "bf16":
             total += n * 2
@@ -269,7 +279,7 @@ def raw_kv_specs(kv_specs, dtype: str):
     pool storing ``dtype`` — what a quantization-aware AOT executor is
     compiled against (shape/dtype arithmetic only)."""
     def one(spec):
-        if dtype == "native":
+        if dtype in POOL_DTYPES and _stored_as_is(spec, dtype):
             return spec
         if dtype == "bf16":
             return (jax.ShapeDtypeStruct(spec.shape, jnp.bfloat16), None)

@@ -40,6 +40,18 @@ class ClimberConfig:
 
 
 @dataclass(frozen=True)
+class HSTUConfig:
+    """HSTU settings (Zhai et al., ICML 2024, arXiv:2402.17152).
+
+    Queries and keys are ``head_dim`` wide and so are the values and gates
+    (the paper's ``dqk == dv``); ``d_ff`` is unused (HSTU has no FFN)."""
+
+    max_seq_len: int = 4096      # N: attention weights are SiLU(.) / N
+    num_time_buckets: int = 128  # bucket(dt) = min(floor(ln|dt| / 0.301), 128)
+    num_tasks: int = 3           # ranking head outputs (sigmoid each)
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Architecture description.
 
@@ -50,7 +62,7 @@ class ModelConfig:
     """
 
     name: str
-    family: str                     # dense | vlm | ssm | audio | moe | hybrid | climber
+    family: str                     # dense | vlm | ssm | audio | moe | hybrid | climber | hstu
     n_layers: int
     d_model: int
     n_heads: int
@@ -66,6 +78,7 @@ class ModelConfig:
     layer_pattern: Tuple[str, ...] = ("attn",)
     moe: Optional[MoEConfig] = None
     climber: Optional[ClimberConfig] = None
+    hstu: Optional[HSTUConfig] = None
     # --- encoder-decoder (audio) ---
     enc_dec: bool = False
     n_enc_layers: int = 0

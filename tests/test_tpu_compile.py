@@ -139,3 +139,132 @@ def test_full_depth_fused_score_step_fits_one_chip(one_chip, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES, mem
+
+
+# ---------------------------------------------------------------------------
+# the pointwise (HSTU) variant
+# ---------------------------------------------------------------------------
+
+HSTU_S = 4096                   # hstu-large-4k's history window
+
+
+@pytest.mark.parametrize("mode", ["cached", "extend"])
+def test_hstu_kernel_compiles_under_its_own_op_name(one_chip, mode):
+    """The pointwise variant compiles to a Pallas call whose HLO op is
+    named ``%_hstu_kernel_call`` (the trace tells its calls apart by it):
+    cached over an int8 pool with a packed, 8-aligned seg index; extend
+    of a 1,024-action suffix over a 3,072-action prefix."""
+    import re
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    if mode == "cached":
+        fn = functools.partial(fs_ops.hstu_cached_attention, n_norm=4096.0,
+                               path="kernel", interpret=False)
+        args = [_spec((1, M, H, D), bf16, one_chip),
+                _spec((B, HSTU_S, H, D), jnp.int8, one_chip),
+                _spec((B, HSTU_S, H, D), jnp.int8, one_chip),
+                _spec((1, M, H, D), bf16, one_chip),
+                _spec((1, M, H, D), bf16, one_chip),
+                _spec((B, 1, HSTU_S), f32, one_chip),
+                _spec((), f32, one_chip)]
+        kw = {"k_scale": _spec((B, 1, H, 1), f32, one_chip),
+              "v_scale": _spec((B, 1, H, 1), f32, one_chip),
+              "row_index": _spec((1, M), jnp.int32, one_chip)}
+    else:
+        fn = functools.partial(fs_ops.hstu_extend_attention, n_norm=4096.0,
+                               path="kernel", interpret=False)
+        suf, pre = 1024, HSTU_S - 1024
+        args = [_spec((B, suf, H, D), bf16, one_chip),
+                _spec((B, pre, H, D), bf16, one_chip),
+                _spec((B, pre, H, D), bf16, one_chip),
+                _spec((B, suf, H, D), bf16, one_chip),
+                _spec((B, suf, H, D), bf16, one_chip),
+                _spec((B, suf, pre), f32, one_chip),
+                _spec((B, suf, suf), f32, one_chip)]
+        kw = {}
+    prev = fs_ops.set_packed_alignment(8)
+    try:
+        text = jax.jit(fn).lower(*args, **kw).compile().as_text()
+    finally:
+        fs_ops.set_packed_alignment(prev)
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(re.match(r"\s*%_hstu_kernel_call\b", ln)
+                         for ln in calls), calls
+
+
+def test_full_depth_hstu_steps_fit_one_chip(one_chip, monkeypatch):
+    """hstu-large at a 4,096-action window (8 layers, d 256, 4 x 64 heads,
+    2M-item catalog): the engine's int8-epilogue encode of four rows and a
+    packed 512-candidate cached step over four int8 pool rows compile, the
+    scoring step holds the pointwise kernel, and each fits one chip."""
+    from repro.kernels.fused_score import bias_kernel
+    from repro.kernels.fused_score import kernel as fs_kernel
+    from repro.serving.kv_cache import quantize_kv_graph
+    from repro.types import HSTUConfig, ModelConfig
+    monkeypatch.setattr(fs_ops, "_auto_path", lambda: "kernel")
+    monkeypatch.setattr(fs_kernel, "default_interpret", lambda: False)
+    monkeypatch.setattr(bias_kernel, "default_interpret", lambda: False)
+
+    cfg = ModelConfig(name="hstu-large-4k", family="hstu", n_layers=8,
+                      d_model=256, n_heads=4, n_kv_heads=4, head_dim=64,
+                      d_ff=0, vocab_size=2_000_000, norm="layernorm",
+                      hstu=HSTUConfig(max_seq_len=HSTU_S))
+    bundle = build_model(cfg)
+    params = jax.eval_shape(lambda k: bundle.init(k)[0], jax.random.key(0))
+    kv = raw_kv_specs(bundle.history_kv_specs(params, HSTU_S, batch=B),
+                      "int8")
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda s: _spec(s.shape, s.dtype, one_chip), t)
+    plane = _spec((B, HSTU_S), jnp.int32, one_chip)
+
+    def encode(params, history, times):
+        return quantize_kv_graph(bundle.encode_history(
+            params, {"history": history, "times": times}, impl="fused"),
+            "int8")
+
+    def score(params, kv, seg, cands):
+        return bundle.score_candidates(params, kv, cands, impl="fused",
+                                       row_index=seg)
+
+    prev = fs_ops.set_packed_alignment(8)
+    try:
+        compiled = [
+            jax.jit(encode).lower(on_chip(params), plane, plane).compile(),
+            jax.jit(score).lower(
+                on_chip(params), on_chip(kv),
+                _spec((1, 512), jnp.int32, one_chip),
+                _spec((1, 512), jnp.int32, one_chip)).compile()]
+    finally:
+        fs_ops.set_packed_alignment(prev)
+    assert "%_hstu_kernel_call" in compiled[1].as_text()
+    # the bias tables are read with no XLA gather (one index at a time on
+    # the TPU): only embedding rows and the packed row index (64 entries)
+    import math
+    import re
+    for c in compiled:
+        for dims in re.findall(r"= \w+\[([\d,]+)\]\S* gather\(",
+                               c.as_text()):
+            shape = [int(x) for x in dims.split(",")]
+            assert shape[-1] == cfg.d_model or math.prod(shape) <= 64, dims
+    for c in compiled:
+        mem = c.memory_analysis()
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+        assert total < HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("n_q,n_k,offset", [(1, HSTU_S, HSTU_S),
+                                            (512, HSTU_S, 3584)])
+def test_hstu_relative_bias_kernel_compiles(one_chip, n_q, n_k, offset):
+    """The lane-gather bias kernel at the cached path's shape (one row per
+    pool row) and at an encode block's (512 queries over 4,096 keys)."""
+    import functools as ft
+    fn = ft.partial(fs_ops.hstu_relative_bias, offset=offset, path="kernel",
+                    interpret=False)
+    text = jax.jit(fn).lower(
+        _spec((HSTU_S + 1,), jnp.bfloat16, one_chip),
+        _spec((129,), jnp.bfloat16, one_chip),
+        _spec((B, n_q), jnp.int32, one_chip),
+        _spec((B, n_k), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text
