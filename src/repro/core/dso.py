@@ -79,6 +79,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.fused_score.ops import qblock_counts
+
 
 # ---------------------------------------------------------------------------
 # stage spans
@@ -637,6 +639,10 @@ class CoalescingOrchestrator:
         self.dedup_rows_saved = 0      # restacks avoided by dedup/packing
         self.packed_rows = 0           # rows carrying >= 1 packed segment
         self.packed_segments = 0       # segments dispatched via packing
+        # q blocks of packed rows in use whose sub-blocks read one pool row
+        # (the pointwise kernel scores them whole) or several
+        self.qblocks_uniform = 0
+        self.qblocks_mixed = 0
         self.ingraph_dispatches = 0    # KV rows handed over per slot
         self.queue_delay_total_s = 0.0
         self.queue_delay_count = 0
@@ -922,9 +928,11 @@ class CoalescingOrchestrator:
     def _note_dispatch(self, kind: str, bucket: int, n_chunks: int,
                        rows_used: int, valid: int, saved: int,
                        packed: bool, missed: int,
-                       stages: Dict[str, float]):
+                       stages: Dict[str, float],
+                       qblocks: Tuple[int, int] = (0, 0)):
         """Count one dispatch; ``stages`` holds its stage seconds (keys of
-        ``stage_s``), whose launch + wait is the cost model's sample."""
+        ``stage_s``), whose launch + wait is the cost model's sample;
+        ``qblocks`` its packed rows' (uniform, mixed) q blocks."""
         key = (kind, bucket)
         cost_s = stages["launch"] + stages["wait"]
         with self._stat_lock:
@@ -943,6 +951,8 @@ class CoalescingOrchestrator:
             if packed:
                 self.packed_rows += rows_used
                 self.packed_segments += n_chunks
+                self.qblocks_uniform += qblocks[0]
+                self.qblocks_mixed += qblocks[1]
             old = self._cost.get(key)
             self._cost[key] = cost_s if old is None else \
                 (1 - self._COST_EWMA) * old + self._COST_EWMA * cost_s
@@ -1100,6 +1110,8 @@ class CoalescingOrchestrator:
                         c.args[n_lead])[0]
                     seg_idx[row, off:off + c.valid] = slot
                 stacked += [seg_idx, cands]
+                qblocks = qblock_counts(seg_idx[:packer.n_rows],
+                                        self.policy.pack_align)
             out, launch_s, wait_s = self._run_attempts(kind, bucket, ex,
                                                        stacked, meta)
             with stage("dso.readback", **meta) as st_read:
@@ -1112,6 +1124,7 @@ class CoalescingOrchestrator:
                                 valid=sum(c.valid for c in batch),
                                 saved=n - packer.n_slots, packed=True,
                                 missed=self._count_missed(batch),
+                                qblocks=qblocks,
                                 stages={"stack": st_stack.s,
                                         "launch": launch_s, "wait": wait_s,
                                         "readback": st_read.s,
@@ -1141,6 +1154,8 @@ class CoalescingOrchestrator:
                 "dedup_rows_saved": self.dedup_rows_saved,
                 "packed_rows": self.packed_rows,
                 "packed_segments": self.packed_segments,
+                "qblocks_uniform": self.qblocks_uniform,
+                "qblocks_mixed": self.qblocks_mixed,
                 "ingraph_dispatches": self.ingraph_dispatches,
                 "cand_slots": slots,
                 "cand_valid": valid,

@@ -48,12 +48,14 @@ in VMEM scratch across the sequential innermost grid axis, exactly like
 ``kernels/flash_attention``.
 
 The **pointwise variant** (:func:`hstu_score_kernel`, HSTU's attention)
-keeps the grid, the ``row_index`` pool-row gather, the in-register
-dequant and the packed-segment steering, and replaces the online-softmax
-update with ``acc += SiLU(s + bias) @ v`` and a final ``/ N``.  Its bias
-arrives precomputed: one row per pool row on the cached path (every
-candidate of a row sits at the same position and time), one row per
-query on the extend path (``bias_kernel.py`` builds it there).
+keeps the ``row_index`` pool-row gather, the in-register dequant and the
+packed-segment steering, and replaces the online-softmax update with
+``acc += SiLU(s + bias) @ v`` and a final ``/ N``.  Its bias arrives
+precomputed: one row per pool row on the cached path (every candidate of
+a row sits at the same position and time), one row per query on the
+extend path (``bias_kernel.py`` builds it there).  Extend mode keeps this
+file's grid; cached mode (``pointwise_cached.py``) scores wide q blocks,
+streaming a history once for every sub-block of a block that reads it.
 """
 from __future__ import annotations
 
@@ -65,6 +67,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import default_interpret
+from repro.kernels.fused_score.pointwise_cached import hstu_cached_call
 
 NEG_INF = -1e30
 
@@ -246,20 +249,16 @@ def fused_score_kernel(row_index, lengths, k_scale, v_scale, q, k_hist,
 # pointwise variant (HSTU): SiLU(s + bias) / N in place of the softmax
 # ---------------------------------------------------------------------------
 
-def _pointwise_kernel(idx_ref, ks_ref, vs_ref, sb_ref, q_ref, kh_ref, vh_ref,
-                      bh_ref, kc_ref, vc_ref, *rest, mode: str, h: int,
-                      g: int, bq: int, bk: int, sq: int, s_hist: int,
-                      hist_steps: int, steps: int, n_norm: float):
-    """Same grid, gather and dequant as :func:`_fused_kernel`; each step
-    adds ``SiLU(s + bias) @ v`` to the accumulator (no running max, no
-    denominator) and the last step divides by ``n_norm``.  ``bh_ref`` is
-    the history bias block, one row broadcast over the q block (cached) or
-    one row per query (extend); the self bias is the scalar ``sb_ref[0]``
-    (cached) or the suffix bias block (extend, first of ``rest``)."""
-    if mode == "extend":
-        bc_ref, o_ref, acc_ref = rest
-    else:
-        o_ref, acc_ref = rest
+def _pointwise_extend_kernel(idx_ref, ks_ref, vs_ref, sb_ref, q_ref, kh_ref,
+                             vh_ref, bh_ref, kc_ref, vc_ref, bc_ref, o_ref,
+                             acc_ref, *, h: int, g: int, bq: int, bk: int,
+                             sq: int, s_hist: int, hist_steps: int,
+                             steps: int, n_norm: float):
+    """Same grid, gather and dequant as :func:`_fused_kernel` in extend
+    mode; each step adds ``SiLU(s + bias) @ v`` to the accumulator (no
+    running max, no denominator) and the last step divides by ``n_norm``.
+    ``bh_ref`` is the history bias block, one row per query; ``bc_ref`` the
+    suffix bias block."""
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -288,22 +287,17 @@ def _pointwise_kernel(idx_ref, ks_ref, vs_ref, sb_ref, q_ref, kh_ref, vh_ref,
         cols = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         _accumulate(s, (rows < sq) & (cols < s_hist), v, vs_ref[row, kvh])
 
-    @pl.when(_self_guard(mode, qi, kj, hist_steps))
-    def _self_step():
-        cj = qi if mode == "cached" else kj - hist_steps
+    @pl.when(_self_guard("extend", qi, kj, hist_steps))
+    def _suffix_step():
+        cj = kj - hist_steps
         q = q_ref[0, 0].astype(jnp.float32)
         k = kc_ref[0, 0].astype(jnp.float32)                 # [bq, D]
         v = vc_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
         rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
         cols = cj * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1)
-        ok = (rows < sq) & (cols < sq)
-        if mode == "cached":
-            s = s + sb_ref[0]
-            msk = ok & (rows == cols)        # self key only (SUMI)
-        else:
-            s = s + bc_ref[0]
-            msk = ok & (cols <= rows)        # causal within the suffix
+        s = s + bc_ref[0]
+        msk = (rows < sq) & (cols < sq) & (cols <= rows)     # causal
         _accumulate(s, msk, v, None)
 
     @pl.when(kj == steps - 1)
@@ -314,7 +308,7 @@ def _pointwise_kernel(idx_ref, ks_ref, vs_ref, sb_ref, q_ref, kh_ref, vh_ref,
 def hstu_score_kernel(row_index, k_scale, v_scale, self_bias, q, k_hist,
                       v_hist, bias_hist, k_cand, v_cand, bias_cand=None, *,
                       mode: str, sq: int, s_hist: int, n_norm: float,
-                      bq: int = 128, bk: int = 128,
+                      bq: int = 128, bk: int = 128, bs: int | None = None,
                       interpret: bool | None = None):
     """Pointwise attention over the same operands as
     :func:`fused_score_kernel` (q not pre-scaled): ``bias_hist``
@@ -322,7 +316,9 @@ def hstu_score_kernel(row_index, k_scale, v_scale, self_bias, q, k_hist,
     over its candidates) or R = Mp (extend: one row per query);
     ``self_bias`` [1] f32, the cached self key's bias; ``bias_cand``
     [B, Mp, Mp] f32, the extend suffix's causal bias (None in cached
-    mode)."""
+    mode).  Cached mode takes ``row_index`` [B, Mp // bs], a pool row per
+    sub-block of ``bs`` rows (default ``bq``), and scores q blocks of
+    ``bq`` rows (``pointwise_cached.py``); extend mode takes [B, Mp // bq]."""
     if mode not in ("cached", "extend"):
         raise ValueError(mode)
     b, h, mp, d = q.shape
@@ -331,37 +327,38 @@ def hstu_score_kernel(row_index, k_scale, v_scale, self_bias, q, k_hist,
     sp = k_hist.shape[2]
     nq = mp // bq
     hist_steps = sp // bk
-    steps = hist_steps + (1 if mode == "cached" else nq)
-    per_query = bias_hist.shape[1] > 1
-
+    interpret = default_interpret() if interpret is None else interpret
+    if mode == "cached":
+        return hstu_cached_call(
+            row_index, k_scale, v_scale, self_bias, q, k_hist, v_hist,
+            bias_hist, k_cand, v_cand, h=h, g=g, bq=bq, bs=bs or bq, bk=bk,
+            hist_steps=hist_steps, n_norm=n_norm, interpret=interpret)
+    steps = hist_steps + nq
     kernel = functools.partial(
-        _pointwise_kernel, mode=mode, h=h, g=g, bq=bq, bk=bk, sq=sq,
+        _pointwise_extend_kernel, h=h, g=g, bq=bq, bk=bk, sq=sq,
         s_hist=s_hist, hist_steps=hist_steps, steps=steps,
         n_norm=float(n_norm))
     q_map, kh_map, kc_map = _index_maps(mode, h, g, nq, hist_steps)
 
     def bh_map(bh, qi, kj, idx_ref, ks_ref, vs_ref, sb_ref):
         row, _, kblk, _ = kh_map(bh, qi, kj, idx_ref, ks_ref, vs_ref, sb_ref)
-        return (row, qi if per_query else 0, kblk)
+        return (row, qi, kblk)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, bq, d), q_map),
-        pl.BlockSpec((1, 1, bk, d), kh_map),
-        pl.BlockSpec((1, 1, bk, d), kh_map),
-        pl.BlockSpec((1, bq if per_query else 1, bk), bh_map),
-        pl.BlockSpec((1, 1, bq, d), kc_map),
-        pl.BlockSpec((1, 1, bq, d), kc_map),
-    ]
-    args = [q, k_hist, v_hist, bias_hist, k_cand, v_cand]
-    if mode == "extend":
-        def bc_map(bh, qi, kj, idx_ref, ks_ref, vs_ref, sb_ref):
-            return (bh // h, qi, _self_block(mode, qi, kj, nq, hist_steps))
-        in_specs.append(pl.BlockSpec((1, bq, bq), bc_map))
-        args.append(bias_cand)
+    def bc_map(bh, qi, kj, idx_ref, ks_ref, vs_ref, sb_ref):
+        return (bh // h, qi, _self_block(mode, qi, kj, nq, hist_steps))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,     # row_index, k_scale, v_scale, self_bias
         grid=(b * h, nq, steps),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), q_map),
+            pl.BlockSpec((1, 1, bk, d), kh_map),
+            pl.BlockSpec((1, 1, bk, d), kh_map),
+            pl.BlockSpec((1, bq, bk), bh_map),
+            pl.BlockSpec((1, 1, bq, d), kc_map),
+            pl.BlockSpec((1, 1, bq, d), kc_map),
+            pl.BlockSpec((1, bq, bq), bc_map),
+        ],
         out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],    # acc
     )
@@ -369,6 +366,6 @@ def hstu_score_kernel(row_index, k_scale, v_scale, self_bias, q, k_hist,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=default_interpret() if interpret is None else interpret,
-    )(row_index, k_scale, v_scale, self_bias, *args)
-
+        interpret=interpret,
+    )(row_index, k_scale, v_scale, self_bias, q, k_hist, v_hist, bias_hist,
+      k_cand, v_cand, bias_cand)

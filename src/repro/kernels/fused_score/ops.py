@@ -325,23 +325,69 @@ def _kernel_layout(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
     ones = jnp.ones((u, hkv), jnp.float32)
     ks = ones if k_scale is None else k_scale
     vs = ones if v_scale is None else v_scale
-    # per-q-block KV row index [B, nq] in scalar prefetch: the DSO v2
-    # generalization of the per-row dedup index — every q block of every
-    # batch row reads its own pool row's KV blocks, so a segment-packed
-    # row steers each candidate segment to its own user's history.  A
-    # per-candidate (2-D) index requires segments aligned to ``bq``
-    # boundaries (the packer's kernel-path contract); it is sampled at
-    # each q block's first candidate.
-    nq = qp.shape[2] // bq
-    if row_index is None:
-        idx = jnp.tile(jnp.arange(b, dtype=jnp.int32)[:, None], (1, nq))
-    elif row_index.ndim == 1:
-        idx = jnp.tile(row_index.astype(jnp.int32)[:, None], (1, nq))
-    else:
-        full = jnp.pad(row_index.astype(jnp.int32),
-                       ((0, 0), (0, nq * bq - m)), mode="edge")
-        idx = full[:, ::bq]
+    idx = _block_rows(row_index, b, qp.shape[2], bq)
     return (qp, khp, vhp, kcp, vcp), (ks, vs), idx, bq, bk
+
+
+def _block_rows(row_index, b: int, mp: int, bq: int, xp=jnp):
+    """Per-q-block KV row index [B, mp // bq] (scalar prefetch): the DSO
+    v2 generalization of the per-row dedup index — every q block of every
+    batch row reads its own pool row's KV blocks, so a segment-packed row
+    steers each candidate segment to its own user's history.  A
+    per-candidate (2-D) index requires segments aligned to ``bq``
+    boundaries (the packer's kernel-path contract); it is sampled at each
+    q block's first candidate.  ``xp`` is ``jnp`` in the graph, ``np`` on
+    the host (:func:`qblock_counts`)."""
+    nq = mp // bq
+    if row_index is None:
+        return xp.tile(xp.arange(b, dtype=xp.int32)[:, None], (1, nq))
+    if row_index.ndim == 1:
+        return xp.tile(row_index.astype(xp.int32)[:, None], (1, nq))
+    full = xp.pad(row_index.astype(xp.int32),
+                  ((0, 0), (0, mp - row_index.shape[1])), mode="edge")
+    return full[:, ::bq]
+
+
+# Rows of the pointwise kernel's cached q block: a block whose sub-blocks
+# all read one pool row streams that row's history once for all of them.
+WIDE_Q_BLOCK = 128
+
+
+def wide_qblock(m: int, sub: int) -> tuple:
+    """(bq, bs) of a pointwise cached call of ``m`` candidates whose pool
+    row may change every ``sub`` candidates: q blocks of the power of two
+    that holds ``m``, at least 8 and at most :data:`WIDE_Q_BLOCK`, in whole
+    sub-blocks of ``bs = min(sub, bq)`` rows."""
+    fit = max(8, 1 << (m - 1).bit_length())
+    bq = min(WIDE_Q_BLOCK, fit)
+    bq = min(max(sub, bq - bq % sub), fit)
+    return bq, min(sub, bq)
+
+
+def qblock_uniform(row_at, n_sub: int):
+    """Whether a q block's ``n_sub`` sub-blocks all read one pool row,
+    ``row_at(j)`` being sub-block ``j``'s: the rule by which the pointwise
+    kernel scores a block whole (scalars from its prefetched index) and by
+    which :func:`qblock_counts` counts blocks (arrays of blocks)."""
+    first = row_at(0)
+    same = first == first
+    for j in range(1, n_sub):
+        same = same & (row_at(j) == first)
+    return same
+
+
+def qblock_counts(seg_idx: np.ndarray, sub: int) -> tuple:
+    """(uniform, mixed) q blocks of a host [rows, m] packed seg index whose
+    segments start on multiples of ``sub``, blocked as the pointwise
+    kernel blocks a cached call of ``m`` candidates."""
+    rows, m = seg_idx.shape
+    bq, bs = wide_qblock(m, sub)
+    mp = -(-m // bq) * bq
+    blocks = _block_rows(seg_idx, rows, mp, bs, xp=np).reshape(
+        rows, mp // bq, bq // bs)
+    n = np.count_nonzero(qblock_uniform(lambda j: blocks[..., j],
+                                        bq // bs))
+    return n, blocks.shape[0] * blocks.shape[1] - n
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "bq", "bk",
@@ -430,9 +476,15 @@ def _hstu_kernel_call(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
                       interpret: bool | None):
     b, m, h, d = q.shape
     s_hist = k_hist.shape[1]
+    bs = None
+    if mode == "cached":
+        # wide q blocks in sub-blocks of the segment alignment ``bq``
+        bq, bs = wide_qblock(m, bq)
     (qp, khp, vhp, kcp, vcp), (ks, vs), idx, bq, bk = _kernel_layout(
         q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale, row_index,
         bq, bk)
+    if bs is not None:
+        idx = _block_rows(row_index, b, qp.shape[2], bs)
     bias_hist = _pad_to(jnp.asarray(bias_hist, jnp.float32), 2, bk)
     if bias_hist.shape[1] > 1:
         bias_hist = _pad_to(bias_hist, 1, bq)
@@ -442,7 +494,8 @@ def _hstu_kernel_call(q, k_hist, v_hist, k_cand, v_cand, k_scale, v_scale,
     out = hstu_score_kernel(
         idx, ks, vs, jnp.asarray(self_bias, jnp.float32).reshape(1), qp,
         khp, vhp, bias_hist, kcp, vcp, bias_cand, mode=mode, sq=m,
-        s_hist=s_hist, n_norm=n_norm, bq=bq, bk=bk, interpret=interpret)
+        s_hist=s_hist, n_norm=n_norm, bq=bq, bk=bk, bs=bs,
+        interpret=interpret)
     return jnp.swapaxes(out[:, :, :m, :d], 1, 2)
 
 

@@ -524,6 +524,49 @@ def test_orchestrator_hands_kv_rows_per_slot():
                                    rtol=1e-6)
 
 
+def test_packed_dispatch_counts_qblocks_by_the_kernel_rule():
+    """``qblocks_uniform`` / ``qblocks_mixed`` count a packed dispatch's
+    q blocks as the pointwise kernel blocks them (``fs_ops.qblock_counts``):
+    one full 256-candidate chunk fills two uniform 128-row blocks; two
+    tails sharing a 32-slot row (8-aligned) make one mixed block."""
+    from repro.kernels.fused_score import ops as fs_ops
+
+    seen = []
+
+    def build(kind, bucket, batch, signature):
+        def run(*args):
+            seen.append(np.asarray(args[-2]))            # the seg index
+            return np.zeros(args[-1].shape, np.float32)
+        return run
+
+    dso = CoalescingOrchestrator(
+        build, pad_slice_fn=lambda req, c, kind: (
+            req[0], req[1][:, c.start:c.start + c.valid]),
+        gather_fn=lambda rows, cs, m, kind: rows[0],
+        policy=CoalescePolicy(enabled=True, max_batch=4, window_s=0.05,
+                              pack_rows=1, pack_align=8),
+        n_streams=1, families={"k": [256, 32]},
+        kv_kinds={"k": (1, "packed")})
+    kv = [np.full((1, 2), u, np.float32) for u in range(3)]
+    try:
+        dso.submit((kv[0], np.ones((1, 256), np.int32)), 256, kind="k",
+                   dedup_token=0).result()
+        full = dso.stats()
+        with dso._cond[("k", 32)]:     # both tails ride one dispatch
+            futs = [dso.submit((kv[u], np.ones((1, 5), np.int32)), 5,
+                               kind="k", dedup_token=u) for u in (1, 2)]
+        for f in futs:
+            f.result()
+        st = dso.stats()
+    finally:
+        dso.shutdown()
+    assert (full["qblocks_uniform"], full["qblocks_mixed"]) == (2, 0)
+    assert (st["qblocks_uniform"], st["qblocks_mixed"]) == (2, 1)
+    assert st["dispatches"] == len(seen) == 2
+    want = [fs_ops.qblock_counts(seg, 8) for seg in seen]
+    assert tuple(map(sum, zip(*want))) == (2, 1)
+
+
 def _session_steps(seed=7, n_users=4, steps=3):
     """Fixed mixed session: one request per user a step; step 0 misses
     (encode), step 1 hits, step 2 grows even users' histories (extend)

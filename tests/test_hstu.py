@@ -157,6 +157,7 @@ def test_packed_tails_coalesce_and_match(setup):
         np.testing.assert_allclose(out, _reference(params, h, u, c),
                                    atol=TOL["native"], rtol=0)
     assert m["dso_packed_segments"] > m["dso_packed_rows"]
+    assert m["dso_qblocks_mixed"] > 0     # shared rows: a block, two users
 
 
 def test_request_timestamps_equal_to_pdas_score_the_same(setup):

@@ -41,9 +41,12 @@ def _exact_case(seed, *, b, m, h, s, u, d=64, store="native"):
     kc = rng.integers(-2, 3, (b, m, h, d)).astype(np.float32)
     vh = np.broadcast_to(_onehot_values(s, d)[None, :, None, :],
                          (u, s, h, d)).copy()
-    # candidate i's self value writes lane s + i: one key per lane
-    vc = np.broadcast_to(_onehot_values(m, d, s)[None, :, None, :],
-                         (b, m, h, d)).copy()
+    # candidate i's self value writes lane s + i (wrapped past lane d - 1:
+    # a cached row reads one self key, so lanes need only differ from the
+    # history's)
+    vc = np.zeros((m, d), np.float32)
+    vc[np.arange(m), s + np.arange(m) % (d - s)] = 1.0
+    vc = np.broadcast_to(vc[None, :, None, :], (b, m, h, d)).copy()
     ks = vs = None
     if store == "int8":
         kh, vh = kh.astype(np.int8), vh.astype(np.int8)
@@ -100,6 +103,50 @@ def test_packed_mask_bitwise():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(oracle))
 
 
+# Packed rows against the kernel's wide q blocks (min(128, Mp) rows in
+# sub-blocks of 8): a block whose sub-blocks share a pool row is scored
+# whole, any other one sub-block at a time.
+WIDE_LAYOUTS = {
+    # one segment fills an Mp = 128 row: one uniform block
+    "uniform": [[1] * 128],
+    # three users' 8-aligned segments in one 64-row block: mixed
+    "mixed": [[0] * 16 + [2] * 8 + [1] * 24],
+    # Mp = 256: a 128-candidate segment (uniform), then small ones (mixed)
+    "both": [[1] * 128 + [2] * 8 + [0] * 40 + [1] * 80],
+    # Mp = 32 (a 32-row block): one row uniform, one mixed
+    "short": [[2] * 32, [2] * 8 + [0] * 24],
+}
+
+
+@pytest.mark.parametrize("bk", [8, 16])
+@pytest.mark.parametrize("store", ["native", "int8"])
+@pytest.mark.parametrize("layout", list(WIDE_LAYOUTS))
+def test_packed_wide_blocks_bitwise(layout, store, bk):
+    """Every candidate of a uniform or mixed q block reads its own pool
+    row's whole history (five blocks of 8, none padded, or three of 16, the
+    last padded) and its own key, to the bit, against the jnp path and the
+    oracle."""
+    seg = np.asarray(WIDE_LAYOUTS[layout], np.int32)
+    b, m = seg.shape
+    t, rng = _exact_case(4, b=b, m=m, h=2, s=40, u=3, store=store)
+    bias = _dyadic(rng, (3, 1, 40))
+    args = (t["q"], t["k_hist"], t["v_hist"], t["k_cand"], t["v_cand"],
+            bias, 0.25)
+    kw = dict(n_norm=N_NORM, k_scale=t["k_scale"], v_scale=t["v_scale"],
+              row_index=seg, bk=bk)
+    prev = fs_ops.set_packed_alignment(8)
+    try:
+        got = fs_ops.hstu_cached_attention(*args, path="kernel", **kw)
+        want = fs_ops.hstu_cached_attention(*args, path="jnp", **kw)
+    finally:
+        fs_ops.set_packed_alignment(prev)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    oracle = fs_ref.pointwise_reference(
+        *args[:6], n_norm=N_NORM, self_bias=0.25, k_scale=t["k_scale"],
+        v_scale=t["v_scale"], row_index=seg)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(oracle))
+
+
 @pytest.mark.parametrize("bk", [8, 128])
 def test_extend_mask_bitwise(bk):
     """Suffix queries see the whole prefix (one bias row each) and the
@@ -140,16 +187,24 @@ def test_cached_random_operands_match_oracle(path, store):
     if store == "int8":
         qk, qv = quantize_leaf(kh, "int8"), quantize_leaf(vh, "int8")
         kh, vh, ks, vs = qk.q, qv.q, qk.scale, qv.scale
-    idx = jnp.asarray([1, 0, 1], jnp.int32)
-    got = fs_ops.hstu_cached_attention(
-        t["q"], kh, vh, t["k_cand"], t["v_cand"], t["bias"], 0.3,
-        n_norm=64.0, k_scale=ks, v_scale=vs, row_index=idx, path=path,
-        bk=16)
-    want = fs_ref.pointwise_reference(
-        t["q"], kh, vh, t["k_cand"], t["v_cand"], t["bias"], n_norm=64.0,
-        self_bias=0.3, k_scale=ks, v_scale=vs, row_index=idx)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=TOL, rtol=TOL)
+    # a 1-D dedup index, then a packed 2-D one (8-aligned segments)
+    packed = np.asarray([[0] * 8 + [1] * 4, [1] * 12, [1] * 8 + [0] * 4],
+                        np.int32)
+    prev = fs_ops.set_packed_alignment(8)
+    try:
+        for idx in (jnp.asarray([1, 0, 1], jnp.int32), packed):
+            got = fs_ops.hstu_cached_attention(
+                t["q"], kh, vh, t["k_cand"], t["v_cand"], t["bias"], 0.3,
+                n_norm=64.0, k_scale=ks, v_scale=vs, row_index=idx,
+                path=path, bk=16)
+            want = fs_ref.pointwise_reference(
+                t["q"], kh, vh, t["k_cand"], t["v_cand"], t["bias"],
+                n_norm=64.0, self_bias=0.3, k_scale=ks, v_scale=vs,
+                row_index=idx)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=TOL, rtol=TOL)
+    finally:
+        fs_ops.set_packed_alignment(prev)
 
 
 @pytest.mark.parametrize("path", ["jnp", "kernel"])

@@ -193,6 +193,40 @@ def test_hstu_kernel_compiles_under_its_own_op_name(one_chip, mode):
                          for ln in calls), calls
 
 
+def test_hstu_packed_cached_is_one_call_reading_k_hist_sixth(one_chip):
+    """A packed 512-candidate cached call over 4,096 int8 positions (wide q
+    blocks, history copied by the kernel) is one ``%_hstu_kernel_call``
+    whose operands keep their order: the benchmark's reader takes the
+    pooled K from the sixth."""
+    import re
+
+    from flamebench.families.hstu import kernel_call
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    fn = functools.partial(fs_ops.hstu_cached_attention, n_norm=4096.0,
+                           path="kernel", bk=512, interpret=False)
+    cand = _spec((1, 512, H, D), bf16, one_chip)
+    hist = _spec((B, HSTU_S, H, D), jnp.int8, one_chip)
+    prev = fs_ops.set_packed_alignment(8)
+    try:
+        text = jax.jit(fn).lower(
+            cand, hist, hist, cand, cand, _spec((B, 1, HSTU_S), f32, one_chip),
+            _spec((), f32, one_chip),
+            k_scale=_spec((B, 1, H, 1), f32, one_chip),
+            v_scale=_spec((B, 1, H, 1), f32, one_chip),
+            row_index=_spec((1, 512), jnp.int32, one_chip)
+        ).compile().as_text()
+    finally:
+        fs_ops.set_packed_alignment(prev)
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and re.match(r"\s*%_hstu_kernel_call\b",
+                                        calls[0]), calls
+    assert kernel_call(calls[0]) == {
+        "rows": 1, "heads": H, "q_rows": 512, "pool_rows": B,
+        "s_pad": HSTU_S, "kv_bytes": 1}
+
+
 def test_full_depth_hstu_steps_fit_one_chip(one_chip, monkeypatch):
     """hstu-large at a 4,096-action window (8 layers, d 256, 4 x 64 heads,
     2M-item catalog): the engine's int8-epilogue encode of four rows and a
