@@ -4,25 +4,18 @@
 Scans the backtick code spans of the narrative docs for repo-relative
 path-like references (contain a ``/`` or a known suffix) and verifies each
 resolves to a real file or directory.  Keeps README.md,
-docs/ARCHITECTURE.md, benchmarks/README.md and DESIGN.md honest as the tree
-refactors.
-
-Also cross-checks the ``--profile <name>`` tokens in benchmarks/README.md
-against the ``PROFILE_RUNNERS`` registry in benchmarks/bench_serving.py
-(parsed by AST so the check never imports jax).
+docs/ARCHITECTURE.md and DESIGN.md honest as the tree refactors.
 
     python scripts/check_docs.py
 """
 from __future__ import annotations
 
-import ast
 import os
 import re
 import sys
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-DOCS = ["README.md", "docs/ARCHITECTURE.md", "benchmarks/README.md",
-        "DESIGN.md"]
+DOCS = ["README.md", "docs/ARCHITECTURE.md", "DESIGN.md"]
 SUFFIXES = (".py", ".md", ".sh", ".json", ".yml")
 
 # `code span` that looks like a repo path: has a slash or a known suffix
@@ -53,8 +46,6 @@ def check(doc: str) -> list:
         text = f.read()
     for ref in _candidates(text):
         ref = ref.rstrip("/").split("::")[0]
-        # e.g. `BENCH_serving.json → quantized_pool` style spans
-        ref = ref.split(" ")[0].split("→")[0].strip()
         if not ("/" in ref or ref.endswith(SUFFIXES)):
             continue
         if "*" in ref:
@@ -73,41 +64,6 @@ def check(doc: str) -> list:
     return missing
 
 
-#: `--profile fke` / `--profile all|fke` style mentions in the bench README
-_PROFILE_REF = re.compile(r"--profile[=\s]+([A-Za-z0-9_|]+)")
-
-
-def _registry_profiles() -> set:
-    """AST-parse PROFILE_RUNNERS keys out of benchmarks/bench_serving.py
-    (importing it would drag in jax; CI gates must stay cheap)."""
-    path = os.path.join(ROOT, "benchmarks", "bench_serving.py")
-    tree = ast.parse(open(path).read(), filename=path)
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Assign):
-            continue
-        names = [t.id for t in node.targets if isinstance(t, ast.Name)]
-        if "PROFILE_RUNNERS" in names and isinstance(node.value, ast.Dict):
-            return {k.value for k in node.value.keys
-                    if isinstance(k, ast.Constant)}
-    raise SystemExit("check_docs: PROFILE_RUNNERS dict not found in "
-                     "benchmarks/bench_serving.py")
-
-
-def check_profiles() -> list:
-    """Every profile name benchmarks/README.md advertises must exist."""
-    doc = "benchmarks/README.md"
-    known = _registry_profiles() | {"all"}
-    bad = []
-    with open(os.path.join(ROOT, doc)) as f:
-        text = f.read()
-    for m in _PROFILE_REF.finditer(text):
-        for name in m.group(1).split("|"):
-            if name and name not in known:
-                bad.append((doc, f"--profile {name} (registry has: "
-                                 f"{', '.join(sorted(known))})"))
-    return bad
-
-
 def main() -> int:
     missing = []
     for doc in DOCS:
@@ -115,13 +71,12 @@ def main() -> int:
             missing.append(("<tree>", doc))
             continue
         missing.extend(check(doc))
-    missing.extend(check_profiles())
     if missing:
         print("docs reference files that do not exist:")
         for doc, ref in missing:
             print(f"  {doc}: {ref}")
         return 1
-    print(f"docs check OK ({', '.join(DOCS)}; bench profiles verified)")
+    print(f"docs check OK ({', '.join(DOCS)})")
     return 0
 
 

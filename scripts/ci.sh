@@ -69,22 +69,4 @@ python -m repro.launch.serve --engine flame --history-cache --mesh 2,2 \
     --pool-slots 64 --users 4 --requests 12 --history 64 \
     --buckets 16,8 --counts 8,16 --d-model 64
 
-echo "== bench gate: FKE vs chunked (1.3x multi-core, parity 1-core) =="
-python -m benchmarks.bench_serving --profile fke
-
-echo "== bench gate: DSO v2 packing >= 1.2x coalescing on zipf traffic =="
-python -m benchmarks.bench_serving --profile dso_nonuniform
-
-echo "== bench gate: sharded parity + per-shard pool split (4-dev mesh) =="
-python -m benchmarks.bench_serving --profile sharded
-
-echo "== bench gate: packed decode bitwise + gen-tokens/s vs unpacked =="
-python -m benchmarks.bench_serving --profile decode
-
-echo "== bench gate: fused decode parity + speedup + zero reroutes =="
-python -m benchmarks.bench_serving --profile decode_fused
-
-echo "== bench gate: EDF goodput-under-SLO vs FIFO + chaos liveness =="
-python -m benchmarks.bench_serving --profile overload
-
 echo "CI OK"

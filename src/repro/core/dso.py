@@ -10,8 +10,6 @@ TPU/JAX mapping of the paper's §3.3 (see DESIGN.md):
   CUDA streams / executor index queue    ->  executor checkout queue +
                                              JAX async dispatch; worker
                                              threads interleave host work
-  implicit-shape baseline                ->  plain jit re-traced/re-compiled
-                                             for every novel candidate count
 
 Routing: an upstream request with M candidates is split greedily into bucket
 chunks in descending bucket order; the final partial chunk is padded up to
@@ -347,10 +345,10 @@ class CoalescePolicy:
     prefetched index sampled at each block's first candidate, so packed
     rows feed ``path="kernel"`` only when no segment crosses a block
     boundary.  1 (the default) packs densely — the jnp formulation does
-    not care; the engine raises it to the kernel ``bq`` under
-    ``impl="fused"`` (`fused_score.ops.set_packed_alignment` declares the
-    contract to the trace).  Alignment holes are dead slots: seg index 0 /
-    candidate -1, exactly like row-tail padding."""
+    not care; the engine raises it to ``fused_score.ops.PACK_ALIGN`` (the
+    kernel's packed ``bq``) under ``impl="fused"``.  Alignment holes are
+    dead slots: seg index 0 / candidate -1, exactly like row-tail
+    padding."""
 
     enabled: bool = True
     max_batch: int = 4
@@ -1196,26 +1194,3 @@ class CoalescingOrchestrator:
                 cond.notify_all()
         for th in self._threads:
             th.join(timeout=5.0)
-
-
-# ---------------------------------------------------------------------------
-# implicit-shape baseline (the paper's "Default" row in Table 5)
-# ---------------------------------------------------------------------------
-
-class ImplicitShapeEngine:
-    """Plain jit: every novel candidate count triggers a fresh trace+compile,
-    the XLA analogue of TensorRT implicit-shape dynamic (re)allocation."""
-
-    def __init__(self, fn: Callable):
-        self._fn = jax.jit(fn)
-        self.compiles = 0
-        self._seen: set = set()
-
-    def score(self, request, m: int
-              ):  # flamecheck: host-sync-ok(implicit-shape baseline engine: the per-request sync IS the modeled cost)
-        if m not in self._seen:
-            self._seen.add(m)
-            self.compiles += 1
-        out = self._fn(*request)
-        jax.block_until_ready(out)
-        return out

@@ -41,8 +41,6 @@ Both paths are gated against ``ref.py`` (the dequantize → gather → concat
 from __future__ import annotations
 
 import functools
-import threading
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -63,66 +61,13 @@ def _auto_path() -> str:
     return "jnp" if default_interpret() else "kernel"
 
 
-# Observability for the 2-D (segment-packed) row_index auto-reroute below:
-# rerouting to the jnp formulation is correct but must not be silent — on
-# TPU it forfeits the kernel path the packer was built to feed.  The count
-# ticks once per traced call (i.e. once per AOT executor family built with
-# packed indices, not per request) and is surfaced by FlameEngine as the
-# ``packed_kernel_reroutes`` ServeMetrics counter.
-_reroute_lock = threading.Lock()
-_packed_reroutes = 0
-_reroute_warned = False
-
-
-def _note_packed_reroute():
-    global _packed_reroutes, _reroute_warned
-    with _reroute_lock:
-        _packed_reroutes += 1
-        first = not _reroute_warned
-        _reroute_warned = True
-    if first:
-        warnings.warn(
-            "fused_score: 2-D (segment-packed) row_index rerouted from the "
-            "Pallas kernel to the jnp formulation — packed segments are not "
-            "bq-aligned yet (ROADMAP: packer `align` knob). Pass "
-            "path='kernel' only with block-aligned segments.",
-            RuntimeWarning, stacklevel=5)
-
-
-def packed_reroute_count() -> int:
-    """Total 2-D row_index kernel->jnp auto-reroutes this process."""
-    with _reroute_lock:
-        return _packed_reroutes
-
-
-# Packed-dispatch alignment contract (process-wide, set by the engine
-# before its executors trace): a nonzero value declares that every
-# segment in a 2-D packed ``row_index`` starts at a multiple of that many
-# candidates — the SegmentPacker's ``align`` knob (core/dso.py) is the
-# producer.  With the contract declared, ``path="auto"`` keeps packed
-# calls on the kernel path (bq = the declared alignment, so sampling the
-# index at each q block's first candidate can never read across a
-# segment boundary) instead of rerouting to the jnp formulation.
-_packed_align = 0
-
-
-def set_packed_alignment(n: int) -> int:
-    """Declare the packed-segment alignment (0 clears). Returns the
-    previous value so callers can restore it."""
-    global _packed_align
-    n = int(n)
-    if n and (n < 8 or n % 8):
-        raise ValueError("packed alignment must be 0 or a multiple of 8 "
-                         f"(the f32 sublane tile), got {n}")
-    with _reroute_lock:
-        prev = _packed_align
-        _packed_align = n
-    return prev
-
-
-def packed_alignment() -> int:
-    with _reroute_lock:
-        return _packed_align
+# Packed-dispatch alignment contract: every segment of a 2-D (segment-
+# packed) ``row_index`` that reaches the kernel path starts at a multiple
+# of this many candidates — the f32 sublane tile of the kernel's q
+# (sub-)blocks.  The SegmentPacker's ``align`` (core/dso.py) produces it;
+# with q blocks of this size, sampling the index at each block's first
+# candidate never reads across a segment boundary.
+PACK_ALIGN = 8
 
 
 # Segment-packed (2-D row_index) histories at/above this length skip the
@@ -620,9 +565,9 @@ def hstu_extend_attention(q, k_prefix, v_prefix, k_suffix, v_suffix,
 # ---------------------------------------------------------------------------
 
 def _route(q, k_hist, row_index, mode: str, path: str):
-    """(path, bq) of one call: a 2-D (segment-packed) index takes the
-    kernel only under a declared alignment, with q blocks that size;
-    ``auto`` is the kernel where it compiles for real."""
+    """(path, bq) of one call: a 2-D (segment-packed) index runs q blocks
+    of :data:`PACK_ALIGN` rows; ``auto`` is the kernel where it compiles
+    for real."""
     bq = 128
     if row_index is not None and row_index.ndim == 2:
         if mode != "cached":
@@ -631,25 +576,7 @@ def _route(q, k_hist, row_index, mode: str, path: str):
         if row_index.shape != q.shape[:2]:
             raise ValueError(f"2-D row_index must be [B, M] = {q.shape[:2]}, "
                              f"got {row_index.shape}")
-        align = packed_alignment()
-        if path == "auto":
-            if align:
-                # the packer declared bq-aligned segments (SegmentPacker
-                # align knob), so per-q-block index sampling is safe: take
-                # the kernel path on TPU like any other fused call
-                path = _auto_path()
-            else:
-                # the kernel path steers KV per q BLOCK, so packed
-                # segments must be bq-aligned.  Sampling an unaligned
-                # index at block starts would silently score candidates
-                # against the wrong user's history, so auto routes
-                # per-candidate indices to the jnp formulation on every
-                # backend; explicit path="kernel" remains the tested
-                # aligned-segment contract.
-                path = "jnp"
-                _note_packed_reroute()
-        if align:
-            bq = align      # q blocks == declared segment alignment
+        bq = PACK_ALIGN
     if k_hist.shape[1] == 0:
         raise ValueError("fused attention needs a non-empty history/prefix "
                          "segment (degenerate cases route to the framework "

@@ -2,9 +2,9 @@
 
 The serving engine AOT-compiles one executable per (family, bucket); at
 Climber's published depth a cold start compiles every one of them.  Entry
-points (``chip_smoke.py``, ``launch/serve.py``, ``benchmarks/run.py``,
-``benchmarks/bench_serving.py``) call :func:`enable_compile_cache` at
-start-up; no library module touches the cache when imported.
+points (``chip_smoke.py``, ``launch/serve.py``, ``flamebench/harness.py``)
+call :func:`enable_compile_cache` at start-up; no library module touches
+the cache when imported.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
 module sets nothing.  Otherwise, on an accelerator backend, the cache lives

@@ -13,7 +13,6 @@
         --generate beam --beam-width 4
     PYTHONPATH=src python -m repro.launch.serve --engine flame \
         --generate topk --impl fused --pool-dtype int8   # FKE v2 decode
-    PYTHONPATH=src python -m repro.launch.serve --engine implicit
     PYTHONPATH=src python -m repro.launch.serve --engine text --arch gemma3-12b
 
 Engines are selected by name through the API v2 registry
@@ -30,6 +29,7 @@ import numpy as np
 from repro.configs import climber as climber_configs
 from repro.configs import reduced_config
 from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_serving_mesh
 from repro.models import build_model
 from repro.serving import ServeRequest, available_engines, create_engine
 from repro.serving.api import BeamConfig, DegradationPolicy, TopKConfig
@@ -112,81 +112,73 @@ def serve_rec(args):
         print(f"[serve] restored checkpoint @ step {step}")
 
     gen_mode = getattr(args, "generate", "none")
-    if gen_mode != "none" and args.engine == "flame":
+    if gen_mode != "none":
         if not args.history_cache:
             print("[serve] --generate implies --history-cache (beams live "
                   "in the pooled-KV plane); enabling it")
             args.history_cache = True
 
     kw = dict(n_history=args.history, feature_mode=args.feature_mode,
-              max_pending=args.max_pending, impl=args.impl)
-    if args.engine == "flame":
-        from repro.launch.mesh import make_serving_mesh
-        mesh = make_serving_mesh(args.mesh, args.model_parallel)
-        kw.update(mesh=mesh,
-                  buckets=tuple(int(b) for b in args.buckets.split(",")),
-                  n_streams=args.streams, coalesce=not args.no_coalesce,
-                  max_batch=args.max_batch,
-                  window_s=args.window_ms * 1e-3,
-                  n_workers=args.concurrency,
-                  history_cache=args.history_cache,
-                  pool_slots=args.pool_slots,
-                  pool_budget_bytes=(int(args.pool_budget_mb * 2**20)
-                                     if args.pool_budget_mb else None),
-                  pool_dtype=args.pool_dtype,
-                  pool_placement=args.pool_placement,
-                  pool_spill_bytes=int(args.pool_spill_mb * 2**20),
-                  incremental_history=args.incremental_history,
-                  extend_buckets=(tuple(int(b) for b in
-                                        args.extend_buckets.split(",")
-                                        if b.strip())
-                                  if args.extend_buckets.strip() else None),
-                  extend_refresh_limit=args.extend_refresh_limit,
-                  pack_tails=args.pack_tails,
-                  pack_rows=args.pack_rows if args.pack_rows > 0 else None,
-                  pack_align=args.pack_align if args.pack_align > 0
-                  else None,
-                  deadline_s=args.deadline_ms * 1e-3)
-        # ---- overload discipline / fault tolerance (ISSUE 9) ----
-        tier_defaults = None
-        if args.slo_tier_defaults.strip():
-            tier_defaults = {k: v * 1e-3 for k, v in _parse_kv_floats(
-                args.slo_tier_defaults, "--slo-tier-defaults").items()}
-        degradation = None
-        if args.degrade > 0:
-            degradation = DegradationPolicy(threshold_s=args.degrade * 1e-3)
-        faults = None
-        if args.fault_spec.strip():
-            faults = FaultInjector.parse(args.fault_spec,
-                                         seed=args.fault_seed)
-        kw.update(admission=args.admission, shed_policy=args.shed_policy,
-                  slo_tier_defaults=tier_defaults,
-                  watchdog_grace_s=args.watchdog_grace_ms * 1e-3,
-                  degradation=degradation, faults=faults)
-        if gen_mode != "none":
-            kw.update(generate=args.gen_steps, gen_vocab=args.gen_vocab)
-    else:
-        kw.update(n_workers=args.concurrency)
+              max_pending=args.max_pending, impl=args.impl,
+              mesh=make_serving_mesh(args.mesh, args.model_parallel),
+              buckets=tuple(int(b) for b in args.buckets.split(",")),
+              n_streams=args.streams, coalesce=not args.no_coalesce,
+              max_batch=args.max_batch,
+              window_s=args.window_ms * 1e-3,
+              n_workers=args.concurrency,
+              history_cache=args.history_cache,
+              pool_slots=args.pool_slots,
+              pool_budget_bytes=(int(args.pool_budget_mb * 2**20)
+                                 if args.pool_budget_mb else None),
+              pool_dtype=args.pool_dtype,
+              pool_placement=args.pool_placement,
+              pool_spill_bytes=int(args.pool_spill_mb * 2**20),
+              incremental_history=args.incremental_history,
+              extend_buckets=(tuple(int(b) for b in
+                                    args.extend_buckets.split(",")
+                                    if b.strip())
+                              if args.extend_buckets.strip() else None),
+              extend_refresh_limit=args.extend_refresh_limit,
+              pack_tails=args.pack_tails,
+              pack_rows=args.pack_rows if args.pack_rows > 0 else None,
+              deadline_s=args.deadline_ms * 1e-3)
+    # ---- overload discipline / fault tolerance (ISSUE 9) ----
+    tier_defaults = None
+    if args.slo_tier_defaults.strip():
+        tier_defaults = {k: v * 1e-3 for k, v in _parse_kv_floats(
+            args.slo_tier_defaults, "--slo-tier-defaults").items()}
+    degradation = None
+    if args.degrade > 0:
+        degradation = DegradationPolicy(threshold_s=args.degrade * 1e-3)
+    faults = None
+    if args.fault_spec.strip():
+        faults = FaultInjector.parse(args.fault_spec,
+                                     seed=args.fault_seed)
+    kw.update(admission=args.admission, shed_policy=args.shed_policy,
+              slo_tier_defaults=tier_defaults,
+              watchdog_grace_s=args.watchdog_grace_ms * 1e-3,
+              degradation=degradation, faults=faults)
+    if gen_mode != "none":
+        kw.update(generate=args.gen_steps, gen_vocab=args.gen_vocab)
     eng = create_engine(args.engine, bundle, params, **kw)
-    if args.engine == "flame":
-        fams = ", ".join(f"{k}:{v}" for k, v in eng.dso.families.items())
-        print(f"[serve] executor pool built in {eng.dso.build_time_s:.2f}s "
-              f"(families {fams}, impl {args.impl}, "
-              f"batch axis {eng.dso.policy.batch}, "
-              f"coalesce={'on' if eng.dso.policy.enabled else 'off'}, "
-              f"pack_tails={'on' if args.pack_tails else 'off'}, "
-              f"deadline={args.deadline_ms:g}ms)")
-        if eng.mesh is not None:
-            print(f"[serve] mesh: data={eng.mesh.shape['data']} x "
-                  f"model={eng.mesh.shape['model']} over "
-                  f"{len(jax.devices())} {jax.default_backend()} device(s)")
-        if args.history_cache:
-            budget = (f"{args.pool_budget_mb:g} MB budget"
-                      if args.pool_budget_mb else "no byte budget")
-            print(f"[serve] history-KV pool: {args.pool_slots} slots, "
-                  f"{budget}, dtype {args.pool_dtype}, "
-                  f"placement {args.pool_placement}, incremental="
-                  f"{'on' if args.incremental_history else 'off'}")
+    fams = ", ".join(f"{k}:{v}" for k, v in eng.dso.families.items())
+    print(f"[serve] executor pool built in {eng.dso.build_time_s:.2f}s "
+          f"(families {fams}, impl {args.impl}, "
+          f"batch axis {eng.dso.policy.batch}, "
+          f"coalesce={'on' if eng.dso.policy.enabled else 'off'}, "
+          f"pack_tails={'on' if args.pack_tails else 'off'}, "
+          f"deadline={args.deadline_ms:g}ms)")
+    if eng.mesh is not None:
+        print(f"[serve] mesh: data={eng.mesh.shape['data']} x "
+              f"model={eng.mesh.shape['model']} over "
+              f"{len(jax.devices())} {jax.default_backend()} device(s)")
+    if args.history_cache:
+        budget = (f"{args.pool_budget_mb:g} MB budget"
+                  if args.pool_budget_mb else "no byte budget")
+        print(f"[serve] history-KV pool: {args.pool_slots} slots, "
+              f"{budget}, dtype {args.pool_dtype}, "
+              f"placement {args.pool_placement}, incremental="
+              f"{'on' if args.incremental_history else 'off'}")
 
     tier_mix = _parse_kv_floats(args.slo_mix, "--slo-mix") \
         if args.slo_mix.strip() else None
@@ -215,8 +207,7 @@ def serve_rec(args):
     # chaos / overload runs tolerate rejections and injected failures —
     # the liveness contract they DO assert is zero hung futures: every
     # submitted request resolves, errors included, inside the timeout
-    chaos = args.engine == "flame" and (bool(args.fault_spec.strip())
-                                        or args.shed_policy != "none")
+    chaos = bool(args.fault_spec.strip()) or args.shed_policy != "none"
     res = run_workload_async(eng, reqs,
                              arrival_gap_s=args.arrival_gap_ms * 1e-3,
                              tolerate_errors=chaos)
@@ -312,14 +303,6 @@ def main():
                          "--max-batch still sizes how many distinct users "
                          "one packed dispatch can steer to; 0 = auto "
                          "max_batch/4)")
-    ap.add_argument("--pack-align", type=int, default=0,
-                    help="start every packed candidate segment on a "
-                         "multiple of this (multiple of 8; 1 = plain "
-                         "first-fit): aligned segments are constant per "
-                         "fused q-block, so packed 2-D dispatches keep the "
-                         "kernel formulation instead of rerouting to jnp "
-                         "(0 = auto: 8 under --impl fused --pack-tails, "
-                         "else 1)")
     ap.add_argument("--deadline-ms", type=float, default=0.0,
                     help="default per-request deadline budget: pending "
                          "chunks flush earliest-deadline-first and the "

@@ -14,13 +14,10 @@ and differs only in the execute stage:
                              requests share one dispatch), SUMI-masked
                              forward of the bundle's model (Climber,
                              HSTU), per-candidate task scores;
-  ImplicitShapeServingEngine Table 5 "Default" — plain jit over the full
-                             model, retrace+recompile per novel M, wrapped
-                             in the same pipeline for A/B comparison;
   TextServingEngine          prefill+decode serving for the decode-based
                              assigned architectures.
 
-Engines self-register ("flame" / "implicit" / "text"); construct them via
+Engines self-register ("flame" / "text"); construct them via
 ``repro.serving.api.create_engine``.  See DESIGN.md for the request
 lifecycle diagram and docs/ARCHITECTURE.md for the end-to-end narrative.
 
@@ -30,7 +27,7 @@ Executors are AOT-compiled per ``(kind, bucket)``:
 
   ("full",   M-bucket)   monolithic SUMI pass (pool off)
   ("cached", M-bucket)   candidate-only scoring against pooled history K/V;
-                         with ``kv_dedup`` the signature carries unique KV
+                         with KV-row dedup the signature carries unique KV
                          rows + a [B] gather index; under ``impl="fused"``
                          the rows are the pool's RAW (quantized) leaves and
                          both dequant and gather happen in-kernel
@@ -84,8 +81,7 @@ from repro.serving.api import (SLO_TIERS, TIER_RANK, AdmissionQueueFull,
                                ResponseFuture, ServeMetrics, ServeRequest,
                                ServeResponse, ShedError, WatchdogTimeout,
                                register_engine)
-from repro.kernels.fused_score.ops import (packed_reroute_count,
-                                           set_packed_alignment)
+from repro.kernels.fused_score.ops import PACK_ALIGN
 from repro.serving.kv_cache import (HistoryKVPool, KVCacheManager,
                                     quantize_kv_graph, raw_kv_specs)
 
@@ -96,6 +92,12 @@ _STOP = object()
 #: default window for better packing.  Tier-less chunks (and "standard")
 #: keep scale 1.0, so tier-agnostic callers see the v1 window exactly.
 _TIER_WINDOW_SCALE = {"interactive": 0.25, "standard": 1.0, "bulk": 2.0}
+
+#: re-encode-vs-extend crossover: an extension re-encodes the (window -
+#: bucket) suffix, so once the trusted prefix drops below this share of the
+#: window the extension does most of a full re-encode's work anyway (while
+#: layering another requantization)
+_EXTEND_CROSSOVER = 0.5
 
 #: service-time EWMA smoothing for admission-time wait prediction
 _SERVICE_EWMA = 0.3
@@ -765,11 +767,11 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
         dequantized prefix, so drift can accumulate over a long-lived
         user's repeated extensions (bounded per step by the dtype's error;
         periodic forced re-encode is a ROADMAP follow-up).
-    ``kv_dedup``
+    KV-row dedup (derived, not an option)
         identity-dedup of KV rows in the cached-scoring dispatcher: a
         multi-chunk request (or co-batched requests hitting one pool
         entry) stacks each user's KV rows once per dispatch, not once per
-        chunk.  Default ``None`` = auto: ON for accelerator backends
+        chunk.  ON for accelerator backends
         (the saved cost is the per-chunk host->HBM transfer; the
         executor-side row gather is an HBM-local copy, ~30x cheaper) and
         OFF for the CPU backend (stacking is a plain memcpy there, so the
@@ -852,11 +854,8 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
                  incremental_history: bool = False,
                  extend_buckets: Optional[Sequence[int]] = None,
                  extend_refresh_limit: int = 0,
-                 extend_crossover: float = 0.5,
-                 kv_dedup: Optional[bool] = None,
                  pack_tails: bool = False,
                  pack_rows: Optional[int] = None,
-                 pack_align: Optional[int] = None,
                  deadline_s: float = 0.0,
                  mesh: Optional[jax.sharding.Mesh] = None,
                  generate: int = 0,
@@ -900,23 +899,11 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
             # sizes the unique-KV axis (distinct users per dispatch).
             pack_rows = max(1, max_batch // 4)
         self._pack_rows = pack_rows
-        # bq-aligned packed dispatch (FKE v2): when the packer starts every
-        # candidate segment on a multiple of the kernel's q-block size, 2-D
-        # seg indices are constant per block and the packed fused families
-        # keep the kernel formulation instead of silently rerouting to jnp.
-        # Default: align to the Pallas sublane quantum under fused packing,
-        # plain first-fit (align 1, bitwise-identical layouts) elsewhere.
-        if pack_align is None:
-            pack_align = 8 if (self._fused and pack_tails) else 1
-        pack_align = int(pack_align)
-        if pack_align > 1 and pack_align % 8:
-            raise ValueError(
-                f"pack_align must be 1 (unaligned) or a multiple of 8 "
-                f"(Pallas sublane quantum), got {pack_align}")
-        self._pack_align = pack_align
-        # value for the fused-ops module knob at TRACE time: 0 declares
-        # "no alignment contract" (2-D kernel dispatch reroutes to jnp)
-        self._ops_pack_align = pack_align if pack_align > 1 else 0
+        # the packer's segment alignment: the fused kernel reads 2-D seg
+        # indices once per q block, so under impl="fused" every segment
+        # starts on the kernel's PACK_ALIGN contract; the other impls read
+        # per candidate and pack densely (first fit)
+        self._pack_align = PACK_ALIGN if self._fused else 1
         self._deadline_s = float(deadline_s)
         if pack_tails and not history_cache:
             raise ValueError(
@@ -947,15 +934,11 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
                     # window, mid-window edits from the nearest rung
                     extend_buckets = (n_history, 3 * n_history // 4,
                                       n_history // 2)
-                # re-encode-vs-extend crossover: an extension re-encodes
-                # the (window - bucket) suffix, so once the trusted prefix
-                # drops below ``extend_crossover`` of the window the
-                # extension does most of a full re-encode's work anyway
-                # (while layering another requantization).  Buckets below
-                # the threshold are dropped HERE so no AOT executor is
-                # ever compiled for a rung the dispatch policy would never
-                # route to (executor builds dominate engine startup).
-                min_prefix = int(extend_crossover * n_history)
+                # buckets below the re-encode-vs-extend crossover are
+                # dropped HERE so no AOT executor is ever compiled for a
+                # rung the dispatch policy would never route to (executor
+                # builds dominate engine startup)
+                min_prefix = int(_EXTEND_CROSSOVER * n_history)
                 self._extend_buckets = tuple(sorted(
                     {b for b in extend_buckets if b >= max(min_prefix, 1)},
                     reverse=True))
@@ -966,9 +949,8 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
                     raise ValueError(
                         f"extend_buckets {tuple(extend_buckets)} all fall "
                         f"below the re-encode-vs-extend crossover "
-                        f"({min_prefix} = {extend_crossover:g} * "
-                        f"n_history); raise the buckets or lower "
-                        f"extend_crossover")
+                        f"({min_prefix} = {_EXTEND_CROSSOVER:g} * "
+                        f"n_history); raise the buckets")
             self.history_pool = HistoryKVPool(
                 pool_slots, budget_bytes=pool_budget_bytes, dtype=pool_dtype,
                 placement=pool_placement, spill_bytes=pool_spill_bytes,
@@ -988,13 +970,11 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
             # (prequantized puts must record it so later dequantizing
             # lookups round-trip to the executors' compiled input dtype)
             self._kv_compute_dtype = jax.tree.leaves(kv_specs)[0].dtype
-            if kv_dedup is None:
-                # auto: ON for accelerator backends (each deduped row is a
-                # skipped H2D transfer) and, under the fused impl, on EVERY
-                # backend — the row gather is folded into the kernel's KV
-                # block reads, so dedup costs nothing even on CPU
-                kv_dedup = jax.default_backend() != "cpu" or self._fused
-            self._kv_dedup = kv_dedup
+            # KV-row dedup: ON for accelerator backends (each deduped row
+            # is a skipped H2D transfer) and, under the fused impl, on EVERY
+            # backend — the row gather is folded into the kernel's KV block
+            # reads, so dedup costs nothing even on CPU
+            self._kv_dedup = jax.default_backend() != "cpu" or self._fused
             self._encode_inflight: Dict[tuple, Future] = {}
             self._encode_lock = threading.Lock()
             self._key_memo: Dict[int, tuple] = {}   # request_id -> (key, fp)
@@ -1039,10 +1019,6 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
                     + s.shape[3:], s.dtype)
                 for s in self._cached_row_specs)
             self._s0 = int(self._cached_row_specs[0].shape[2])
-
-        # baseline for the packed_kernel_reroutes delta counter: the ops
-        # module count is process-wide and may predate this engine
-        self._reroutes_seen = packed_reroute_count()
 
         _batched = lambda specs, batch: tuple(  # noqa: E731
             jax.ShapeDtypeStruct((batch,) + s.shape[1:], s.dtype)
@@ -1213,38 +1189,28 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
             # a stable name per executor: its HLO module (and its host
             # events in a trace) read jit_flame_<kind>_b<bucket>
             fn.__name__ = fn.__qualname__ = f"flame_{kind}_b{bucket}"
-            # declare the packer's bq-alignment contract for the duration
-            # of THIS trace: the fused ops module consults it when a 2-D
-            # seg index reaches _fused_attention, and the knob is process-
-            # wide — scoping it to the compile keeps engines with
-            # different pack_align settings from leaking into each other
-            prev_align = set_packed_alignment(self._ops_pack_align)
-            try:
-                if self.mesh is not None:
-                    # attach the resolved NamedSharding specs to the AOT
-                    # signature: the executor consumes its operands in
-                    # exactly the layout the dispatcher stacks / the pool
-                    # stores them, so the steady-state hot path never
-                    # reshards.  Tracing under mesh_rules() binds the
-                    # model's constrain_ctx annotations (and the impl="cp"
-                    # shard_map route) to the same rule table.
-                    shapes = tuple(
-                        jax.ShapeDtypeStruct(
-                            s.shape, s.dtype,
-                            sharding=self._arg_sharding(s.shape))
-                        for s in shapes)
-                    out_sh = jax.tree.map(
-                        lambda s: self._arg_sharding(s.shape),
-                        jax.eval_shape(fn, self.params, *shapes))
-                    with shd.mesh_rules(self.mesh, self._shard_rules):
-                        compiled = jax.jit(fn, out_shardings=out_sh) \
-                            .lower(self.params, *shapes).compile()
-                else:
-                    compiled = jax.jit(fn).lower(self.params,
-                                                 *shapes).compile()
-                return _ParamsBound(compiled, self.params)
-            finally:
-                set_packed_alignment(prev_align)
+            if self.mesh is not None:
+                # attach the resolved NamedSharding specs to the AOT
+                # signature: the executor consumes its operands in exactly
+                # the layout the dispatcher stacks / the pool stores them,
+                # so the steady-state hot path never reshards.  Tracing
+                # under mesh_rules() binds the model's constrain_ctx
+                # annotations (and the impl="cp" shard_map route) to the
+                # same rule table.
+                shapes = tuple(
+                    jax.ShapeDtypeStruct(
+                        s.shape, s.dtype,
+                        sharding=self._arg_sharding(s.shape))
+                    for s in shapes)
+                out_sh = jax.tree.map(
+                    lambda s: self._arg_sharding(s.shape),
+                    jax.eval_shape(fn, self.params, *shapes))
+                with shd.mesh_rules(self.mesh, self._shard_rules):
+                    compiled = jax.jit(fn, out_shardings=out_sh) \
+                        .lower(self.params, *shapes).compile()
+            else:
+                compiled = jax.jit(fn).lower(self.params, *shapes).compile()
+            return _ParamsBound(compiled, self.params)
 
         # the bucket key gains a hit/miss dimension: candidate-only
         # ("cached") executors serve pool traffic, "encode" repopulates the
@@ -1264,7 +1230,7 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
             # packing subsumes KV-row dedup: same-user segments share one
             # KV slot inside the packer
             kv_kinds["cached"] = (n_kv, "packed" if self._pack_tails else
-                                  "dedup" if kv_dedup else "row")
+                                  "dedup" if self._kv_dedup else "row")
             if self._generate:
                 families["decode"] = tuple(buckets)
                 families["append"] = (1,)
@@ -1950,13 +1916,6 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
         valid = sum(st.get(f"cand_valid_{k}", 0) for k in ("cached", "full"))
         self._metrics.set_gauge(
             "padded_fraction", 1.0 - valid / slots if slots else 0.0)
-        # satellite observability for the packed-seg kernel->jnp reroute:
-        # the ops-module count is process-wide, so fold in deltas only
-        reroutes = packed_reroute_count()
-        delta = reroutes - self._reroutes_seen
-        if delta > 0:
-            self._metrics.incr("packed_kernel_reroutes", delta)
-        self._reroutes_seen = reroutes
         out = {f"dso_{k}": v for k, v in st.items()}
         out["dso_build_s"] = self.dso.build_time_s
         out.update({f"pda_{k}": v for k, v in
@@ -1979,63 +1938,6 @@ class FlameEngine(_HostPlaneMixin, _PipelinedEngine):
         self.dso.shutdown()
         if self.history_pool is not None:
             self.history_pool.release()
-
-
-@register_engine("implicit")
-class ImplicitShapeServingEngine(_HostPlaneMixin, _PipelinedEngine):
-    """Table 5 "Default" — plain jit over the full model: every novel
-    candidate count M retraces + recompiles in-band (the XLA analogue of
-    TensorRT implicit-shape dynamic (re)allocation).  Same pipeline and
-    protocol as FlameEngine so the two are A/B-comparable."""
-
-    def __init__(self, bundle: ModelBundle, params, *, n_history: int,
-                 feature_mode: str = "off",
-                 cache_capacity: int = 50_000, cache_ttl_s: float = 30.0,
-                 store: Optional[PDA.RemoteFeatureStore] = None,
-                 max_pending: int = 64, n_workers: int = 4,
-                 impl: str = "chunked"):
-        self.bundle = bundle
-        self.params = params
-        self.n_history = n_history
-        self.impl = impl
-        self._init_planes(bundle, feature_mode, store, cache_capacity,
-                          cache_ttl_s)
-        self._fn = jax.jit(lambda planes, c: bundle.prefill(
-            params, dict(self._plane_batch(planes), candidates=c),
-            impl=impl))
-        self.compiles = 0
-        self._seen: set = set()
-        self._seen_lock = threading.Lock()
-        super().__init__(max_pending=max_pending, n_workers=n_workers,
-                         name="implicit")
-
-    def _execute(self, req: ServeRequest
-                 ):  # flamecheck: host-sync-ok(Table-5 Default baseline: per-request jit + sync is the comparison point, not a defect)
-        self._check_request(req)
-        t0 = time.perf_counter()
-        planes = self._host_planes(
-            req, np.asarray(req.history[None, :self.n_history], np.int32))
-        t1 = time.perf_counter()
-        with self._seen_lock:
-            if req.m not in self._seen:
-                self._seen.add(req.m)
-                self.compiles += 1
-        cand = jnp.asarray(req.candidates[None], jnp.int32)
-        out = self._fn(tuple(jnp.asarray(a) for a in planes), cand)
-        jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        return np.asarray(out)[0], {"features_s": t1 - t0,
-                                    "execute_s": t2 - t1}
-
-    def _extra_metrics(self):
-        with self._seen_lock:
-            out = {"jit_compiles": self.compiles}
-        out.update({f"pda_{k}": v for k, v in
-                    dataclasses.asdict(self.features.stats).items()})
-        return out
-
-    def _close(self):
-        self.features.shutdown()
 
 
 @register_engine("text")

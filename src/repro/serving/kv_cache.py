@@ -47,7 +47,7 @@ absmax scale.  Only floating leaves (K/V) are quantized: integer leaves
 (an entry's action times) are stored as they are.  Dequantization happens at lookup (on device under device
 placement), so executor input signatures never change; int8 roughly
 quadruples users-per-budget vs f32 at a bounded score drift (asserted in
-tests/test_pda_v2.py, measured in BENCH_serving.json).
+tests/test_pda_v2.py).
 """
 from __future__ import annotations
 

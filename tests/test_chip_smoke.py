@@ -53,4 +53,4 @@ def test_chip_smoke_cpu_rehearsal(four_chips):
     if four_chips:
         assert "(4,1) vs single device: max abs diff 0.000e+00" in res.stdout
     else:
-        assert "packed_kernel_reroutes 0" in res.stdout
+        assert "dso_packed_segments" in res.stdout

@@ -25,7 +25,9 @@ Layers of coverage:
      sequential decode of the same engine, the pack_tails engine emits
      bitwise the unpacked engine's sequences, and a beam evicted from a
      tiny pool mid-generation replays (re-encode + re-append) to the same
-     sequences, counted by ``gen_replays``.
+     sequences, counted by ``gen_replays``; under dispatch faults and
+     eviction storms every decode future resolves and evicted beams
+     replay.
 """
 import dataclasses
 
@@ -43,6 +45,7 @@ from repro.kernels.flash_decode import ref as fd_ref
 from repro.models import build_model
 from repro.serving import FlameEngine, ServeRequest
 from repro.serving.api import BeamConfig, TopKConfig
+from repro.serving.faults import FaultInjector
 from repro.serving import generate as G
 from repro.serving.scheduler import run_workload_async
 from repro.types import ClimberConfig
@@ -394,6 +397,35 @@ def test_evicted_beam_replays_to_same_sequences(climber_setup, engines):
             "a 1-slot pool must force at least one beam replay"
     finally:
         tiny.shutdown()
+
+
+@pytest.mark.parametrize("impl", ["chunked", "fused"])
+def test_decode_chaos_zero_hung_and_beams_replay(climber_setup, impl):
+    """Chaos on packed generative decode over an int8 pool: transient
+    dispatch faults retry decode-step dispatches and eviction storms drop
+    parked beam caches mid-generation.  Every future resolves (result or
+    error), and evicted beams re-decode from the root (``gen_replays``)
+    instead of failing their requests."""
+    cfg, bundle, params, _ = climber_setup
+    eng = _engine(bundle, params, impl=impl, pool_dtype="int8",
+                  pack_tails=True,
+                  faults=FaultInjector.parse("dispatch:0.1,evict:0.15",
+                                             seed=43))
+    reqs = _requests(12, seed=4)
+    hung = 0
+    try:
+        for _ in range(2):
+            res = run_workload_async(eng, reqs, tolerate_errors=True,
+                                     result_timeout_s=120.0)
+            hung += res["hung"]
+            assert res["resolved"] + res["rejected"] + res["failed"] \
+                == len(reqs), res
+        m = eng.metrics()
+    finally:
+        eng.shutdown()
+    assert hung == 0
+    assert m["fault_dispatch_fired"] >= 1 and m["fault_evict_fired"] >= 1, m
+    assert m.get("gen_replays", 0) >= 1, m
 
 
 def test_generate_request_validation(engines):

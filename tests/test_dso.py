@@ -6,8 +6,7 @@ import pytest
 from tests._propcheck import given, settings, st
 
 from repro.core.dso import (Chunk, DynamicStreamOrchestrator, ExecutorPool,
-                            ImplicitShapeEngine, padded_fraction,
-                            split_request)
+                            padded_fraction, split_request)
 
 BUCKETS = st.lists(st.sampled_from([16, 32, 64, 128, 256, 512, 1024]),
                    min_size=1, max_size=5, unique=True)
@@ -81,14 +80,6 @@ def test_orchestrator_end_to_end_matches_direct():
         np.testing.assert_allclose(out, np.asarray(x) * 2.0)
         assert out.shape == (1, m)
     dso.shutdown()
-
-
-def test_implicit_shape_engine_recompiles():
-    eng = ImplicitShapeEngine(lambda x: x + 1.0)
-    for m in (3, 5, 3, 7):
-        out = eng.score((jnp.zeros((1, m)),), m)
-        assert out.shape == (1, m)
-    assert eng.compiles == 3     # 3 novel shapes
 
 
 def test_concurrent_submissions():

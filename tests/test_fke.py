@@ -321,6 +321,31 @@ def test_engine_fused_parity_and_free_dedup(climber_setup, pool_dtype):
         full.shutdown()
 
 
+def test_engine_fused_matches_chunked_on_int8_pool(climber_setup):
+    """Over int8 pools the fused engine scores a repeat-user session as the
+    chunked engine does, misses and hits alike: chunked dequantizes the
+    stored rows, fused folds the scales into its score and accumulator
+    multiplies, so only the order of the math separates them."""
+    cfg, bundle, params, _ = climber_setup
+    rng = np.random.default_rng(11)
+    hists = [rng.integers(0, 5000, 80).astype(np.int32) for _ in range(3)]
+    session = [(u, rng.integers(0, 5000, m).astype(np.int32))
+               for u, m in ((0, 24), (1, 9), (0, 32), (2, 16), (1, 24),
+                            (2, 5))]
+    outs = []
+    for impl in ("chunked", "fused"):
+        eng = _engine(bundle, params, history_cache=True, pool_slots=4,
+                      pool_dtype="int8", impl=impl)
+        try:
+            outs.append([eng.serve(hists[u], c, user_id=u)
+                         for u, c in session])
+        finally:
+            eng.shutdown()
+    for a, b in zip(*outs):
+        np.testing.assert_allclose(a.astype(np.float32),
+                                   b.astype(np.float32), atol=1e-2, rtol=0)
+
+
 def test_engine_default_extension_ladder(climber_setup):
     """incremental_history without explicit buckets ships the (n, 3n/4,
     n/2) trusted-prefix ladder."""

@@ -17,15 +17,15 @@ Layers of coverage:
   4. packed dispatch alignment — ``SegmentPacker(align=8)`` starts every
      segment on an 8-multiple (fuzzed: no align-sized block ever mixes
      two segments), ``align=1`` reproduces the legacy first-fit layouts
-     exactly, ``set_packed_alignment`` validates its contract, and a 2-D
-     seg index dispatched under a declared alignment takes the auto path
-     with ZERO ``packed_kernel_reroutes``;
+     exactly, and a 2-D seg index aligned to ``fused_score.ops.PACK_ALIGN``
+     scores on the auto and kernel paths as on the jnp one;
   5. engine level — fused generative decode (mixed top-k/beam) reproduces
      the chunked engine token for token on a native pool; the packed
-     fused engine reproduces the unpacked fused engine on an int8 pool
-     with zero kernel reroutes; EOS finishes sequences early against a
-     truncation oracle (``gen_early_exits``); beam width wider than the
-     universe; all-zero histories exercise the int8 scale-underflow floor.
+     fused engine, its packer aligned to ``PACK_ALIGN``, reproduces the
+     unpacked fused engine on an int8 pool; EOS finishes sequences early
+     against a truncation oracle (``gen_early_exits``); beam width wider
+     than the universe; all-zero histories exercise the int8
+     scale-underflow floor.
 """
 import dataclasses
 
@@ -323,56 +323,24 @@ def test_segment_packer_align1_is_legacy_first_fit():
     assert p.is_full() == all(f >= 16 for f in fills) and len(fills) == 3
 
 
-def test_set_packed_alignment_contract():
-    prev = fs_ops.set_packed_alignment(0)
-    try:
-        assert fs_ops.packed_alignment() == 0
-        assert fs_ops.set_packed_alignment(8) == 0
-        assert fs_ops.packed_alignment() == 8
-        assert fs_ops.set_packed_alignment(16) == 8
-        for bad in (4, -8, 7, 1):
-            with pytest.raises(ValueError):
-                fs_ops.set_packed_alignment(bad)
-        assert fs_ops.packed_alignment() == 16
-    finally:
-        fs_ops.set_packed_alignment(prev)
-
-
 def test_packed_2d_auto_path_no_reroute():
-    """With the alignment contract declared, a 2-D seg index on path="auto"
-    dispatches without counting a kernel->jnp reroute; without it, the
-    legacy reroute (and its counter) is preserved."""
-    t = _mk(41, b=2, m=16, h=2, hkv=2, d=16, s=24, u=3)
-    idx2 = jnp.asarray([[2] * 8 + [0] * 8, [1] * 8 + [2] * 8], jnp.int32)
+    """A 2-D seg index whose segments start on ``PACK_ALIGN`` multiples
+    scores on path="auto" and on the explicit kernel path (q blocks of
+    ``PACK_ALIGN`` rows) exactly as the jnp formulation does."""
+    a = fs_ops.PACK_ALIGN
+    t = _mk(41, b=2, m=2 * a, h=2, hkv=2, d=16, s=24, u=3)
+    idx2 = jnp.asarray([[2] * a + [0] * a, [1] * a + [2] * a], jnp.int32)
     # cached_reference has no 2-D gather; the jnp formulation (validated
     # against it on 1-D indices above and in test_fke) is the oracle here
     ref = fs_ops.fused_cached_attention(
         t["q"], t["k_hist"], t["v_hist"], t["k_cand"], t["v_cand"],
         row_index=idx2, path="jnp")
-    prev = fs_ops.set_packed_alignment(8)
-    try:
-        before = fs_ops.packed_reroute_count()
+    for path in ("auto", "kernel"):
         got = fs_ops.fused_cached_attention(
             t["q"], t["k_hist"], t["v_hist"], t["k_cand"], t["v_cand"],
-            row_index=idx2, path="auto")
-        assert fs_ops.packed_reroute_count() == before, \
-            "aligned 2-D dispatch must not count a reroute"
+            row_index=idx2, path=path)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=TOL, rtol=TOL)
-        # the declared alignment also sizes bq for the explicit kernel path
-        gk = fs_ops.fused_cached_attention(
-            t["q"], t["k_hist"], t["v_hist"], t["k_cand"], t["v_cand"],
-            row_index=idx2, path="kernel")
-        np.testing.assert_allclose(np.asarray(gk), np.asarray(ref),
-                                   atol=TOL, rtol=TOL)
-        fs_ops.set_packed_alignment(0)
-        before = fs_ops.packed_reroute_count()
-        fs_ops.fused_cached_attention(
-            t["q"], t["k_hist"], t["v_hist"], t["k_cand"], t["v_cand"],
-            row_index=idx2, path="auto")
-        assert fs_ops.packed_reroute_count() == before + 1
-    finally:
-        fs_ops.set_packed_alignment(prev)
 
 
 # ---------------------------------------------------------------------------
@@ -435,11 +403,10 @@ def test_fused_generate_matches_chunked_token_for_token(engines):
 
 def test_packed_fused_decode_equals_unpacked_zero_reroutes(engines):
     """int8 pool: concurrent segment-packed fused decode emits bitwise the
-    unpacked fused engine's sequences, packs real segments, and never
-    reroutes a packed kernel dispatch to the jnp formulation (the bq
-    alignment contract holds end to end)."""
+    unpacked fused engine's sequences and packs real segments, its packer
+    aligned to the kernel's ``PACK_ALIGN`` contract."""
     _, _, fused8, fused8p = engines
-    assert fused8p._pack_align == 8
+    assert fused8p._pack_align == fs_ops.PACK_ALIGN
     reqs = _requests(6, seed=3)
     want = [fused8.serve(r["history"], candidates=r["candidates"],
                          user_id=r["user_id"], generate=r["generate"])
@@ -449,7 +416,6 @@ def test_packed_fused_decode_equals_unpacked_zero_reroutes(engines):
         np.testing.assert_array_equal(got, exp)
     m = fused8p.metrics()
     assert m["dso_packed_segments"] > 0
-    assert m.get("packed_kernel_reroutes", 0) == 0
     # plain candidate scoring through the same packed fused engine too:
     # the packed layout traces a different graph shape for tail chunks, so
     # XLA refuses bitwise here (2.4e-4, pre-existing, an order under the
@@ -459,7 +425,6 @@ def test_packed_fused_decode_equals_unpacked_zero_reroutes(engines):
     b = fused8p.serve(r0["history"], candidates=r0["candidates"], user_id=0)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                atol=1e-3, rtol=0)
-    assert fused8p.metrics().get("packed_kernel_reroutes", 0) == 0
 
 
 def test_eos_early_exit_truncation_oracle(engines):

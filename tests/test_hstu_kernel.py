@@ -89,14 +89,10 @@ def test_packed_mask_bitwise():
                       [1] * 16 + [0] * 8], np.int32)
     args = (t["q"], t["k_hist"], t["v_hist"], t["k_cand"], t["v_cand"],
             bias, -0.5)
-    prev = fs_ops.set_packed_alignment(8)
-    try:
-        got = fs_ops.hstu_cached_attention(*args, n_norm=N_NORM,
-                                           row_index=seg, path="kernel")
-        want = fs_ops.hstu_cached_attention(*args, n_norm=N_NORM,
-                                            row_index=seg, path="jnp")
-    finally:
-        fs_ops.set_packed_alignment(prev)
+    got = fs_ops.hstu_cached_attention(*args, n_norm=N_NORM,
+                                       row_index=seg, path="kernel")
+    want = fs_ops.hstu_cached_attention(*args, n_norm=N_NORM,
+                                        row_index=seg, path="jnp")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     oracle = fs_ref.pointwise_reference(*args[:6], n_norm=N_NORM,
                                         self_bias=-0.5, row_index=seg)
@@ -134,12 +130,8 @@ def test_packed_wide_blocks_bitwise(layout, store, bk):
             bias, 0.25)
     kw = dict(n_norm=N_NORM, k_scale=t["k_scale"], v_scale=t["v_scale"],
               row_index=seg, bk=bk)
-    prev = fs_ops.set_packed_alignment(8)
-    try:
-        got = fs_ops.hstu_cached_attention(*args, path="kernel", **kw)
-        want = fs_ops.hstu_cached_attention(*args, path="jnp", **kw)
-    finally:
-        fs_ops.set_packed_alignment(prev)
+    got = fs_ops.hstu_cached_attention(*args, path="kernel", **kw)
+    want = fs_ops.hstu_cached_attention(*args, path="jnp", **kw)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     oracle = fs_ref.pointwise_reference(
         *args[:6], n_norm=N_NORM, self_bias=0.25, k_scale=t["k_scale"],
@@ -190,21 +182,17 @@ def test_cached_random_operands_match_oracle(path, store):
     # a 1-D dedup index, then a packed 2-D one (8-aligned segments)
     packed = np.asarray([[0] * 8 + [1] * 4, [1] * 12, [1] * 8 + [0] * 4],
                         np.int32)
-    prev = fs_ops.set_packed_alignment(8)
-    try:
-        for idx in (jnp.asarray([1, 0, 1], jnp.int32), packed):
-            got = fs_ops.hstu_cached_attention(
-                t["q"], kh, vh, t["k_cand"], t["v_cand"], t["bias"], 0.3,
-                n_norm=64.0, k_scale=ks, v_scale=vs, row_index=idx,
-                path=path, bk=16)
-            want = fs_ref.pointwise_reference(
-                t["q"], kh, vh, t["k_cand"], t["v_cand"], t["bias"],
-                n_norm=64.0, self_bias=0.3, k_scale=ks, v_scale=vs,
-                row_index=idx)
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       atol=TOL, rtol=TOL)
-    finally:
-        fs_ops.set_packed_alignment(prev)
+    for idx in (jnp.asarray([1, 0, 1], jnp.int32), packed):
+        got = fs_ops.hstu_cached_attention(
+            t["q"], kh, vh, t["k_cand"], t["v_cand"], t["bias"], 0.3,
+            n_norm=64.0, k_scale=ks, v_scale=vs, row_index=idx,
+            path=path, bk=16)
+        want = fs_ref.pointwise_reference(
+            t["q"], kh, vh, t["k_cand"], t["v_cand"], t["bias"],
+            n_norm=64.0, self_bias=0.3, k_scale=ks, v_scale=vs,
+            row_index=idx)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=TOL, rtol=TOL)
 
 
 @pytest.mark.parametrize("path", ["jnp", "kernel"])

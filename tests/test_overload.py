@@ -14,7 +14,7 @@ Three layers of coverage:
    bulk-tier work, per-family/per-tier deadline-miss breakouts populate.
 3. Chaos: a seeded `FaultInjector` replays an identical fault schedule,
    and a mixed-arm chaos run resolves (or errors) every single future —
-   zero hung, the gate `bench_serving --profile overload` also enforces.
+   zero hung.
 """
 import dataclasses
 import threading

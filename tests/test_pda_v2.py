@@ -428,13 +428,14 @@ def test_engine_partial_prefix_extension(serving_setup):
 @pytest.mark.parametrize("pool_dtype", ["native", "int8"])
 def test_engine_multi_chunk_dedup_correctness(serving_setup, pool_dtype):
     """A request split into same-bucket chunks rides one dispatch with its
-    KV rows stacked ONCE; scores must match the full-pass engine and stay
-    bitwise-stable across repeats.  The int8 variant exercises the
+    KV rows stacked ONCE (the fused impl dedups on every backend); scores
+    must match the full-pass engine and stay bitwise-stable across
+    repeats.  The int8 variant exercises the
     (key, fingerprint) dedup token: quantized lookups dequantize to fresh
     arrays, so object identity alone could never match."""
     cfg, bundle, params = serving_setup
     eng = _engine(bundle, params, history_cache=True, pool_slots=4,
-                  window_s=0.02, kv_dedup=True, pool_dtype=pool_dtype)
+                  window_s=0.02, impl="fused", pool_dtype=pool_dtype)
     eng_full = _engine(bundle, params)
     rng = np.random.default_rng(4)
     hist = rng.integers(0, 5000, 64).astype(np.int32)

@@ -41,7 +41,7 @@ def _flame(bundle, params, **kw):
 
 
 def test_registry_names_and_unknown():
-    assert {"flame", "implicit", "text"} <= set(available_engines())
+    assert {"flame", "text"} <= set(available_engines())
     with pytest.raises(KeyError, match="unknown engine"):
         create_engine("nope")
 
@@ -153,22 +153,6 @@ def test_malformed_request_fails_alone(climber_setup):
         eng.submit(ServeRequest(
             history=rng.integers(0, 1000, 64).astype(np.int32),
             candidates=None)).result(timeout=60)
-    eng.shutdown()
-
-
-def test_implicit_engine_same_protocol(climber_setup):
-    cfg, bundle, params = climber_setup
-    eng = create_engine("implicit", bundle, params, n_history=64,
-                        feature_mode="off", store=_store(), n_workers=2)
-    rng = np.random.default_rng(1)
-    reqs = [{"history": rng.integers(0, 1000, 64).astype(np.int32),
-             "candidates": rng.integers(0, 1000, m).astype(np.int32)}
-            for m in (8, 12, 8)]
-    outs = run_workload_async(eng, reqs)["outputs"]
-    assert [o.shape for o in outs] == [(8, 3), (12, 3), (8, 3)]
-    m = eng.metrics()
-    assert m["requests"] == 3
-    assert m["jit_compiles"] == 2          # 8 and 12 are the novel shapes
     eng.shutdown()
 
 

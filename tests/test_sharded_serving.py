@@ -203,7 +203,8 @@ def run(mesh, impl, dtype):
         h = rr.integers(0, 5000, 64).astype(np.int32)
         c = rr.integers(0, 5000, 11).astype(np.int32)
         res.append(np.asarray(eng.serve(h, c, user_id=i % 2)))
-    gauges = {k: v for k, v in eng.metrics().items() if "shard" in k}
+    gauges = {k: v for k, v in eng.metrics().items()
+              if "shard" in k or k == "pool_bytes_used"}
     hlo = {kb: ex.as_text() for kb, ex in eng.dso.compiled.items()}
     eng.shutdown()
     return np.concatenate([r.ravel() for r in res]), gauges, hlo
@@ -238,6 +239,9 @@ out, g, hlo = run(make_serving_mesh("2,2"), "chunked", "native")
 assert np.allclose(base, out, atol=5e-3), float(np.abs(base - out).max())
 assert g.get("pool_shard_ways") == 2, g
 assert g["pool_bytes_shard0"] == g["pool_bytes_shard1"] > 0, g
+# every stored byte sits on exactly one model shard
+assert g["pool_bytes_used_shard0"] == g["pool_bytes_used_shard1"] > 0, g
+assert 2 * g["pool_bytes_used_shard0"] == g["pool_bytes_used"], g
 for kb, txt in hlo.items():
     for op in RESHARD:
         assert op not in txt, (kb, op)

@@ -104,11 +104,7 @@ def test_fused_score_packed_aligned_kernel_compiles(one_chip, store):
     fn = functools.partial(fs_ops.fused_cached_attention, path="kernel",
                            interpret=False)
     args, kw = _kernel_args(one_chip, dtype, index_shape=(B, M))
-    prev = fs_ops.set_packed_alignment(8)
-    try:
-        _assert_kernel_compiles(fn, args, kw)
-    finally:
-        fs_ops.set_packed_alignment(prev)
+    _assert_kernel_compiles(fn, args, kw)
 
 
 def test_full_depth_fused_score_step_fits_one_chip(one_chip, monkeypatch):
@@ -182,11 +178,7 @@ def test_hstu_kernel_compiles_under_its_own_op_name(one_chip, mode):
                 _spec((B, suf, pre), f32, one_chip),
                 _spec((B, suf, suf), f32, one_chip)]
         kw = {}
-    prev = fs_ops.set_packed_alignment(8)
-    try:
-        text = jax.jit(fn).lower(*args, **kw).compile().as_text()
-    finally:
-        fs_ops.set_packed_alignment(prev)
+    text = jax.jit(fn).lower(*args, **kw).compile().as_text()
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert calls and all(re.match(r"\s*%_hstu_kernel_call\b", ln)
@@ -207,17 +199,13 @@ def test_hstu_packed_cached_is_one_call_reading_k_hist_sixth(one_chip):
                            path="kernel", bk=512, interpret=False)
     cand = _spec((1, 512, H, D), bf16, one_chip)
     hist = _spec((B, HSTU_S, H, D), jnp.int8, one_chip)
-    prev = fs_ops.set_packed_alignment(8)
-    try:
-        text = jax.jit(fn).lower(
-            cand, hist, hist, cand, cand, _spec((B, 1, HSTU_S), f32, one_chip),
-            _spec((), f32, one_chip),
-            k_scale=_spec((B, 1, H, 1), f32, one_chip),
-            v_scale=_spec((B, 1, H, 1), f32, one_chip),
-            row_index=_spec((1, 512), jnp.int32, one_chip)
-        ).compile().as_text()
-    finally:
-        fs_ops.set_packed_alignment(prev)
+    text = jax.jit(fn).lower(
+        cand, hist, hist, cand, cand, _spec((B, 1, HSTU_S), f32, one_chip),
+        _spec((), f32, one_chip),
+        k_scale=_spec((B, 1, H, 1), f32, one_chip),
+        v_scale=_spec((B, 1, H, 1), f32, one_chip),
+        row_index=_spec((1, 512), jnp.int32, one_chip)
+    ).compile().as_text()
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1 and re.match(r"\s*%_hstu_kernel_call\b",
@@ -261,16 +249,12 @@ def test_full_depth_hstu_steps_fit_one_chip(one_chip, monkeypatch):
         return bundle.score_candidates(params, kv, cands, impl="fused",
                                        row_index=seg)
 
-    prev = fs_ops.set_packed_alignment(8)
-    try:
-        compiled = [
-            jax.jit(encode).lower(on_chip(params), plane, plane).compile(),
-            jax.jit(score).lower(
-                on_chip(params), on_chip(kv),
-                _spec((1, 512), jnp.int32, one_chip),
-                _spec((1, 512), jnp.int32, one_chip)).compile()]
-    finally:
-        fs_ops.set_packed_alignment(prev)
+    compiled = [
+        jax.jit(encode).lower(on_chip(params), plane, plane).compile(),
+        jax.jit(score).lower(
+            on_chip(params), on_chip(kv),
+            _spec((1, 512), jnp.int32, one_chip),
+            _spec((1, 512), jnp.int32, one_chip)).compile()]
     assert "%_hstu_kernel_call" in compiled[1].as_text()
     # the bias tables are read with no XLA gather (one index at a time on
     # the TPU): only embedding rows and the packed row index (64 entries)
